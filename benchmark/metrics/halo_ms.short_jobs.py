@@ -1,0 +1,3 @@
+"""``halo_ms`` in the cells whose rate is ``gpx_per_s_per_chip.short_jobs``."""
+
+from benchmark.metrics.halo_ms import read  # noqa: F401

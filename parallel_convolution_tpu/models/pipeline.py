@@ -15,6 +15,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
+from parallel_convolution_tpu.obs import trace as obs_trace
 from parallel_convolution_tpu.ops.filters import Filter, get_filter
 from parallel_convolution_tpu.parallel import step as step_lib
 from parallel_convolution_tpu.parallel.mesh import make_grid_mesh
@@ -169,16 +170,31 @@ class ConvolutionModel:
 
     # -- array-level API ----------------------------------------------------
     def run_planar(self, x, iters: int) -> jnp.ndarray:
-        """(C, H, W) f32 in → (C, H, W) f32 out after ``iters`` iterations."""
-        backend, fuse, tile, overlap, col_mode = self._resolved_knobs(
-            x.shape[-2:], x.shape[0])
-        return step_lib.sharded_iterate(
-            x, self.filt, iters, mesh=self.mesh,
-            quantize=self.quantize, backend=backend,
-            storage=self.storage, fuse=fuse, boundary=self.boundary,
-            tile=tile, interior_split=self.interior_split, overlap=overlap,
-            col_mode=col_mode,
-        )
+        """(C, H, W) f32 in → (C, H, W) f32 out after ``iters`` iterations.
+
+        Its host work sits in the profiler span ``pctpu.run_planar``, with
+        ``pctpu.resolve`` and the step layer's ``pctpu.step.*`` inside."""
+        with obs_trace.span("pctpu.run_planar"):
+            return step_lib.sharded_iterate(
+                x, self.filt, iters, mesh=self.mesh,
+                **self._launch_knobs(x.shape[-2:], x.shape[0]))
+
+    def iterate_text(self, shape, iters: int) -> str:
+        """The compiled text of the iterate executable ``run_planar`` runs
+        for a (C, H, W) input of ``shape``: ``step.op_scopes`` maps its
+        instructions, as a device trace names them, to their layer."""
+        return step_lib.iterate_text(
+            shape, self.filt, iters, mesh=self.mesh,
+            **self._launch_knobs(shape[-2:], shape[0]))
+
+    def _launch_knobs(self, hw, channels) -> dict:
+        with obs_trace.span("pctpu.resolve"):
+            backend, fuse, tile, overlap, col_mode = self._resolved_knobs(
+                hw, channels)
+        return dict(quantize=self.quantize, backend=backend,
+                    storage=self.storage, fuse=fuse, boundary=self.boundary,
+                    tile=tile, interior_split=self.interior_split,
+                    overlap=overlap, col_mode=col_mode)
 
     def run_image(self, img: np.ndarray, iters: int) -> np.ndarray:
         """uint8 (H, W[, 3]) in → uint8 out; the one-call user entrypoint."""

@@ -43,7 +43,8 @@ from parallel_convolution_tpu.tuning import costmodel
 __all__ = [
     "exchange_rounds", "halo_bytes_per_round", "halo_bytes_total",
     "predicted_exchange_fraction", "predicted_exchange_split",
-    "record_drift", "record_step", "volume_face_bytes_per_round",
+    "record_drift", "record_halo", "record_step",
+    "volume_face_bytes_per_round",
 ]
 
 DIRECTIONS = ("north", "south", "east", "west")
@@ -224,10 +225,11 @@ def predicted_exchange_fraction(
         overlap=overlap)["exchange_fraction"]
 
 
-# -- the step-level recorder (metrics + event, one helper, two callers) ----
-# parallel/step.iterate_prepared and serving/engine.run_batch both drive
-# compiled runners; both call record_step so exchange attribution lands in
-# the same series regardless of the entry point.
+# -- the step-level recorders ----------------------------------------------
+# The fenced callers (serving/engine.run_batch, the convergence path, the
+# bench rows) call record_step: wall, model-attributed split, counters.
+# parallel/step.iterate_prepared dispatches asynchronously and feeds only
+# the counters, through record_halo, from what its runner's trace built.
 
 def _m():
     """Metric handles, created lazily through the global registry (so a
@@ -250,7 +252,7 @@ def _m():
             "overlapped pipeline", ("backend",)),
         metrics.counter(
             "pctpu_halo_bytes_total",
-            "analytic ghost-band bytes moved, per direction",
+            "ghost-band bytes moved, per direction",
             ("backend", "direction")),
         metrics.counter(
             "pctpu_halo_rounds_total", "halo exchange rounds executed",
@@ -307,7 +309,7 @@ def record_step(*, backend: str, grid, block_hw, radius: int, fuse: int,
         col_mode=col_mode)
     frac = split["exchange_fraction"]
     hidden_of_ex = split["exchange_hidden_fraction"]
-    wall, ex_s, comp_s, hid_s, hbytes, rounds, iters_m = _m()
+    wall, ex_s, comp_s, hid_s, *_ = _m()
     if wall_s is not None:
         wall.observe(wall_s, backend=backend)
         ex_s.inc(wall_s * frac, backend=backend)
@@ -338,10 +340,7 @@ def record_step(*, backend: str, grid, block_hw, radius: int, fuse: int,
                 if hidden_s > 0.0:
                     slab.inc(hidden_s * share, backend=backend,
                              direction=d, which="hidden")
-    for d in DIRECTIONS:
-        hbytes.inc(by[d], backend=backend, direction=d)
-    rounds.inc(by["rounds"], backend=backend)
-    iters_m.inc(iters, backend=backend)
+    record_halo(backend, by, by["rounds"], iters)
     if mg_level is not None:
         # Per-level multigrid attribution: sweeps executed at each grid
         # level, labeled so one series shows where cycle time goes.
@@ -387,6 +386,17 @@ def record_step(*, backend: str, grid, block_hw, radius: int, fuse: int,
     return {"halo_bytes": by, "exchange_fraction": frac,
             "exchange_hidden_fraction": hidden_of_ex,
             "overlap": bool(split["overlap"])}
+
+
+def record_halo(backend: str, halo_bytes: dict, rounds: int,
+                iters: int) -> None:
+    """The byte, round and iteration counters of one compiled-iterate
+    call; ``halo_bytes`` maps each of :data:`DIRECTIONS` to bytes."""
+    *_, hbytes, rounds_c, iters_c = _m()
+    for d in DIRECTIONS:
+        hbytes.inc(halo_bytes[d], backend=backend, direction=d)
+    rounds_c.inc(rounds, backend=backend)
+    iters_c.inc(iters, backend=backend)
 
 
 def record_drift(plan_key: str, backend: str, predicted_gpx: float | None,

@@ -20,6 +20,16 @@ so a trace is just a filtered view of the timeline every other subsystem
 already writes to.  ``scripts/trace_report.py`` reconstructs the trees,
 computes batch critical paths, and renders Chrome ``trace_event`` JSON.
 
+Every span also writes a profiler event under its own name (a
+``jax.profiler.TraceAnnotation``, with its attrs as the event's
+arguments), so a ``jax.profiler`` trace shows the program's spans on the
+device trace's clock: the job path's ``pctpu.run_planar`` →
+``pctpu.resolve`` / ``pctpu.step.{prepare,build,dispatch,crop}``, the
+serving phases (``pctpu.phase.<path>``, :class:`utils.tracing.PhaseTimer`)
+and the request spans.  The device side carries named scopes
+(``pctpu.halo``, ``pctpu.kernel_io``) that ``parallel.step.op_scopes``
+maps onto the compiled executable's instructions.
+
 Context propagation is ``contextvars``-based (thread- and
 task-correct): :func:`span` makes its context current for the enclosed
 code; worker threads that pick a request up later re-enter its context
@@ -35,20 +45,32 @@ makes first-class: :func:`obs.attribution.record_step` emits
 the measured wall by the roofline attribution (including the r12
 hidden-vs-exposed overlap split).
 
-Disabled mode (``PCTPU_OBS=0``, the metrics switch): :func:`span`
-returns a shared no-op context manager after one load + one branch —
-the ``fault_point`` contract, perf-guarded in ``tests/test_trace.py``.
-With obs ON but no event log installed, contexts and ids still
-propagate (responses carry a ``trace_id``) and only the span *records*
-are dropped (``events.emit`` no-ops).
+Cost, by mode (guarded in ``tests/test_trace.py`` and
+``tests/test_step_spans.py``):
 
-stdlib-only, jax-free.
+* ``PCTPU_OBS=0``: :func:`span` returns a shared no-op context manager
+  after one load + one branch — the ``fault_point`` contract (0.4 µs a
+  ``with`` block on CPU).
+* Obs on, no event log installed and no trace context current (a
+  library or benchmark call, not a served request): ids would reach no
+  one, so none are minted and no :class:`Span` is built; the span is
+  the profiler event alone: about 1 µs on CPU with no profiler
+  collecting, about 4 µs while one collects.
+  A caller that passes ``parent`` explicitly (a request entry point,
+  even a fresh root with ``parent=None``) always gets ids, so responses
+  carry a ``trace_id`` without a log.
+* Obs on with an event log or a current context: the full span, plus
+  its record at exit.
+
+stdlib-only and jax-free at import: the profiler event class is looked
+up once jax is loaded (no profiler can collect in a process without it).
 """
 
 from __future__ import annotations
 
 import contextlib
 import contextvars
+import sys
 import time
 import uuid
 from typing import NamedTuple
@@ -56,9 +78,9 @@ from typing import NamedTuple
 from parallel_convolution_tpu.obs import events as _events, metrics as _metrics
 
 __all__ = [
-    "SpanContext", "add_link", "attach", "build_trees", "current",
-    "emit_span", "format_traceparent", "new_span_id", "new_trace_id",
-    "parse_traceparent", "span", "span_records",
+    "SpanContext", "add_link", "annotate", "attach", "build_trees",
+    "current", "emit_span", "format_traceparent", "new_span_id",
+    "new_trace_id", "parse_traceparent", "span", "span_records",
 ]
 
 TRACEPARENT_VERSION = "00"
@@ -154,7 +176,7 @@ class Span:
 
     __slots__ = ("name", "context", "parent_id", "links", "attrs",
                  "status", "start_ts", "_start_perf", "_ctx_token",
-                 "_span_token")
+                 "_span_token", "_event")
 
     def __init__(self, name: str, context: SpanContext, parent_id: str,
                  links: list[dict], attrs: dict):
@@ -168,14 +190,17 @@ class Span:
         self._start_perf = 0.0
         self._ctx_token = None
         self._span_token = None
+        self._event = NULL_SPAN
 
     @property
     def ref(self) -> dict:
         return self.context.ref
 
     def set(self, **attrs) -> None:
-        """Attach/overwrite attributes on the eventual record."""
+        """Attach/overwrite attributes on the eventual record (and on the
+        profiler event)."""
         self.attrs.update(attrs)
+        self._event.set(**attrs)
 
     def link(self, ref, **attrs) -> None:
         """Add a causal link (a SpanContext or a ``{trace_id, span_id}``
@@ -187,6 +212,7 @@ class Span:
             self.links.append(r)
 
     def __enter__(self) -> "Span":
+        self._event = annotate(self.name, **self.attrs).__enter__()
         self.start_ts = time.time()
         self._start_perf = time.perf_counter()
         self._ctx_token = _CTX.set(self.context)
@@ -195,6 +221,7 @@ class Span:
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         dur = time.perf_counter() - self._start_perf
+        self._event.__exit__(exc_type, exc, tb)
         _SPAN.reset(self._span_token)
         _CTX.reset(self._ctx_token)
         if exc_type is not None:
@@ -232,6 +259,49 @@ class _NullSpan:
 
 NULL_SPAN = _NullSpan()
 
+
+_PROFILER_SPAN = None   # built once jax is loaded
+
+
+def _profiler_span_class():
+    """The profiler-only span: a ``jax.profiler.TraceAnnotation`` that
+    answers the :class:`Span` surface with no ids and no record (``set``
+    adds event arguments).  None before jax is imported: no profiler can
+    collect in a process without it."""
+    global _PROFILER_SPAN
+    if _PROFILER_SPAN is None and "jax" in sys.modules:
+        from jax.profiler import TraceAnnotation
+
+        class ProfilerSpan(TraceAnnotation):
+            __slots__ = ()
+            name = ""
+            context = None
+            parent_id = ""
+            status = "ok"
+            ref = None
+            set = TraceAnnotation.set_metadata
+
+            def link(self, ref, **attrs) -> None:
+                pass
+
+        _PROFILER_SPAN = ProfilerSpan
+    return _PROFILER_SPAN
+
+
+def annotate(name: str, /, **attrs):
+    """A profiler event under ``name`` with ``attrs`` as its arguments,
+    as a span-like context manager: what :func:`span` opens where no one
+    would read ids (no event log, no current trace context), and what
+    every full span writes beside its record.
+
+    Costs what a ``TraceAnnotation`` costs, under a microsecond when no
+    profiler is collecting.  The shared no-op span with obs disabled."""
+    if not _metrics.enabled():
+        return NULL_SPAN
+    cls = _PROFILER_SPAN or _profiler_span_class()
+    return NULL_SPAN if cls is None else cls(name, **attrs)
+
+
 _INHERIT = object()   # sentinel: span() parents to the current context
 
 
@@ -243,11 +313,16 @@ def span(name: str, *, parent=_INHERIT, links=(), **attrs):
     span parents to a request enqueued on another thread), or ``None``
     to force a new root trace.  ``links`` are extra causal edges
     (contexts or ref dicts).  With obs disabled this is one load + one
-    branch returning the shared no-op span.
+    branch returning the shared no-op span.  Every span writes a
+    profiler event (:func:`annotate`); ids are minted and a :class:`Span`
+    is built only where something reads them: an event log is installed,
+    a trace context is current, or ``parent`` is passed explicitly.
     """
     if not _metrics.enabled():
         return NULL_SPAN
     pctx = _CTX.get() if parent is _INHERIT else parent
+    if pctx is None and parent is _INHERIT and _events.get_log() is None:
+        return annotate(name, **attrs)
     tid = pctx.trace_id if pctx is not None else new_trace_id()
     ctx = SpanContext(tid, new_span_id())
     lk = [r for r in (_norm_link(l) for l in links) if r is not None]

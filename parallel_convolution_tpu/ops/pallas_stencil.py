@@ -18,6 +18,11 @@ SURVEY.md §3.2, reborn on-chip).
 Semantics contract: identical op order to ``ops.oracle.correlate_once`` /
 ``ops.conv.correlate_padded`` → float32 results are bit-identical, so the
 kernel drops into the sharded step as a backend with no semantic change.
+
+The wrappers' own XLA work around a launch — the tile-alignment pad, the
+crop back to the block, the band concatenation of a split launch — runs
+under the named scope ``pctpu.kernel_io`` (``KERNEL_IO``), so a device
+trace tells it from the kernels and from the halo (``step.op_scopes``).
 """
 
 from __future__ import annotations
@@ -31,6 +36,8 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from parallel_convolution_tpu.ops.filters import Filter
+
+KERNEL_IO = "pctpu.kernel_io"  # named scope of the wrappers' pad/crop/concat
 
 # Default output-tile shapes: multiples of the f32 (8, 128) VMEM tile.
 # Two defaults because Mosaic's scoped-VMEM stack scales differently per
@@ -389,7 +396,9 @@ def correlate_padded_pallas(
     eh = (gh - 1) * th + ext_h - Hp
     ew = (gw - 1) * tw + ext_w - Wp
     if eh > 0 or ew > 0:
-        padded = jnp.pad(padded, ((0, 0), (0, max(eh, 0)), (0, max(ew, 0))))
+        with jax.named_scope(KERNEL_IO):
+            padded = jnp.pad(padded,
+                             ((0, 0), (0, max(eh, 0)), (0, max(ew, 0))))
 
     taps = tuple(float(t) for t in filt.taps.reshape(-1))
     kernel = functools.partial(
@@ -417,7 +426,8 @@ def correlate_padded_pallas(
         ],
         interpret=interpret,
     )(padded)
-    return out[:, :H, :W]
+    with jax.named_scope(KERNEL_IO):
+        return out[:, :H, :W]
 
 
 def correlate_shifted_pallas(x: jnp.ndarray, filt: Filter, **kw) -> jnp.ndarray:
@@ -716,7 +726,9 @@ def fused_iterate_pallas(
     eh = (gh - 1) * th + ext_h - Hp
     ew = (gw - 1) * tw + ext_w - Wp
     if eh > 0 or ew > 0:
-        padded = jnp.pad(padded, ((0, 0), (0, max(eh, 0)), (0, max(ew, 0))))
+        with jax.named_scope(KERNEL_IO):
+            padded = jnp.pad(padded,
+                             ((0, 0), (0, max(eh, 0)), (0, max(ew, 0))))
 
     taps = tuple(float(t) for t in filt.taps.reshape(-1))
     vma = jax.typeof(padded).vma
@@ -767,22 +779,21 @@ def fused_iterate_pallas(
         split = _interior_range(valid_hw, (th, tw), r * T, (gh, gw),
                                 block_off)
     if split is None:
-        return call((gh, gw), (0, 0))[:, :h, :w]
+        out = call((gh, gw), (0, 0))
+        with jax.named_scope(KERNEL_IO):
+            return out[:, :h, :w]
 
     # 9-patch launch (split_patches is the shared plan): the
     # middle×middle patch runs fully unmasked; a pure-edge patch (middle
     # on one axis) statically drops the other axis's mask — its tiles
     # are interior along that axis by the box construction.
     patches = split_patches(split, (gh, gw))
-    bands, row_calls, cur_row = [], [], None
+    rows: dict = {}
     for (r0b, r1b), (c0b, c1b), axes in patches:
-        if cur_row is not None and (r0b, r1b) != cur_row:
-            bands.append(jnp.concatenate(row_calls, axis=2)
-                         if len(row_calls) > 1 else row_calls[0])
-            row_calls = []
-        cur_row = (r0b, r1b)
-        row_calls.append(call((r1b - r0b, c1b - c0b), (r0b, c0b), axes))
-    bands.append(jnp.concatenate(row_calls, axis=2)
-                 if len(row_calls) > 1 else row_calls[0])
-    out = jnp.concatenate(bands, axis=1) if len(bands) > 1 else bands[0]
-    return out[:, :h, :w]
+        rows.setdefault((r0b, r1b), []).append(
+            call((r1b - r0b, c1b - c0b), (r0b, c0b), axes))
+    with jax.named_scope(KERNEL_IO):
+        bands = [jnp.concatenate(calls, axis=2) if len(calls) > 1
+                 else calls[0] for calls in rows.values()]
+        out = jnp.concatenate(bands, axis=1) if len(bands) > 1 else bands[0]
+        return out[:, :h, :w]

@@ -22,13 +22,104 @@ ghost ring at the image boundary — non-periodic borders come for free.
 
 Everything here runs *inside* ``jax.shard_map`` over the ('x', 'y') mesh;
 ``block`` is one device's planar (C, h, w) float32 tile.
+
+Measurement: :func:`halo_exchange` runs under the named scope
+``pctpu.halo``, so its ghost pads, concatenates and collective-permutes
+carry that scope in the compiled executable (``step.op_scopes``).  While
+a :func:`counting` block is open, tracing an exchange tallies what it
+built — collective rounds, and each ppermute's slab bytes times the
+pairs of its permutation across the whole mesh — into a
+:class:`HaloCount` (the iterate runner keeps one per traced shape).
 """
 
 from __future__ import annotations
 
+import contextlib
+import dataclasses
+import math
+import threading
+
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from parallel_convolution_tpu.obs.attribution import DIRECTIONS
+
+SCOPE = "pctpu.halo"
+# (mesh axis, toward higher index) -> where the ghost band travels, in
+# obs.attribution's names: 'south' = toward higher rows, 'east' = cols.
+_DIRECTION = {("x", True): "south", ("x", False): "north",
+              ("y", True): "east", ("y", False): "west"}
+
+
+@dataclasses.dataclass
+class HaloCount:
+    """What traced halo exchanges built, summed over the whole mesh:
+    rounds (exchanges that sent anything) and bytes per direction."""
+
+    rounds: int = 0
+    bytes: dict = dataclasses.field(
+        default_factory=lambda: dict.fromkeys(DIRECTIONS, 0))
+
+    @property
+    def total_bytes(self) -> int:
+        return sum(self.bytes.values())
+
+    def __mul__(self, k: int) -> "HaloCount":
+        return HaloCount(self.rounds * k,
+                         {d: b * k for d, b in self.bytes.items()})
+
+    def __add__(self, other: "HaloCount") -> "HaloCount":
+        return HaloCount(self.rounds + other.rounds,
+                         {d: b + other.bytes[d]
+                          for d, b in self.bytes.items()})
+
+
+_TALLY = threading.local()
+
+
+@contextlib.contextmanager
+def counting():
+    """Tally the halo exchanges traced inside the block into a fresh
+    :class:`HaloCount` (innermost block wins; trace time only)."""
+    outer = getattr(_TALLY, "count", None)
+    _TALLY.count = count = HaloCount()
+    try:
+        yield count
+    finally:
+        _TALLY.count = outer
+
+
+def _note_ppermute(x, axis_name: str, n: int, down: bool, perm) -> None:
+    count = getattr(_TALLY, "count", None)
+    if count is None:
+        return
+    # One ppermute inside shard_map runs in every group of the other mesh
+    # axes: its global permutation has len(perm) pairs per group.
+    devices = math.prod(jax.sharding.get_abstract_mesh().shape.values())
+    groups = max(1, devices // n)
+    count.bytes[_DIRECTION[axis_name, down]] += (
+        x.size * x.dtype.itemsize * len(perm) * groups)
+
+
+def count_remote_exchange(block, r: int, grid: tuple[int, int],
+                          boundary: str = "zero") -> None:
+    """Tally one exchange that a kernel makes with remote DMAs, not
+    ppermutes (``pallas_rdma``): the ghost bands :func:`halo_exchange`
+    would move for this (C, h, w) block."""
+    count = getattr(_TALLY, "count", None)
+    if count is None:
+        return
+    C, h, w = block.shape
+    R, Cc = grid
+    B = jnp.dtype(block.dtype).itemsize
+    sent = count.total_bytes
+    for axis, n, groups, slab in (("x", R, Cc, C * r * w * B),
+                                  ("y", Cc, R, C * (h + 2 * r) * r * B)):
+        pairs = 0 if n == 1 else n if boundary == "periodic" else n - 1
+        for down in (True, False):
+            count.bytes[_DIRECTION[axis, down]] += slab * pairs * groups
+    count.rounds += count.total_bytes > sent
 
 
 def _shift(x: jnp.ndarray, axis_name: str, n: int, down: bool,
@@ -55,6 +146,7 @@ def _shift(x: jnp.ndarray, axis_name: str, n: int, down: bool,
         perm = [(i, i + 1) for i in range(n - 1)]
     else:
         perm = [(i + 1, i) for i in range(n - 1)]
+    _note_ppermute(x, axis_name, n, down, perm)
     return lax.ppermute(x, axis_name, perm)
 
 
@@ -94,5 +186,11 @@ def halo_exchange(block: jnp.ndarray, r: int, grid: tuple[int, int],
         raise ValueError(f"boundary must be one of {BOUNDARIES}, got {boundary!r}")
     periodic = boundary == "periodic"
     R, C = grid
-    padded = halo_pad_axis(block, r, "x", R, dim=1, periodic=periodic)
-    return halo_pad_axis(padded, r, "y", C, dim=2, periodic=periodic)
+    count = getattr(_TALLY, "count", None)
+    sent = count.total_bytes if count is not None else 0
+    with jax.named_scope(SCOPE):
+        padded = halo_pad_axis(block, r, "x", R, dim=1, periodic=periodic)
+        out = halo_pad_axis(padded, r, "y", C, dim=2, periodic=periodic)
+    if count is not None and count.total_bytes > sent:
+        count.rounds += 1  # a round is an exchange that built a collective
+    return out

@@ -24,6 +24,7 @@ bit-identical to the serial oracle for any mesh shape.
 from __future__ import annotations
 
 import os
+import re
 import time
 import warnings
 from functools import lru_cache
@@ -34,7 +35,9 @@ import numpy as np
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from parallel_convolution_tpu.obs import events as obs_events, metrics as obs_metrics
+from parallel_convolution_tpu.obs import (
+    events as obs_events, metrics as obs_metrics, trace as obs_trace,
+)
 from parallel_convolution_tpu.ops import conv
 from parallel_convolution_tpu.ops.filters import Filter
 from parallel_convolution_tpu.parallel import halo, kernels as kernel_forms
@@ -53,7 +56,8 @@ from jax import shard_map
 
 __all__ = ["BACKENDS", "STORAGE_DTYPES", "sharded_iterate", "sharded_converge",
            "sharded_converge_stream", "iterate_prepared", "reshard_prepared",
-           "resolve_overlap", "resolve_col_mode", "clamp_col_mode"]
+           "resolve_overlap", "resolve_col_mode", "clamp_col_mode",
+           "iterate_text", "op_scopes"]
 
 
 def _note_compile(builder: str, backend: str, grid, iters: int, fuse: int,
@@ -67,8 +71,6 @@ def _note_compile(builder: str, backend: str, grid, iters: int, fuse: int,
     request's span tree triggered which build-cache miss."""
     if not obs_metrics.enabled():
         return
-    from parallel_convolution_tpu.obs import trace as obs_trace
-
     obs_metrics.counter(
         "pctpu_compiles_total", "fresh traces/compiles of iteration runners",
         ("builder", "backend")).inc(builder=builder, backend=backend)
@@ -283,6 +285,7 @@ def _build_rdma_step(filt: Filter, grid, valid_hw, block_hw, quantize: bool,
     def step(v):
         from parallel_convolution_tpu.ops import pallas_rdma
 
+        halo.count_remote_exchange(v, filt.radius * fuse, grid, boundary)
         p = pallas_rdma.fused_rdma_step(
             v, filt, grid, boundary, quantize=quantize,
             out_dtype=v.dtype, tile=tile, interpret=interpret,
@@ -451,6 +454,10 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     (``resolve_overlap`` / ``resolve_col_mode``) — this layer compiles
     exactly what it is told, so the stamped knobs and the executable can
     never disagree.
+
+    The runner keeps what its halo exchanges built: ``halo_counts`` maps
+    each traced per-device block (shape, dtype) to the
+    :class:`halo.HaloCount` of one whole call (every chunk and the tail).
     """
     # Consulted only on lru_cache misses — i.e. exactly when a fresh
     # trace/compile happens, the event the 'backend_compile' site models.
@@ -473,17 +480,38 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
                              interior_split, overlap, col_mode)
             if rem else None)
 
+    counted: dict = {}   # the last trace of each step wins: no doubling
+
+    def count(name, fn):
+        def run(v):
+            with halo.counting() as c:
+                out = fn(v)
+            counted[name] = c
+            return out
+        return run
+
+    chunk = count("chunk", chunk)
+    if tail is not None:
+        tail = count("tail", tail)
+    halo_counts: dict = {}
+
     def body(block):
+        key = (tuple(block.shape), jnp.dtype(block.dtype))
         block = lax.fori_loop(0, n_chunks, lambda _, v: chunk(v), block)
         if tail is not None:
             block = tail(block)
+        zero = halo.HaloCount()
+        halo_counts[key] = (counted.get("chunk", zero) * n_chunks
+                            + counted.get("tail", zero))
         return block
 
     sharded = shard_map(
         body, mesh=mesh, in_specs=P(None, *AXES), out_specs=P(None, *AXES),
         check_vma=False,  # pallas interpret-mode slices trip the vma checker
     )
-    return jax.jit(sharded, donate_argnums=0)
+    runner = jax.jit(sharded, donate_argnums=0)
+    runner.halo_counts = halo_counts
+    return runner
 
 
 @lru_cache(maxsize=64)
@@ -903,22 +931,50 @@ def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
     """
     if jnp.dtype(xs.dtype) == jnp.uint8 and not quantize:
         _check_storage("u8", quantize)  # public entry: same guard as above
-    if check_contract:
-        _check_quantize_contract(xs, filt, quantize)
     R, Cc = grid_shape(mesh)
     block_hw = (xs.shape[1] // R, xs.shape[2] // Cc)
+    block = ((xs.shape[0], *block_hw), jnp.dtype(xs.dtype))
+    with obs_trace.span("pctpu.step.build") as sp:
+        if check_contract:
+            _check_quantize_contract(xs, filt, quantize)
+        fn, backend = _iterate_runner(
+            mesh, filt, iters, quantize, valid_hw, block_hw, xs.shape[0],
+            _storage_name(xs.dtype), backend, fuse, boundary, tile,
+            interior_split, fallback, overlap, col_mode)
+        if block not in fn.halo_counts:
+            sp.set(compiled=1)  # the dispatch below traces and compiles
+    # The dispatch stays asynchronous: no fence here (callers overlap the
+    # next chunk's work with device execution); wall-based series come
+    # from the callers that already fence.  The halo counts are what the
+    # runner's trace built, so counting costs no model arithmetic.
+    with obs_trace.span("pctpu.step.dispatch") as sp:
+        out = fn(xs)
+        if obs_metrics.enabled():
+            count = fn.halo_counts[block]
+            sp.set(exchanges=count.rounds, halo_bytes=count.total_bytes)
+    if obs_metrics.enabled():
+        from parallel_convolution_tpu.obs import attribution
+
+        attribution.record_halo(backend, count.bytes, count.rounds, iters)
+    return out
+
+
+def _iterate_runner(mesh, filt, iters, quantize, valid_hw, block_hw,
+                    channels, storage, backend, fuse, boundary, tile,
+                    interior_split, fallback, overlap, col_mode):
+    """Resolve a launch's knobs and look up its runner: ``(runner,
+    backend)`` — the shared build step of :func:`iterate_prepared` and
+    :func:`iterate_text`."""
     backend, fuse, tile, overlap, col_mode, _ = _resolve_auto(
-        mesh, filt, backend, fuse, tile, _storage_name(xs.dtype), quantize,
-        boundary, tuple(valid_hw), xs.shape[0], overlap=overlap,
-        col_mode=col_mode)
+        mesh, filt, backend, fuse, tile, storage, quantize, boundary,
+        tuple(valid_hw), channels, overlap=overlap, col_mode=col_mode)
     overlap = resolve_overlap(overlap, backend, mesh)
     col_mode = resolve_col_mode(col_mode, backend, mesh, block_hw,
-                                filt.radius, fuse, _storage_name(xs.dtype))
+                                filt.radius, fuse, storage)
     if fallback:
         backend = _resolve_fallback(mesh, filt, backend, quantize, fuse,
                                     boundary, _norm_tile(tile),
-                                    interior_split,
-                                    storage=_storage_name(xs.dtype),
+                                    interior_split, storage=storage,
                                     block_hw=block_hw, overlap=overlap,
                                     col_mode=col_mode)
         overlap = kernel_forms.clamp_overlap(overlap, backend)
@@ -926,22 +982,80 @@ def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
     fn = _build_iterate(mesh, filt, iters, quantize, tuple(valid_hw),
                         block_hw, backend, fuse, boundary, _norm_tile(tile),
                         interior_split, overlap, col_mode)
-    if not obs_metrics.enabled():
-        return fn(xs)
-    # Observed mode: attribute halo bytes/rounds and emit the exchange
-    # event.  NO wall and NO fence: this entry dispatches asynchronously
-    # (callers overlap the next chunk's work with device execution), and
-    # adding a block_until_ready here would silently serialize them —
-    # wall-based series come from the callers that already fence (bench,
-    # serving, the convergence count readback).
-    channels, shape = xs.shape[0], tuple(xs.shape)
-    out = fn(xs)
-    _record_step_obs(backend, mesh, block_hw, filt.radius,
-                     max(1, min(fuse, iters or 1)), iters, channels,
-                     _storage_name(out.dtype), boundary, None, shape,
-                     quantize, _norm_tile(tile),
-                     source="iterate_prepared", overlap=overlap,
-                     col_mode=col_mode)
+    return fn, backend
+
+
+def iterate_text(shape, filt: Filter, iters: int, mesh: Mesh | None = None,
+                 quantize: bool = True, backend: str = "shifted",
+                 storage: str = "f32", fuse: int | None = 1,
+                 boundary: str = "zero",
+                 tile: tuple[int, int] | None = None,
+                 interior_split: bool = False, fallback: bool = False,
+                 overlap: bool | None = None,
+                 col_mode: str | None = None) -> str:
+    """The compiled text of the iterate executable that
+    :func:`sharded_iterate` runs for a (C, H, W) image of ``shape`` with
+    these knobs — what :func:`op_scopes` reads to join a device trace's
+    ops to their layer.  After a run of the same shape jit's caches hold
+    the executable, so this compiles nothing new."""
+    if mesh is None:
+        mesh = make_grid_mesh()
+    C, H, W = (int(v) for v in shape)
+    R, Cc = grid_shape(mesh)
+    Hp, Wp = padded_extent(H, R), padded_extent(W, Cc)
+    fn, _ = _iterate_runner(
+        mesh, filt, iters, quantize, (H, W), (Hp // R, Wp // Cc), C, storage,
+        backend, fuse, boundary, tile, interior_split, fallback, overlap,
+        col_mode)
+    arg = jax.ShapeDtypeStruct((C, Hp, Wp), STORAGE_DTYPES[storage],
+                               sharding=block_sharding(mesh))
+    return fn.lower(arg).compile().as_text()
+
+
+_HLO_HEADER = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")
+_HLO_INSTR = re.compile(r"^\s+(ROOT\s+)?%?([^\s=]+)\s*=\s")
+_HLO_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS = re.compile(r"\bcalls=%?([^\s,})]+)")
+
+
+def op_scopes(compiled_text: str) -> dict[str, str]:
+    """``{instruction name: scope}`` of a compiled executable's text.
+
+    The scope is the innermost ``pctpu.*`` named scope in the
+    instruction's ``op_name`` metadata (``pctpu.halo``,
+    ``pctpu.kernel_io``), "" where there is none.  A fusion takes its
+    root's ``op_name``.  A device trace's op events carry the same
+    instruction names (``pad.20``, ``concatenate.7``), so this reads a
+    trace by layer."""
+    own: dict[str, str | None] = {}
+    fusion_calls: dict[str, str] = {}
+    root_of: dict[str, str] = {}
+    comp = ""
+    for line in compiled_text.splitlines():
+        if not line.startswith(" "):
+            m = _HLO_HEADER.match(line)
+            if m:
+                comp = m.group(1)
+            continue
+        m = _HLO_INSTR.match(line)
+        if m is None:
+            continue
+        name = m.group(2)
+        op = _HLO_OP_NAME.search(line)
+        own[name] = op.group(1) if op else None
+        calls = _HLO_CALLS.search(line)
+        if calls and " fusion(" in line:
+            fusion_calls[name] = calls.group(1)
+        if m.group(1):
+            root_of[comp] = name
+    out = {}
+    for name, op_name in own.items():
+        root = root_of.get(fusion_calls.get(name, ""))
+        if root is not None and own.get(root) is not None:
+            op_name = own[root]
+        scopes = [p for p in (op_name or "").split("/")
+                  if p.startswith("pctpu.")]
+        out[name] = scopes[-1] if scopes else ""
     return out
 
 
@@ -975,13 +1089,15 @@ def sharded_iterate(x, filt: Filter, iters: int, mesh: Mesh | None = None,
     if mesh is None:
         mesh = make_grid_mesh()
     _check_storage(storage, quantize)
-    xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage)
+    with obs_trace.span("pctpu.step.prepare"):
+        xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage)
     out = iterate_prepared(xs, filt, iters, mesh, valid_hw,
                            quantize=quantize, backend=backend, fuse=fuse,
                            boundary=boundary, tile=tile,
                            interior_split=interior_split, fallback=fallback,
                            overlap=overlap, col_mode=col_mode)
-    return out[:, : valid_hw[0], : valid_hw[1]].astype(jnp.float32)
+    with obs_trace.span("pctpu.step.crop"):
+        return out[:, : valid_hw[0], : valid_hw[1]].astype(jnp.float32)
 
 
 def sharded_converge(x, filt: Filter, tol: float, max_iters: int,
@@ -1051,8 +1167,6 @@ def sharded_converge(x, filt: Filter, tol: float, max_iters: int,
     # real device span: root of a fresh trace for a bare CLI call, child
     # of the caller's span when one is active.  record_step below then
     # hangs the model-attributed exchange/compute children off it.
-    from parallel_convolution_tpu.obs import trace as obs_trace
-
     with obs_trace.span("device", source="sharded_converge",
                         backend=backend) as dsp:
         out, done = fn(xs)

@@ -1421,7 +1421,10 @@ class ReplicaRouter:
         # and the response, so a trace/loadgen run can segment its
         # latency curves by codec.
         wire_arm = "frames" if "_frames_raw" in body else "json"
-        with obs_trace.span("route", request_id=rid, tenant=tenant,
+        # An explicit parent: the route span may root the request's trace,
+        # whose ids the response carries even with no event log.
+        with obs_trace.span("route", parent=obs_trace.current(),
+                            request_id=rid, tenant=tenant,
                             wire=wire_arm,
                             **({"shard": self.shard,
                                 "map_version": self.map_version}
@@ -1685,7 +1688,8 @@ class ReplicaRouter:
                     body["resume"] = token
                     ledger_seeded = True
         cost = self._converge_cost(body)
-        with obs_trace.span("route", request_id=rid, tenant=tenant,
+        with obs_trace.span("route", parent=obs_trace.current(),
+                            request_id=rid, tenant=tenant,
                             progressive=True,
                             **({"shard": self.shard,
                                 "map_version": self.map_version}

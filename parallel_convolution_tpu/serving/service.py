@@ -594,7 +594,9 @@ class ConvolutionService:
         # traced request has exactly ONE root (obs.trace).
         parent = obs_trace.current()
         root = parent
-        with obs_trace.span("admission", request_id=rid,
+        # An explicit parent: the admission span may root the request's
+        # trace, whose ids the response carries even with no event log.
+        with obs_trace.span("admission", parent=parent, request_id=rid,
                             backend=req.backend) as asp:
             if root is None:
                 root = asp.context
@@ -963,7 +965,7 @@ class ConvolutionService:
         self._bump("submitted")
         parent = obs_trace.current()
         root = parent
-        with obs_trace.span("admission", request_id=rid,
+        with obs_trace.span("admission", parent=parent, request_id=rid,
                             backend=req.backend, progressive=True) as asp:
             if root is None:
                 root = asp.context

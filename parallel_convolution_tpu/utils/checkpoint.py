@@ -280,11 +280,14 @@ def save_state(ckpt_dir, arr: jax.Array, meta: dict) -> None:
         shards[name] = {"crc32": zlib.crc32(raw), "bytes": len(raw)}
     meta = dict(meta, shards=shards)
     diskio.consult("checkpoint_write_meta")
-    tmp = snap / (META_NAME + ".tmp")
+    # Every host of a multi-host run writes the same meta and pointer:
+    # a temp name of its own keeps one host's rename from taking the
+    # other's file away mid-write.
+    tmp = snap / f"{META_NAME}.{os.getpid()}.tmp"
     tmp.write_text(json.dumps(meta))
     os.replace(tmp, snap / META_NAME)
     diskio.consult("checkpoint_write_meta")
-    ptr_tmp = d / (LATEST_NAME + ".tmp")
+    ptr_tmp = d / f"{LATEST_NAME}.{os.getpid()}.tmp"
     ptr_tmp.write_text(snap.name)
     os.replace(ptr_tmp, d / LATEST_NAME)
     _note_ckpt("save", time.perf_counter() - t_save0,

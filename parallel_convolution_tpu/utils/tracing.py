@@ -10,6 +10,9 @@ printed at the end.  Here:
   ``compile``/``device``/``copy`` is the serving layer's per-request
   breakdown); nested walls are recorded under slash-joined paths and a
   flat :meth:`PhaseTimer.to_row` export merges them into bench-row dicts.
+  Each phase also writes a profiler event, ``pctpu.phase.<path>``
+  (``obs.trace.annotate``), so the serving phases sit on a
+  ``jax.profiler`` trace beside the device ops.
 * :func:`device_trace` — context manager around ``jax.profiler.trace``;
   writes a TensorBoard/Perfetto trace of the XLA execution (the real
   per-op timeline the reference never had).
@@ -23,6 +26,8 @@ import threading
 import time
 
 import jax
+
+from parallel_convolution_tpu.obs import trace as obs_trace
 
 
 class PhaseTimer:
@@ -66,6 +71,7 @@ class PhaseTimer:
         stack = self._stack()
         stack.append(name)
         path = "/".join(stack)
+        event = obs_trace.annotate("pctpu.phase." + path).__enter__()
         t0 = time.perf_counter()
         try:
             yield
@@ -74,6 +80,7 @@ class PhaseTimer:
                 if fence is not None:
                     jax.block_until_ready(fence)
             finally:
+                event.__exit__(None, None, None)
                 # Record + pop even when the body OR the fence raised:
                 # a failing phase must not corrupt the nesting stack (the
                 # fault/retry paths re-enter the same timer afterwards).
