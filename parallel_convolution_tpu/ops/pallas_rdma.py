@@ -87,7 +87,7 @@ from parallel_convolution_tpu.ops.filters import Filter
 from parallel_convolution_tpu.parallel import channels
 from parallel_convolution_tpu.ops.pallas_stencil import (
     DEFAULT_TILE, _from_f32, _iterate_levels, _prefetch_window,
-    _round_mode_for, _round_up, _sublane, _to_f32, on_tpu,
+    _round_mode_for, _round_up, _sublane, _to_f32, on_tpu, tap_form,
 )
 
 # Semaphore slots: one (send, recv) pair per direction.
@@ -295,7 +295,7 @@ def _packed_slab_copy(cstage, s, send_sem, recv_sem, nbr):
 
 
 def _rdma_kernel(in_ref, out_ref, pad, send_sem, recv_sem, *scr, plan,
-                 taps, sep, k, r, T, C, h, w, R, Cc, periodic, quantize,
+                 form, k, r, T, C, h, w, R, Cc, periodic, quantize,
                  convex, round_mode, valid_hw, overlap=False,
                  partitioned=True):
     """One device's program: exchange T·r-deep ghosts in-kernel, then run
@@ -396,7 +396,7 @@ def _rdma_kernel(in_ref, out_ref, pad, send_sem, recv_sem, *scr, plan,
             for c in range(C):
                 acc = _iterate_levels(
                     pad[c, r0 : r1 + 2 * d, c0 : c1 + 2 * d],
-                    taps=taps, sep=sep, k=k, r=r, T=T,
+                    form=form, k=k, r=r, T=T,
                     out_hw=(r1 - r0, c1 - c0),
                     quantize=quantize, convex=convex,
                     round_mode=round_mode,
@@ -610,7 +610,7 @@ def _or2(a, b):
     return jnp.logical_or(a, b)
 
 
-def _rdma_tiled_kernel(in_ref, out_ref, pad, *rest, plan, taps, sep, k, r,
+def _rdma_tiled_kernel(in_ref, out_ref, pad, *rest, plan, form, k, r,
                        T, C, h, w, R, Cc, periodic, quantize, convex, th,
                        tw, sub_v, round_mode, valid_hw, overlap=False,
                        partitioned=True):
@@ -1008,7 +1008,7 @@ def _rdma_tiled_kernel(in_ref, out_ref, pad, *rest, plan, taps, sep, k, r,
     if valid_hw is not None:
         rows0 = rows + (lax.axis_index("x") * h - sub_v)
         cols0 = cols + (lax.axis_index("y") * w - LANE)
-    acc = _iterate_levels(cur, taps=taps, sep=sep, k=k, r=r, T=T,
+    acc = _iterate_levels(cur, form=form, k=k, r=r, T=T,
                           out_hw=(th, tw), quantize=quantize, convex=convex,
                           round_mode=round_mode, rows0=rows0, cols0=cols0,
                           valid_hw=valid_hw)
@@ -1155,7 +1155,8 @@ def fused_rdma_step(
     # is valid).
     kern_valid = (None if (T == 1 or periodic)
                   else (int(valid_hw[0]), int(valid_hw[1])))
-    sep = None  # rank-1 split saves little at one level; keep 2D order
+    # The rank-1 split saves little at one level: never requested here.
+    form = tap_form(filt, False, quantize)
     taps = tuple(float(t) for t in filt.taps.reshape(-1))
     vma = jax.typeof(block).vma
     cparams = pltpu.CompilerParams(
@@ -1201,7 +1202,7 @@ def fused_rdma_step(
     plan = channels.plan_for(ckey)
     if not tiled:
         kernel = functools.partial(
-            _rdma_kernel, plan=plan, taps=taps, sep=sep, k=k, r=r, T=T,
+            _rdma_kernel, plan=plan, form=form, k=k, r=r, T=T,
             C=C, h=h, w=w, R=grid[0], Cc=grid[1], periodic=periodic,
             quantize=quantize, convex=filt.convex, round_mode=round_mode,
             valid_hw=kern_valid, overlap=bool(overlap),
@@ -1255,7 +1256,7 @@ def fused_rdma_step(
     w_pad = max((gw - 1) * tw + ext_w, w + 2 * LANE)
 
     kernel = functools.partial(
-        _rdma_tiled_kernel, plan=plan, taps=taps, sep=sep, k=k, r=r, T=T,
+        _rdma_tiled_kernel, plan=plan, form=form, k=k, r=r, T=T,
         C=C, h=h, w=w, R=grid[0], Cc=grid[1], periodic=periodic,
         quantize=quantize, convex=filt.convex, th=th, tw=tw, sub_v=sub_v,
         round_mode=round_mode, valid_hw=kern_valid, overlap=bool(overlap),

@@ -18,6 +18,9 @@ SURVEY.md §3.2, reborn on-chip).
 Semantics contract: identical op order to ``ops.oracle.correlate_once`` /
 ``ops.conv.correlate_padded`` → float32 results are bit-identical, so the
 kernel drops into the sharded step as a backend with no semantic change.
+The two shorter tap forms (``tap_form``: rank-1 separable, box plus
+sparse taps) leave that order only where every partial sum is exact, so
+their bytes are the same.
 
 The wrappers' own XLA work around a launch — the tile-alignment pad, the
 crop back to the block, the band concatenation of a split launch — runs
@@ -29,9 +32,11 @@ from __future__ import annotations
 
 import functools
 import warnings
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -111,17 +116,84 @@ def _sep_taps(filt: Filter, separable: bool):
     return (tuple(float(t) for t in col), tuple(float(t) for t in row))
 
 
-def _correlate_window(win, taps, sep, k, th, tw):
+def _box_sparse_taps(filt: Filter, quantize: bool):
+    """Static ``(c, residue)`` of the constant-plus-sparse form, or None.
+
+    ``c`` is one tap value applied to the whole k×k window: a unit-weight
+    separable box sum (k lane-shifted, then k sublane-shifted slices)
+    times ``c``.  ``residue`` holds ``(dy, dx, tap - c)`` for every tap
+    that differs from ``c``, one multiply-add each.  With ``c == 0`` the
+    form is the direct loop minus its zero taps.  ``c`` is the value
+    needing the fewest slices: the most frequent non-zero tap, or zero
+    when it saves more (edge5: ``c = -1``, residue ``((2, 2, 25.0),)``;
+    sharpen3: ``c = 0``, five taps).
+
+    The form leaves the normative op order, so it is taken only where
+    every partial sum is exact in f32 and any order gives the same bits:
+    quantize mode (every level's input an integer in [0, 255]), taps that
+    are integer multiples of 1/256, and 255·(k²|c| + Σ|residue|)·256 <
+    2²⁴.  It must also take fewer slices than the k² of the direct loop.
+    """
+    if not (quantize and filt.dyadic):
+        return None
+    t = filt.taps.astype(np.float64)
+    if not np.all(t * 256.0 == np.rint(t * 256.0)):
+        return None
+    k = filt.size
+    vals = [float(v) for v in t.reshape(-1)]
+
+    def slices(c):
+        return (0 if c == 0.0 else 2 * k) + sum(v != c for v in vals)
+
+    c = min(dict.fromkeys(vals), key=slices)  # ties: first in row-major
+    residue = tuple((i // k, i % k, v - c) for i, v in enumerate(vals)
+                    if v != c)
+    l1 = k * k * abs(c) + sum(abs(rv) for _, _, rv in residue)
+    if 255.0 * l1 * 256.0 >= 2.0**24 or slices(c) >= k * k:
+        return None
+    return c, residue
+
+
+class TapForm(NamedTuple):
+    """The static tap arithmetic of one kernel build.
+
+    ``name`` is ``"direct"`` (``args``: the k² taps, row-major),
+    ``"separable"`` (``args``: ``(col_taps, row_taps)``, see
+    ``_sep_taps``) or ``"box_sparse"`` (``args``: ``(c, residue)``, see
+    ``_box_sparse_taps``)."""
+
+    name: str
+    args: tuple
+
+
+def tap_form(filt: Filter, separable: bool, quantize: bool) -> TapForm:
+    """The tap form a kernel build runs: the rank-1 form where requested
+    and exact, else the constant-plus-sparse form where exact and
+    shorter, else the normative direct loop.  Decided from the taps and
+    the quantize flag alone."""
+    sep = _sep_taps(filt, separable)
+    if sep is not None:
+        return TapForm("separable", sep)
+    box = _box_sparse_taps(filt, quantize)
+    if box is not None:
+        return TapForm("box_sparse", box)
+    return TapForm("direct", tuple(float(t) for t in filt.taps.reshape(-1)))
+
+
+def _correlate_window(win, form: TapForm, k, th, tw):
     """Stencil a (th+2r, tw+2r)+ f32-castable window down to (th, tw) f32.
 
-    ``sep = (col_taps, row_taps)`` switches to the rank-1 two-pass form —
-    2k MACs/px instead of k² (ops/conv.correlate_padded_separable's op
-    order: full-height row pass, then column pass), bit-identical to the
-    2D order for dyadic factors over u8-range values.  ``sep=None`` is the
-    normative row-major 2D multiply-add.
+    ``form`` (``tap_form``) picks the arithmetic.  ``separable`` is the
+    rank-1 two-pass form — 2k MACs/px instead of k²
+    (ops/conv.correlate_padded_separable's op order: full-height row
+    pass, then column pass), bit-identical to the 2D order for dyadic
+    factors over u8-range values.  ``box_sparse`` is ``c`` times a
+    unit-weight box sum in the same two passes, then one multiply-add
+    per residue tap, bit-identical in its exact regime.  ``direct`` is
+    the normative row-major 2D multiply-add.
     """
-    if sep is not None:
-        colt, rowt = sep
+    if form.name == "separable":
+        colt, rowt = form.args
         acc1 = jnp.zeros((th + k - 1, tw), jnp.float32)
         for dx in range(k):
             acc1 = acc1 + jnp.float32(rowt[dx]) * _to_f32(
@@ -131,12 +203,26 @@ def _correlate_window(win, taps, sep, k, th, tw):
             acc = acc + jnp.float32(colt[dy]) * acc1[dy : dy + th, :]
         return acc
     acc = jnp.zeros((th, tw), jnp.float32)
+    if form.name == "box_sparse":
+        c, residue = form.args
+        if c != 0.0:
+            rows = _to_f32(win[: th + k - 1, :tw])
+            for dx in range(1, k):
+                rows = rows + _to_f32(win[: th + k - 1, dx : dx + tw])
+            box = rows[:th, :]
+            for dy in range(1, k):
+                box = box + rows[dy : dy + th, :]
+            acc = acc - box if c == -1.0 else acc + jnp.float32(c) * box
+        for dy, dx, rv in residue:
+            acc = acc + jnp.float32(rv) * _to_f32(
+                win[dy : dy + th, dx : dx + tw])
+        return acc
     idx = 0
     for dy in range(k):
         for dx in range(k):
             # f32 accumulation even for narrow storage (cast is VPU-free-ish).
             w = _to_f32(win[dy : dy + th, dx : dx + tw])
-            acc = acc + jnp.float32(taps[idx]) * w
+            acc = acc + jnp.float32(form.args[idx]) * w
             idx += 1
     return acc
 
@@ -232,8 +318,6 @@ def _probe_magic_round() -> bool:
     Runs exactly once per process, on the first compiled quantized kernel
     build (sub-second next to any real workload's compile).
     """
-    import numpy as np
-
     from parallel_convolution_tpu.ops import oracle
     from parallel_convolution_tpu.ops.filters import get_filter
 
@@ -316,8 +400,8 @@ def _quantize_acc(acc, convex, round_mode):
     return acc
 
 
-def _stencil_kernel(hbm_ref, out_ref, scratch, sems, *, taps, sep, k, r, th,
-                    tw, ext_h, ext_w, quantize, convex, round_mode):
+def _stencil_kernel(hbm_ref, out_ref, scratch, sems, *, form, k, r, th, tw,
+                    ext_h, ext_w, quantize, convex, round_mode):
     """One grid program: DMA window c,i,j → VMEM, stencil it, emit tile.
 
     ``scratch`` holds two (ext_h, ext_w) slots — the (th+2r, tw+2r)
@@ -334,7 +418,7 @@ def _stencil_kernel(hbm_ref, out_ref, scratch, sems, *, taps, sep, k, r, th,
 
     slot = _prefetch_window(window_copy)
 
-    acc = _correlate_window(scratch[slot], taps, sep, k, th, tw)
+    acc = _correlate_window(scratch[slot], form, k, th, tw)
     if quantize:
         # Fused u8 store-back: saves one full HBM round trip per iteration
         # vs quantizing in a separate XLA fusion after the kernel.
@@ -402,7 +486,7 @@ def correlate_padded_pallas(
 
     taps = tuple(float(t) for t in filt.taps.reshape(-1))
     kernel = functools.partial(
-        _stencil_kernel, taps=taps, sep=sep,
+        _stencil_kernel, form=tap_form(filt, separable, quantize),
         k=k, r=r, th=th, tw=tw, ext_h=ext_h, ext_w=ext_w, quantize=quantize,
         convex=filt.convex,
         round_mode=(_round_mode_for(taps, interpret) if quantize
@@ -543,15 +627,15 @@ def axis_offset_classes(n_dev: int, block: int):
     return [(0, 0), (block, last - block), (last, last)]
 
 
-def _iterate_levels(cur, *, taps, sep, k, r, T, out_hw, quantize, convex,
+def _iterate_levels(cur, *, form, k, r, T, out_hw, quantize, convex,
                     round_mode, rows0=None, cols0=None, valid_hw=None):
     """T level-shrinking stencil levels: (oh + 2rT, ow + 2rT) f32 → (oh, ow).
 
     The single source of the temporal-fusion compute shape, shared by the
     ppermute fused kernel (``_fused_kernel``) and both RDMA fuse>1 kernels
     (``ops/pallas_rdma.py``) so the quantize path — magic round included —
-    and the tap chain (2D or separable ``sep``) are threaded identically
-    everywhere.
+    and the tap chain (``form``: direct, separable or box_sparse) are
+    threaded identically everywhere.
 
     Per level the window shrinks by r; ``rows0``/``cols0`` are the hoisted
     GLOBAL-coordinate iotas of the level-0 window ((w0h, 1) / (1, w0w));
@@ -566,7 +650,7 @@ def _iterate_levels(cur, *, taps, sep, k, r, T, out_hw, quantize, convex,
     H, W = valid_hw if valid_hw is not None else (None, None)
     for s in range(1, T + 1):
         ch, cw = oh + 2 * r * (T - s), ow + 2 * r * (T - s)
-        acc = _correlate_window(cur, taps, sep, k, ch, cw)
+        acc = _correlate_window(cur, form, k, ch, cw)
         if quantize:
             acc = _quantize_acc(acc, convex, round_mode)
         # Level-s window starts r*s deeper; slice the hoisted iotas.
@@ -581,7 +665,7 @@ def _iterate_levels(cur, *, taps, sep, k, r, T, out_hw, quantize, convex,
 
 
 def _fused_kernel(off_ref, hbm_ref, out_ref, scratch, sems, *,
-                  taps, sep, k, r, T, th, tw, ext_h, ext_w, valid_hw,
+                  form, k, r, T, th, tw, ext_h, ext_w, valid_hw,
                   quantize, convex, round_mode, grid_off=(0, 0),
                   mask_rows=True, mask_cols=True):
     """T in-VMEM stencil levels on one (th + 2rT, tw + 2rT) window.
@@ -654,7 +738,7 @@ def _fused_kernel(off_ref, hbm_ref, out_ref, scratch, sems, *,
             okc0 = (cols0 >= 0) & (cols0 < W)
             ok0 = okc0 if ok0 is None else (ok0 & okc0)
         cur = jnp.where(ok0, cur, 0.0)
-    cur = _iterate_levels(cur, taps=taps, sep=sep, k=k, r=r, T=T,
+    cur = _iterate_levels(cur, form=form, k=k, r=r, T=T,
                           out_hw=(th, tw), quantize=quantize, convex=convex,
                           round_mode=round_mode, rows0=rows0, cols0=cols0,
                           valid_hw=valid_hw)
@@ -731,13 +815,14 @@ def fused_iterate_pallas(
                              ((0, 0), (0, max(eh, 0)), (0, max(ew, 0))))
 
     taps = tuple(float(t) for t in filt.taps.reshape(-1))
+    form = tap_form(filt, separable, quantize)
     vma = jax.typeof(padded).vma
     off32 = offsets.astype(jnp.int32)
 
     def call(grid_hw, grid_off, mask_axes=(True, True)):
         mr, mc = mask_axes
         kernel = functools.partial(
-            _fused_kernel, taps=taps, sep=sep,
+            _fused_kernel, form=form,
             k=k, r=r, T=T, th=th, tw=tw, ext_h=ext_h, ext_w=ext_w,
             valid_hw=(tuple(valid_hw)
                       if (mr or mc) and valid_hw is not None else None),

@@ -395,6 +395,20 @@ def _build_halo_step(backend: str, filt: Filter, grid, valid_hw, block_hw,
     return step
 
 
+def _tap_form(backend: str, filt: Filter, quantize: bool) -> str:
+    """The tap form a backend's stencil is built with: for the Pallas
+    kernels ``pallas_stencil.tap_form`` (only ``pallas_sep`` asks for
+    the rank-1 form), for the XLA forms the one each runs."""
+    if backend in ("pallas", "pallas_sep", "pallas_rdma"):
+        from parallel_convolution_tpu.ops import pallas_stencil
+
+        return pallas_stencil.tap_form(filt, backend == "pallas_sep",
+                                       quantize).name
+    if backend == "separable" and filt.separable() is not None:
+        return "separable"
+    return "direct"
+
+
 def _make_block_step(filt: Filter, grid, valid_hw, block_hw, quantize: bool,
                      backend: str, fuse: int = 1, boundary: str = "zero",
                      tile: tuple[int, int] | None = None,
@@ -458,6 +472,7 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     The runner keeps what its halo exchanges built: ``halo_counts`` maps
     each traced per-device block (shape, dtype) to the
     :class:`halo.HaloCount` of one whole call (every chunk and the tail).
+    ``tap_form`` names the stencil's tap arithmetic (:func:`_tap_form`).
     """
     # Consulted only on lru_cache misses — i.e. exactly when a fresh
     # trace/compile happens, the event the 'backend_compile' site models.
@@ -511,6 +526,7 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     )
     runner = jax.jit(sharded, donate_argnums=0)
     runner.halo_counts = halo_counts
+    runner.tap_form = _tap_form(backend, filt, quantize)
     return runner
 
 
@@ -941,6 +957,7 @@ def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
             mesh, filt, iters, quantize, valid_hw, block_hw, xs.shape[0],
             _storage_name(xs.dtype), backend, fuse, boundary, tile,
             interior_split, fallback, overlap, col_mode)
+        sp.set(tap_form=fn.tap_form)
         if block not in fn.halo_counts:
             sp.set(compiled=1)  # the dispatch below traces and compiles
     # The dispatch stays asynchronous: no fence here (callers overlap the

@@ -108,7 +108,7 @@ def test_cold_call_marks_its_build_span_compiled(traced):
     _, per_call, _ = traced
     (build,) = [s for s in per_call[len(CASES)]
                 if s[2] == "pctpu.step.build"]
-    assert build[3] == {"compiled": 1}
+    assert build[3] == {"compiled": 1, "tap_form": "direct"}
 
 
 def test_phase_timer_phases_reach_the_profiler(traced):

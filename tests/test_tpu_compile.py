@@ -19,7 +19,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from parallel_convolution_tpu.ops import pallas_rdma, pallas_stencil
-from parallel_convolution_tpu.ops.filters import get_filter
+from parallel_convolution_tpu.ops.filters import get_filter, make_filter
 from parallel_convolution_tpu.parallel.mesh import AXES, make_grid_mesh
 
 
@@ -96,10 +96,24 @@ def test_fused_separable_T4(one_chip, dtype):
 
 @pytest.mark.parametrize("tile", [None, (512, 512)], ids=["default", "512"])
 def test_fused_edge5_T2(one_chip, tile):
-    # At (512, 512) the 2D tap loop's ~k² live level-0 temporaries take
-    # ~27 MiB of scoped VMEM: over Mosaic's 16 MiB default, inside the
-    # kernel's raised cap (the limit that stopped edge5 at fuse 8).
+    # edge5 in quantize mode takes the box-plus-sparse tap form.
     _fused(get_filter("edge5"), 2, jnp.bfloat16, False, one_chip, tile=tile)
+
+
+def test_fused_direct_loop_T2(one_chip):
+    # The direct 2D tap loop (edge5's taps outside the registry, so not
+    # dyadic): at (512, 512) its ~k² live level-0 temporaries take ~27 MiB
+    # of scoped VMEM, over Mosaic's 16 MiB default and inside the
+    # kernel's raised cap (the limit that stopped edge5 at fuse 8).
+    filt = make_filter("edge5_taps", get_filter("edge5").taps)
+    assert pallas_stencil.tap_form(filt, True, True).name == "direct"
+    _fused(filt, 2, jnp.bfloat16, True, one_chip, tile=(512, 512))
+
+
+def test_fused_edge5_cell_T8(one_chip):
+    # The edge5 cells' launch: a 2048² bf16 block at fuse 8.
+    _fused(get_filter("edge5"), 8, jnp.bfloat16, True, one_chip,
+           hw=(2048, 2048))
 
 
 @pytest.mark.parametrize("overlap", [False, True], ids=["serial", "overlap"])
