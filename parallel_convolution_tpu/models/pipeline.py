@@ -142,12 +142,10 @@ class ConvolutionModel:
             fuse = 1 if fuse is None else fuse
             self.plan_source = "explicit"
         overlap = step_lib.resolve_overlap(overlap, backend, self.mesh)
-        from parallel_convolution_tpu.parallel.mesh import (
-            grid_shape, padded_extent,
-        )
+        from parallel_convolution_tpu.parallel.mesh import grid_shape
 
-        R, C = grid_shape(self.mesh)
-        block_hw = (padded_extent(hw[0], R) // R, padded_extent(hw[1], C) // C)
+        block_hw = step_lib.block_extent(hw, grid_shape(self.mesh), backend,
+                                         self.storage, self.boundary)
         col_mode = step_lib.resolve_col_mode(
             col_mode, backend, self.mesh, block_hw, self.filt.radius,
             int(fuse), self.storage)

@@ -389,14 +389,21 @@ def record_step(*, backend: str, grid, block_hw, radius: int, fuse: int,
 
 
 def record_halo(backend: str, halo_bytes: dict, rounds: int,
-                iters: int) -> None:
+                iters: int, wire_bytes: int | None = None) -> None:
     """The byte, round and iteration counters of one compiled-iterate
-    call; ``halo_bytes`` maps each of :data:`DIRECTIONS` to bytes."""
+    call; ``halo_bytes`` maps each of :data:`DIRECTIONS` to bytes, and
+    ``wire_bytes`` is what a remote-DMA kernel's copies moved (None for
+    forms that exchange otherwise)."""
     *_, hbytes, rounds_c, iters_c = _m()
     for d in DIRECTIONS:
         hbytes.inc(halo_bytes[d], backend=backend, direction=d)
     rounds_c.inc(rounds, backend=backend)
     iters_c.inc(iters, backend=backend)
+    if wire_bytes is not None:
+        metrics.counter(
+            "pctpu_rdma_wire_bytes_total",
+            "bytes the remote-DMA kernels' band copies moved over the mesh",
+            ("backend",)).inc(wire_bytes, backend=backend)
 
 
 def record_drift(plan_key: str, backend: str, predicted_gpx: float | None,

@@ -52,14 +52,20 @@ semaphore: no remote copy is issued until every RDMA partner has entered
 the current invocation (tests/test_rdma.py::test_rdma_back_to_back_race
 runs the multi-invocation protocol under the race detector).
 
-STATUS: functionally validated — bit-exact against the oracle on the
-multi-device CPU mesh under TPU interpret mode (which simulates remote
-DMAs, semaphores, and the barrier).  For a multi-chip v5e mesh only the
-tiled variant compiles (operand-backed pad, ``pad_operand``; AOT-checked
-in tests/test_tpu_compile.py): Mosaic refuses the monolithic kernel's
-1-row VMEM ghost slices, which are not (8, 128)-tile aligned.  The
-four-chip 2×2 run of ``chip_smoke.py --chips 4`` (CHANGES.md, PR 21) is
-the record of the multi-chip protocol on hardware.
+STATUS: bit-exact against the oracle on the multi-device CPU mesh under
+TPU interpret mode (which simulates remote DMAs, semaphores, and the
+barrier), and on silicon.  On a multi-chip v5e mesh only the tiled
+variant compiles (operand-backed pad, ``pad_operand``; AOT-checked in
+tests/test_tpu_compile.py): Mosaic refuses the monolithic kernel's
+1-row VMEM ghost slices, which are not (8, 128)-tile aligned, and any
+block that is not whole (sublane, 128) tiles (see the alignment note
+below — the step layer pads for it).  On a 2×2 v5e mesh the tiled
+variant runs the benchmark's ``rgb_2520.mesh2x2`` cell: three planes of
+u8 at fuse 16 with a 4-deep tail, on 960×1280 blocks of a 1920×2520
+image (the right-hand blocks hold 40 columns of rim), byte-identical to
+the plain reference on every run; bf16 at fuse 8 and 16 and u8 at fuse
+32 ran correct in the same sweep, and ``chip_smoke.py --chips 4`` runs
+one grey f32 level (CHANGES.md records each chip run).
 
 VMEM budget: the monolithic kernel holds the whole (C, h+2r, w+2r) f32
 padded block plus the (C, h, w) output in VMEM (~16 MB limit ≈ 1400²
@@ -74,6 +80,7 @@ corner/periodic/radius-2 suite).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 
 import jax
@@ -86,7 +93,7 @@ from parallel_convolution_tpu.ops.collective_ids import collective_id
 from parallel_convolution_tpu.ops.filters import Filter
 from parallel_convolution_tpu.parallel import channels
 from parallel_convolution_tpu.ops.pallas_stencil import (
-    DEFAULT_TILE, _from_f32, _iterate_levels, _prefetch_window,
+    DEFAULT_TILE, KERNEL_IO, _from_f32, _iterate_levels, _prefetch_window,
     _round_mode_for, _round_up, _sublane, _to_f32, on_tpu, tap_form,
 )
 
@@ -574,20 +581,15 @@ def _rdma_kernel(in_ref, out_ref, pad, send_sem, recv_sem, *scr, plan,
 # VMEM per program: 2 window slots of (th + 2·sub_v, tw + 256) storage
 # dtype — ~1.7 MB at the 256×512 f32 default, independent of block size.
 #
-# Honesty note on alignment coverage: the scheme is FULLY aligned
-# (every start and every extent) precisely when the block shape itself
-# is (sub_v, 128)-aligned — then the h/w-derived starts (row h, h+sub_v;
-# col w, w+LANE) and the orthogonal extents (h, w) are all multiples.
-# For non-multiple blocks, both those starts and extents are raw h/w,
-# and whether real Mosaic constrains HBM↔HBM copies that way cannot be
-# validated in this environment (the tiled path's multi-chip form only
-# runs under the interpreter; single-chip silicon runs the degenerate
-# no-exchange form — same standing caveat as the monolithic STATUS).
-# If silicon rejects raw-h/w transfers, the fix is at the CALLER: pad
-# the global image so blocks are (sub_v, 128)-multiples — the framework
-# already pads to mesh multiples (`parallel/step._prepare`) and the
-# valid-box mask here already ignores rim, so widening that padding is
-# a one-line change with no kernel edits.
+# Alignment: the scheme is fully aligned (every start and every extent)
+# exactly when the block shape itself is (sub_v, 128)-aligned — then the
+# h/w-derived starts (row h, h+sub_v; col w, w+LANE) and the orthogonal
+# extents (h, w) are all multiples.  Mosaic refuses anything else ("Slice
+# shape along dimension 2 must be aligned to tiling (128), but is 1260"
+# for BASELINE config 2's 960x1260 blocks), so the caller pads: the form
+# is registered ``tile_aligned_blocks`` (``parallel.kernels``) and
+# ``parallel.step.block_extent`` rounds every block up to whole tiles;
+# the valid-box mask keeps that rim out of the result.
 
 _TILED_VMEM_BYTES = 10 * 2**20  # monolithic-kernel budget before auto-tiling
 
@@ -1015,6 +1017,102 @@ def _rdma_tiled_kernel(in_ref, out_ref, pad, *rest, plan, form, k, r,
     out_ref[0] = _from_f32(acc, out_ref.dtype)
 
 
+@dataclasses.dataclass(frozen=True)
+class Launch:
+    """What :func:`fused_rdma_step` compiles for one (C, h, w) block: the
+    kernel form, its bound channel plan and the pad buffer's extent
+    (the tiled form's output tile ``th``/``tw`` and sublane ``sub_v``;
+    0 on the monolithic form)."""
+
+    kernel: str                      # "tiled" | "monolithic"
+    plan: channels.ChannelPlan
+    planes: int
+    pad_hw: tuple[int, int]
+    wire_itemsize: int               # bytes per element of the pad buffer
+    th: int = 0
+    tw: int = 0
+    sub_v: int = 0
+
+    @property
+    def wire_bytes(self) -> int:
+        """Bytes the kernel's slab copies move over the mesh in one
+        exchange: whole aligned bands on the tiled form, not the r*fuse
+        ghosts inside them."""
+        return self.plan.wire_bytes(self.planes, self.wire_itemsize,
+                                    self.pad_hw[0])
+
+
+def launch_plan(shape, dtype, out_dtype, radius: int, fuse: int, grid,
+                boundary: str = "zero", col_mode: str = "strided",
+                tiled: bool | None = None, tile=None) -> Launch:
+    """The kernel form, channel plan and pad geometry of one launch.
+
+    ``tiled=None`` auto-selects: blocks whose monolithic VMEM footprint
+    (f32 padded buffer + output) exceeds ``_TILED_VMEM_BYTES`` take the
+    tiled kernel.  Raises where the tiled kernel cannot carry the ghost
+    depth or the block is narrower than one band."""
+    C, h, w = (int(v) for v in shape)
+    d = int(radius) * int(fuse)
+    sub_v = _sublane(dtype)
+    if tiled is None:
+        mono_bytes = (C * (h + 2 * d) * (w + 2 * d) * 4
+                      + C * h * w * jnp.dtype(out_dtype).itemsize)
+        if col_mode == "packed" and grid[1] > 1:
+            # The packed transport's 4 f32 staging slots live in VMEM
+            # for the monolithic kernel — they count against the same
+            # budget (mirrored in costmodel.rdma_is_tiled).
+            mono_bytes += 4 * C * (h + 2 * d) * d * 4
+        tiled = mono_bytes > _TILED_VMEM_BYTES
+        if tiled and (d > min(sub_v, 128) or h < sub_v or w < 128):
+            # Silently falling back to the monolithic kernel here would
+            # trade this clear error for an opaque Mosaic VMEM failure.
+            raise ValueError(
+                f"block {(C, h, w)} needs ~{mono_bytes >> 20} MB of VMEM "
+                f"(over the {_TILED_VMEM_BYTES >> 20} MB monolithic "
+                f"budget) but the tiled kernel requires ghost depth "
+                f"r*fuse <= {min(sub_v, 128)} (got {d}) and blocks >= "
+                f"({sub_v}, 128); use a finer or differently-shaped mesh, "
+                "or a shallower fuse")
+    # The persistent channel plan: descriptor geometry bound ONCE per
+    # exchange identity (parallel.channels) and fetched from the
+    # process-global cache by every trace that shares it — fused
+    # iteration chunks, converge chunks, multigrid V-cycle levels.
+    plan = channels.plan_for(channels.ChannelKey(
+        grid=(int(grid[0]), int(grid[1])), block_hw=(h, w),
+        radius=int(radius), fuse=int(fuse),
+        dtype=str(jnp.dtype(dtype).name), boundary=boundary,
+        kernel="tiled" if tiled else "monolithic", col_mode=col_mode))
+    if not tiled:
+        return Launch("monolithic", plan, C, (h + 2 * d, w + 2 * d), 4)
+    if d > min(sub_v, 128):
+        raise ValueError(
+            f"tiled RDMA kernel needs ghost depth r*fuse <= "
+            f"{min(sub_v, 128)} (the aligned transfer bands are one "
+            f"({sub_v}, 128) tile deep and their trailing/leading r*fuse "
+            f"rows/cols must all be live ghosts), got r*fuse = {d}")
+    if h < sub_v or w < 128:
+        # A band narrower than the block would make src/dst of the band
+        # copies overlap (undefined for real DMA engines even though the
+        # interpreter's atomic copies happen to produce the right bytes).
+        raise ValueError(
+            f"tiled RDMA kernel needs blocks >= ({sub_v}, 128) for "
+            f"non-overlapping band transfers, got {(h, w)}; blocks this "
+            "small fit the monolithic kernel (tiled=False) unless the "
+            "other dimension is huge — then reshape the mesh")
+    LANE = 128
+    t0, t1 = tile if tile is not None else DEFAULT_TILE
+    th = min(_round_up(t0, sub_v), _round_up(h, sub_v))
+    tw = min(_round_up(t1, LANE), _round_up(w, LANE))
+    # Pad buffer: interior at (sub_v, LANE); sized so the LAST window
+    # [(gh-1)·th, +th+2·sub_v) fits — any rim beyond the ghost ring is
+    # never valid (masked) and never sent (transfers address interior/
+    # ghost coordinates only).
+    h_pad = -(-h // th) * th + 2 * sub_v
+    w_pad = -(-w // tw) * tw + 2 * LANE
+    return Launch("tiled", plan, C, (h_pad, w_pad),
+                  jnp.dtype(dtype).itemsize, th, tw, sub_v)
+
+
 @functools.partial(
     jax.jit,
     static_argnames=("filt", "grid", "boundary", "quantize", "out_dtype",
@@ -1164,43 +1262,16 @@ def fused_rdma_step(
         has_side_effects=True,
     )
 
-    sub_v = _sublane(block.dtype)
-    if tiled is None:
-        mono_bytes = (C * (h + 2 * d) * (w + 2 * d) * 4
-                      + C * h * w * jnp.dtype(out_dtype).itemsize)
-        if col_mode == "packed" and grid[1] > 1:
-            # The packed transport's 4 f32 staging slots live in VMEM
-            # for the monolithic kernel — they count against the same
-            # budget (mirrored in costmodel.rdma_is_tiled).
-            mono_bytes += 4 * C * (h + 2 * d) * d * 4
-        tiled = mono_bytes > _TILED_VMEM_BYTES
-        if tiled and (d > min(sub_v, 128) or h < sub_v or w < 128):
-            # Silently falling back to the monolithic kernel here would
-            # trade this clear error for an opaque Mosaic VMEM failure.
-            raise ValueError(
-                f"block {(C, h, w)} needs ~{mono_bytes >> 20} MB of VMEM "
-                f"(over the {_TILED_VMEM_BYTES >> 20} MB monolithic "
-                f"budget) but the tiled kernel requires ghost depth "
-                f"r*fuse <= {min(sub_v, 128)} (got {d}) and blocks >= "
-                f"({sub_v}, 128); use a finer or differently-shaped mesh, "
-                "or a shallower fuse")
-
+    geo = launch_plan((C, h, w), block.dtype, out_dtype, r, T, grid,
+                      boundary, col_mode, tiled, tile)
+    plan = geo.plan
     # interpret here is False (silicon) or InterpretParams — the barrier
     # form is needed exactly when XLA (not Mosaic) executes the kernel.
     # round_mode is dead when not quantizing: skip the selector (and the
     # compiled-probe guard it consults on silicon) entirely.
     round_mode = (_round_mode_for(taps, interpret is not False)
                   if quantize else "rint")
-    # The persistent channel plan: descriptor geometry bound ONCE per
-    # exchange identity (parallel.channels) and fetched from the
-    # process-global cache by every trace that shares it — fused
-    # iteration chunks, converge chunks, multigrid V-cycle levels.
-    ckey = channels.ChannelKey(
-        grid=(int(grid[0]), int(grid[1])), block_hw=(h, w), radius=r,
-        fuse=T, dtype=str(jnp.dtype(block.dtype).name), boundary=boundary,
-        kernel="tiled" if tiled else "monolithic", col_mode=col_mode)
-    plan = channels.plan_for(ckey)
-    if not tiled:
+    if geo.kernel == "monolithic":
         kernel = functools.partial(
             _rdma_kernel, plan=plan, form=form, k=k, r=r, T=T,
             C=C, h=h, w=w, R=grid[0], Cc=grid[1], periodic=periodic,
@@ -1227,33 +1298,11 @@ def fused_rdma_step(
         )(block)
 
     # ---- tiled variant ----
-    if d > min(sub_v, 128):
-        raise ValueError(
-            f"tiled RDMA kernel needs ghost depth r*fuse <= "
-            f"{min(sub_v, 128)} (the aligned transfer bands are one "
-            f"({sub_v}, 128) tile deep and their trailing/leading r*fuse "
-            f"rows/cols must all be live ghosts), got r*fuse = {d}")
-    if h < sub_v or w < 128:
-        # A band narrower than the block would make src/dst of the band
-        # copies overlap (undefined for real DMA engines even though the
-        # interpreter's atomic copies happen to produce the right bytes).
-        raise ValueError(
-            f"tiled RDMA kernel needs blocks >= ({sub_v}, 128) for "
-            f"non-overlapping band transfers, got {(h, w)}; blocks this "
-            "small fit the monolithic kernel (tiled=False) unless the "
-            "other dimension is huge — then reshape the mesh")
     LANE = 128
-    t0, t1 = tile if tile is not None else DEFAULT_TILE
-    th = min(_round_up(t0, sub_v), _round_up(h, sub_v))
-    tw = min(_round_up(t1, LANE), _round_up(w, LANE))
+    sub_v, th, tw = geo.sub_v, geo.th, geo.tw
     gh, gw = -(-h // th), -(-w // tw)
     ext_h, ext_w = th + 2 * sub_v, tw + 2 * LANE
-    # Pad buffer: interior at (sub_v, LANE); sized so the LAST window
-    # [gh-1·th, +ext_h) fits — any rim beyond the ghost ring is never
-    # valid (masked) and never sent (transfers address interior/ghost
-    # coordinates only).
-    h_pad = max((gh - 1) * th + ext_h, h + 2 * sub_v)
-    w_pad = max((gw - 1) * tw + ext_w, w + 2 * LANE)
+    h_pad, w_pad = geo.pad_hw
 
     kernel = functools.partial(
         _rdma_tiled_kernel, plan=plan, form=form, k=k, r=r, T=T,
@@ -1320,7 +1369,8 @@ def fused_rdma_step(
             compiler_params=cparams,
             interpret=interpret,
         )(block)[0]
-        return out[:, :h, :w]
+        with jax.named_scope(KERNEL_IO):   # the crop of the whole tiles
+            return out[:, :h, :w]
     hbm = [pltpu.MemorySpace.HBM((C, h_pad, w_pad), block.dtype)]
     if plan.packed_cols:
         hbm.append(pltpu.MemorySpace.HBM(stage_shape, block.dtype))
@@ -1335,4 +1385,5 @@ def fused_rdma_step(
         compiler_params=cparams,
         interpret=interpret,
     )(block)
-    return out[:, :h, :w]
+    with jax.named_scope(KERNEL_IO):
+        return out[:, :h, :w]

@@ -149,6 +149,23 @@ class ChannelPlan:
                 return s
         return None
 
+    def wire_bytes(self, planes: int, itemsize: int, full_rows: int) -> int:
+        """Bytes this plan's slab copies move over the whole mesh in one
+        exchange: each slab's source rectangle (``full_rows`` where it
+        runs the padded height) times its senders — every device with a
+        partner on that side, in every group of the other axis."""
+        (R, C) = self.key.grid
+        periodic = self.key.boundary == "periodic"
+        total = 0
+        for s in self.slabs():
+            n, groups = (R, C) if s in self.row_slabs else (C, R)
+            senders = (n if periodic else n - 1) * groups
+            rows = (full_rows if s.src_rows is None
+                    else s.src_rows[1] - s.src_rows[0])
+            total += (planes * rows * (s.src_cols[1] - s.src_cols[0])
+                      * itemsize * senders)
+        return total
+
 
 def _monolithic_slabs(key: ChannelKey):
     """Slab geometry of the all-VMEM kernel: ghost depth d = radius*fuse,

@@ -29,7 +29,10 @@ carry that scope in the compiled executable (``step.op_scopes``).  While
 a :func:`counting` block is open, tracing an exchange tallies what it
 built — collective rounds, and each ppermute's slab bytes times the
 pairs of its permutation across the whole mesh — into a
-:class:`HaloCount` (the iterate runner keeps one per traced shape).
+:class:`HaloCount` (the iterate runner keeps one per traced shape).  A
+kernel that exchanges by remote DMA counts the ghosts the same way
+(:func:`count_remote_exchange`) and, apart, the whole bands its copies
+move (:func:`count_wire`).
 """
 
 from __future__ import annotations
@@ -55,11 +58,14 @@ _DIRECTION = {("x", True): "south", ("x", False): "north",
 @dataclasses.dataclass
 class HaloCount:
     """What traced halo exchanges built, summed over the whole mesh:
-    rounds (exchanges that sent anything) and bytes per direction."""
+    rounds (exchanges that sent anything), ghost bytes per direction,
+    and ``wire_bytes``, what remote-DMA kernels' copies move (None where
+    no kernel exchanged by remote DMA)."""
 
     rounds: int = 0
     bytes: dict = dataclasses.field(
         default_factory=lambda: dict.fromkeys(DIRECTIONS, 0))
+    wire_bytes: int | None = None
 
     @property
     def total_bytes(self) -> int:
@@ -67,12 +73,16 @@ class HaloCount:
 
     def __mul__(self, k: int) -> "HaloCount":
         return HaloCount(self.rounds * k,
-                         {d: b * k for d, b in self.bytes.items()})
+                         {d: b * k for d, b in self.bytes.items()},
+                         None if self.wire_bytes is None
+                         else self.wire_bytes * k)
 
     def __add__(self, other: "HaloCount") -> "HaloCount":
+        wire = (None if self.wire_bytes is None and other.wire_bytes is None
+                else (self.wire_bytes or 0) + (other.wire_bytes or 0))
         return HaloCount(self.rounds + other.rounds,
                          {d: b + other.bytes[d]
-                          for d, b in self.bytes.items()})
+                          for d, b in self.bytes.items()}, wire)
 
 
 _TALLY = threading.local()
@@ -120,6 +130,15 @@ def count_remote_exchange(block, r: int, grid: tuple[int, int],
         for down in (True, False):
             count.bytes[_DIRECTION[axis, down]] += slab * pairs * groups
     count.rounds += count.total_bytes > sent
+
+
+def count_wire(nbytes: int) -> None:
+    """Tally what one remote-DMA exchange's copies move over the mesh
+    (``pallas_rdma.Launch.wire_bytes``): whole aligned bands, where
+    :func:`count_remote_exchange` counts the ghosts inside them."""
+    count = getattr(_TALLY, "count", None)
+    if count is not None:
+        count.wire_bytes = (count.wire_bytes or 0) + int(nbytes)
 
 
 def _shift(x: jnp.ndarray, axis_name: str, n: int, down: bool,

@@ -47,8 +47,9 @@ from __future__ import annotations
 import dataclasses
 from typing import Callable
 
-__all__ = ["KernelForm", "clamp_overlap", "overlap_capable",
-           "persistent_capable", "register", "registered_keys", "resolve"]
+__all__ = ["KernelForm", "block_multiple", "clamp_overlap",
+           "overlap_capable", "persistent_capable", "register",
+           "registered_keys", "resolve"]
 
 # The stencil-form vocabulary (closed: dispatch code switches on it).
 # "physics" (round 23) classes the time-dependent rank-3 forms (wave,
@@ -81,6 +82,11 @@ class KernelForm:
     # place that knowledge lives (round 16) — the cost model's zeroed
     # setup term and the col_mode resolution both key off it.
     persistent_capable: bool = False
+    # Blocks aligned to the storage dtype's HBM tiling, (sublane, 128):
+    # the form moves whole tiles by DMA, and Mosaic refuses a slice of
+    # any other shape.  The dispatch layer pads the image so each
+    # device's block is such a multiple (:func:`block_multiple`).
+    tile_aligned_blocks: bool = False
     build: Callable | None = None
 
     def __post_init__(self) -> None:
@@ -113,6 +119,7 @@ def register(form: KernelForm) -> KernelForm:
                 old.stencil_form != form.stencil_form
                 or old.overlap_capable != form.overlap_capable
                 or old.persistent_capable != form.persistent_capable
+                or old.tile_aligned_blocks != form.tile_aligned_blocks
                 or old.boundaries != form.boundaries
                 or _build_id(old.build) != _build_id(form.build)):
             raise ValueError(
@@ -195,6 +202,21 @@ def persistent_capable(name: str, rank: int = 2) -> bool:
         if form is not None:
             return form.persistent_capable
     return False
+
+
+def block_multiple(name: str, storage: str, rank: int = 2) -> tuple[int, int]:
+    """The (rows, cols) multiple a per-device block of ``name`` must be
+    at ``storage``: the dtype's (sublane, 128) tiling for a form
+    registered ``tile_aligned_blocks``, else (1, 1).  Unknown names
+    (``auto`` before resolution) need no alignment."""
+    from parallel_convolution_tpu.tuning.costmodel import LANE, SUBLANE
+
+    _ensure_default_forms()
+    for bd in ("zero", "periodic"):
+        form = _FORMS.get((int(rank), str(name), bd))
+        if form is not None and form.tile_aligned_blocks:
+            return SUBLANE[storage], LANE
+    return 1, 1
 
 
 def clamp_overlap(overlap, name: str, rank: int = 2) -> bool:

@@ -16,9 +16,10 @@ The functional loop carry is the double buffer; donated input storage gives
 XLA the reference's pointer swap for free.
 
 Non-divisible images (e.g. 2520 over a 4-high grid) are padded to the next
-block multiple and re-masked to zero every iteration, which keeps the pad
-region behaving exactly like the oracle's zero ghost ring — outputs stay
-bit-identical to the serial oracle for any mesh shape.
+block multiple — and, for a kernel form that moves whole (sublane, 128)
+tiles, to blocks of whole tiles — and re-masked to zero every iteration,
+which keeps the pad region behaving exactly like the oracle's zero ghost
+ring: outputs stay bit-identical to the serial oracle for any mesh shape.
 """
 
 from __future__ import annotations
@@ -54,8 +55,9 @@ from parallel_convolution_tpu.utils.config import (  # canonical registries
 )
 from jax import shard_map
 
-__all__ = ["BACKENDS", "STORAGE_DTYPES", "sharded_iterate", "sharded_converge",
-           "sharded_converge_stream", "iterate_prepared", "reshard_prepared",
+__all__ = ["BACKENDS", "STORAGE_DTYPES", "block_extent", "sharded_iterate",
+           "sharded_converge", "sharded_converge_stream", "iterate_prepared",
+           "reshard_prepared",
            "resolve_overlap", "resolve_col_mode", "clamp_col_mode",
            "iterate_text", "op_scopes"]
 
@@ -282,10 +284,18 @@ def _build_rdma_step(filt: Filter, grid, valid_hw, block_hw, quantize: bool,
     periodic, needs_mask = _boundary_geometry(grid, valid_hw, block_hw,
                                               boundary)
 
+    def launch(shape, dtype):
+        from parallel_convolution_tpu.ops import pallas_rdma
+
+        return pallas_rdma.launch_plan(shape, dtype, dtype, filt.radius,
+                                       fuse, grid, boundary, col_mode,
+                                       tile=tile)
+
     def step(v):
         from parallel_convolution_tpu.ops import pallas_rdma
 
         halo.count_remote_exchange(v, filt.radius * fuse, grid, boundary)
+        halo.count_wire(launch(v.shape, v.dtype).wire_bytes)
         p = pallas_rdma.fused_rdma_step(
             v, filt, grid, boundary, quantize=quantize,
             out_dtype=v.dtype, tile=tile, interpret=interpret,
@@ -296,6 +306,14 @@ def _build_rdma_step(filt: Filter, grid, valid_hw, block_hw, quantize: bool,
             p = p * _valid_mask(valid_hw, block_hw).astype(p.dtype)
         return p
 
+    @lru_cache(maxsize=None)
+    def launch_args(planes: int, dtype) -> dict:
+        """What the build span says of this launch (one entry per block)."""
+        return {"rdma_kernel": launch((planes, *block_hw), dtype).kernel,
+                "col_mode": col_mode, "overlap": int(bool(overlap)),
+                "block": [int(b) for b in block_hw]}
+
+    step.launch_args = launch_args
     return step
 
 
@@ -472,7 +490,10 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     The runner keeps what its halo exchanges built: ``halo_counts`` maps
     each traced per-device block (shape, dtype) to the
     :class:`halo.HaloCount` of one whole call (every chunk and the tail).
-    ``tap_form`` names the stencil's tap arithmetic (:func:`_tap_form`).
+    ``tap_form`` names the stencil's tap arithmetic (:func:`_tap_form`);
+    ``launch_args(planes, dtype)``, on a form that describes its launch
+    (``pallas_rdma``: kernel, column transport, overlap, block), what the
+    build span says of a full chunk, else None.
     """
     # Consulted only on lru_cache misses — i.e. exactly when a fresh
     # trace/compile happens, the event the 'backend_compile' site models.
@@ -489,6 +510,7 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     chunk = _make_block_step(filt, grid, valid_hw, block_hw, quantize,
                              backend, fuse, boundary, tile, interp,
                              interior_split, overlap, col_mode)
+    launch_args = getattr(chunk, "launch_args", None)
     n_chunks, rem = divmod(iters, fuse)
     tail = (_make_block_step(filt, grid, valid_hw, block_hw, quantize,
                              backend, rem, boundary, tile, interp,
@@ -527,6 +549,7 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     runner = jax.jit(sharded, donate_argnums=0)
     runner.halo_counts = halo_counts
     runner.tap_form = _tap_form(backend, filt, quantize)
+    runner.launch_args = launch_args
     return runner
 
 
@@ -734,6 +757,7 @@ def _register_smoother_forms() -> None:
             boundaries=tuple(BOUNDARIES),
             overlap_capable=(name == "pallas_rdma"),
             persistent_capable=(name == "pallas_rdma"),
+            tile_aligned_blocks=(name == "pallas_rdma"),
             build=(_build_rdma_step if name == "pallas_rdma"
                    else partial(_build_halo_step, name))))
 
@@ -784,16 +808,34 @@ def _check_storage(storage: str, quantize: bool) -> None:
         )
 
 
-def _prepare(x, mesh: Mesh, r: int, storage: str = "f32"):
-    """Pad a global (C, H, W) image to block multiples and shard it."""
+def block_extent(hw, grid, backend: str = "shifted", storage: str = "f32",
+                 boundary: str = "zero") -> tuple[int, int]:
+    """The per-device block of an (H, W) image on a ``grid``: the mesh's
+    share, rounded up to the multiple the backend's kernel form needs
+    (``kernels.block_multiple``; a zero boundary only — padding a torus
+    would move its wrap).  The rim beyond the image is masked, so the
+    bytes are the same on any block."""
+    R, Cc = grid
+    br, bc = -(-int(hw[0]) // R), -(-int(hw[1]) // Cc)
+    if boundary != "zero":
+        return br, bc
+    ar, ac = kernel_forms.block_multiple(backend, storage)
+    return padded_extent(br, ar), padded_extent(bc, ac)
+
+
+def _prepare(x, mesh: Mesh, r: int, storage: str = "f32",
+             backend: str = "shifted", boundary: str = "zero"):
+    """Pad a global (C, H, W) image to whole blocks (:func:`block_extent`)
+    and shard it."""
     x = jnp.asarray(x, STORAGE_DTYPES[storage])
     C, H, W = x.shape
     R, Cc = grid_shape(mesh)
-    Hp, Wp = padded_extent(H, R), padded_extent(W, Cc)
+    bh, bw = block_extent((H, W), (R, Cc), backend, storage, boundary)
+    Hp, Wp = bh * R, bw * Cc
     if (Hp, Wp) != (H, W):
         x = jnp.pad(x, ((0, 0), (0, Hp - H), (0, Wp - W)))
     x = jax.device_put(x, block_sharding(mesh))
-    return x, (H, W), (Hp // R, Wp // Cc)
+    return x, (H, W), (bh, bw)
 
 
 def reshard_prepared(xs, valid_hw, mesh: Mesh):
@@ -958,6 +1000,8 @@ def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
             _storage_name(xs.dtype), backend, fuse, boundary, tile,
             interior_split, fallback, overlap, col_mode)
         sp.set(tap_form=fn.tap_form)
+        if fn.launch_args is not None and obs_metrics.enabled():
+            sp.set(**fn.launch_args(xs.shape[0], block[1]))
         if block not in fn.halo_counts:
             sp.set(compiled=1)  # the dispatch below traces and compiles
     # The dispatch stays asynchronous: no fence here (callers overlap the
@@ -969,10 +1013,13 @@ def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
         if obs_metrics.enabled():
             count = fn.halo_counts[block]
             sp.set(exchanges=count.rounds, halo_bytes=count.total_bytes)
+            if count.wire_bytes is not None:
+                sp.set(rdma_wire_bytes=count.wire_bytes)
     if obs_metrics.enabled():
         from parallel_convolution_tpu.obs import attribution
 
-        attribution.record_halo(backend, count.bytes, count.rounds, iters)
+        attribution.record_halo(backend, count.bytes, count.rounds, iters,
+                                count.wire_bytes)
     return out
 
 
@@ -1019,12 +1066,12 @@ def iterate_text(shape, filt: Filter, iters: int, mesh: Mesh | None = None,
         mesh = make_grid_mesh()
     C, H, W = (int(v) for v in shape)
     R, Cc = grid_shape(mesh)
-    Hp, Wp = padded_extent(H, R), padded_extent(W, Cc)
+    bh, bw = block_extent((H, W), (R, Cc), backend, storage, boundary)
     fn, _ = _iterate_runner(
-        mesh, filt, iters, quantize, (H, W), (Hp // R, Wp // Cc), C, storage,
+        mesh, filt, iters, quantize, (H, W), (bh, bw), C, storage,
         backend, fuse, boundary, tile, interior_split, fallback, overlap,
         col_mode)
-    arg = jax.ShapeDtypeStruct((C, Hp, Wp), STORAGE_DTYPES[storage],
+    arg = jax.ShapeDtypeStruct((C, bh * R, bw * Cc), STORAGE_DTYPES[storage],
                                sharding=block_sharding(mesh))
     return fn.lower(arg).compile().as_text()
 
@@ -1107,7 +1154,8 @@ def sharded_iterate(x, filt: Filter, iters: int, mesh: Mesh | None = None,
         mesh = make_grid_mesh()
     _check_storage(storage, quantize)
     with obs_trace.span("pctpu.step.prepare"):
-        xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage)
+        xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage,
+                                          backend, boundary)
     out = iterate_prepared(xs, filt, iters, mesh, valid_hw,
                            quantize=quantize, backend=backend, fuse=fuse,
                            boundary=boundary, tile=tile,
@@ -1157,7 +1205,8 @@ def sharded_converge(x, filt: Filter, tol: float, max_iters: int,
     if mesh is None:
         mesh = make_grid_mesh()
     _check_storage(storage, quantize)
-    xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage)
+    xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage,
+                                      backend, boundary)
     backend, fuse, tile, overlap, col_mode, _ = _resolve_auto(
         mesh, filt, backend, fuse, tile, storage, quantize, boundary,
         tuple(valid_hw), xs.shape[0], check_every=int(check_every),
@@ -1250,7 +1299,8 @@ def sharded_converge_stream(x, filt: Filter, tol: float, max_iters: int,
     if mesh is None:
         mesh = make_grid_mesh()
     _check_storage(storage, quantize)
-    xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage)
+    xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage,
+                                      backend, boundary)
     backend, fuse, tile, overlap, col_mode, _ = _resolve_auto(
         mesh, filt, backend, fuse, tile, storage, quantize, boundary,
         tuple(valid_hw), xs.shape[0], check_every=int(check_every),

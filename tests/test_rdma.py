@@ -493,3 +493,42 @@ def test_rdma_fused_needs_valid_hw():
         pallas_rdma.fused_rdma_step(jnp.zeros((1, 32, 32), jnp.float32),
                                     filters.get_filter("blur3"), (2, 2),
                                     fuse=2)
+
+
+@pytest.mark.parametrize("storage,fuse", [("bf16", 4), ("u8", 8)])
+def test_rdma_run_planar_pads_unaligned_rgb_blocks(storage, fuse):
+    """The normal path on a 2x2 mesh whose 32x140 RGB blocks are not whole
+    (sublane, 128) tiles: the step pads each block to 32x256, the valid
+    box keeps the rim out, and the bytes are the oracle's."""
+    from parallel_convolution_tpu.models import ConvolutionModel
+
+    img = np.random.default_rng(25).integers(0, 256, (64, 280, 3),
+                                             dtype=np.uint8)
+    filt = filters.get_filter("blur3")
+    want = oracle.run_serial_u8(img, filt, 10)
+    x = imageio.interleaved_to_planar(img).astype(np.float32)
+    model = ConvolutionModel(filt, mesh=_mesh((2, 2)), backend="pallas_rdma",
+                             storage=storage, fuse=fuse, quantize=True)
+    out = model.run_planar(x, 10)
+    assert out.shape == (3, 64, 280)
+    got = imageio.planar_to_interleaved(np.asarray(out).astype(np.uint8))
+    np.testing.assert_array_equal(got, want)
+
+
+@pytest.mark.parametrize("storage", ["f32", "bf16", "u8"])
+def test_prepare_aligns_only_the_rdma_block(storage):
+    """Only the form that moves whole tiles by DMA gets tile-aligned
+    blocks; every other form keeps the mesh's own share."""
+    x = np.zeros((3, 64, 280), np.float32)
+    m = _mesh((2, 2))
+    sub_v = {"f32": 8, "bf16": 16, "u8": 32}[storage]
+    for backend in ("pallas_sep", "pallas", "shifted"):
+        xs, valid, block = step._prepare(x, m, 1, storage, backend)
+        assert block == (32, 140) and xs.shape == (3, 64, 280)
+    xs, valid, block = step._prepare(x, m, 1, storage, "pallas_rdma")
+    assert valid == (64, 280)
+    assert block == (-(-32 // sub_v) * sub_v, 256)
+    assert xs.shape == (3, 2 * block[0], 512)
+    # A torus keeps its wrap: no padding beyond the mesh's share.
+    assert step.block_extent((64, 280), (2, 2), "pallas_rdma", storage,
+                             "periodic") == (32, 140)

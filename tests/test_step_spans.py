@@ -130,12 +130,15 @@ def test_iterate_prepared_counts_what_the_runner_built():
 
 def test_rdma_counts_the_bands_it_moves_in_kernel():
     """pallas_rdma exchanges by remote DMA inside the kernel, with no
-    ppermute: its runner counts the same ghost bands."""
+    ppermute: its runner counts the same ghost bands, on its block of
+    whole (8, 128) f32 tiles."""
     metrics.reset()
     grid = (2, 2)
     _model(grid, "zero", backend="pallas_rdma").run_planar(
         np.ones(SHAPE, np.float32), ITERS).block_until_ready()
-    want = attribution.halo_bytes_total(grid, (8, 12), 1, FUSE, ITERS, 1,
+    block = step.block_extent(SHAPE[1:], grid, "pallas_rdma", "f32")
+    assert block == (8, 128)
+    want = attribution.halo_bytes_total(grid, block, 1, FUSE, ITERS, 1,
                                         "f32")
     hb = metrics.counter("pctpu_halo_bytes_total", "",
                          ("backend", "direction"))
@@ -143,6 +146,81 @@ def test_rdma_counts_the_bands_it_moves_in_kernel():
         assert hb.value(backend="pallas_rdma", direction=d) == want[d]
     assert metrics.counter("pctpu_halo_rounds_total", "", ("backend",)
                            ).value(backend="pallas_rdma") == want["rounds"]
+
+
+def _mono_wire(planes, h, w, d, itemsize=4):
+    """One monolithic-kernel exchange on a 2x2 zero-boundary mesh: each
+    of 2 row channels has 2 senders (one per mesh column) moving a d x w
+    slab, each column channel 2 senders moving a (h + 2d) x d slab, all
+    from the f32 VMEM pad."""
+    return 2 * 2 * planes * itemsize * (d * w + (h + 2 * d) * d)
+
+
+def test_rdma_spans_describe_the_launch_and_its_wire_bytes(tmp_path):
+    """On the RDMA form the build span names the kernel, the column
+    transport, overlap and the aligned block; the dispatch span carries
+    the wire bytes of the plan's slabs beside the ghost bytes."""
+    metrics.reset()
+    grid, shape = (2, 2), (3, 16, 24)
+    m = _model(grid, "zero", backend="pallas_rdma", storage="bf16")
+    x = np.full(shape, 7, np.float32)
+    m.run_planar(x, ITERS).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        m.run_planar(x, ITERS).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    spans = {s[2]: s[3] for s in _host_spans(str(tmp_path))}
+    h, w = step.block_extent(shape[1:], grid, "pallas_rdma", "bf16")
+    assert (h, w) == (16, 128)
+    build = spans["pctpu.step.build"]
+    assert build["rdma_kernel"] == "monolithic"
+    assert build["col_mode"] == m.effective_col_mode
+    assert build["overlap"] == 0
+    assert build["block"] == str([h, w])
+    dispatch = spans["pctpu.step.dispatch"]
+    n_chunks, rem = divmod(ITERS, FUSE)
+    want = (n_chunks * _mono_wire(3, h, w, FUSE)
+            + _mono_wire(3, h, w, rem))
+    assert dispatch["rdma_wire_bytes"] == want
+    assert dispatch["halo_bytes"] == attribution.halo_bytes_total(
+        grid, (h, w), 1, FUSE, ITERS, 3, "bf16")["total"]
+    wire = metrics.counter("pctpu_rdma_wire_bytes_total", "", ("backend",))
+    assert wire.value(backend="pallas_rdma") == 2 * want
+
+
+def test_ppermute_dispatch_has_no_wire_bytes_and_the_same_block():
+    """A form that exchanges by ppermute keeps the mesh's own block and
+    reports no remote-DMA bytes."""
+    metrics.reset()
+    assert step.block_extent((16, 24), (2, 2), "pallas_sep", "bf16") == \
+        (8, 12)
+    _model((2, 2), "zero", backend="pallas_sep", storage="bf16").run_planar(
+        np.ones(SHAPE, np.float32), ITERS).block_until_ready()
+    wire = metrics.counter("pctpu_rdma_wire_bytes_total", "", ("backend",))
+    assert wire.value(backend="pallas_sep") == 0
+
+
+@pytest.mark.parametrize("storage,fuse", [("bf16", 16), ("u8", 32)])
+def test_tiled_launch_wire_bytes_are_whole_bands(storage, fuse):
+    """The tiled kernel moves whole (sub_v, 128) bands: rows sub_v deep
+    across the block, columns 128 wide down the padded height."""
+    from parallel_convolution_tpu.ops import pallas_rdma
+
+    dtype = {"bf16": jax.numpy.bfloat16, "u8": jax.numpy.uint8}[storage]
+    C, h, w = 3, 960, 1280
+    launch = pallas_rdma.launch_plan((C, h, w), dtype, dtype, 1, fuse,
+                                     (2, 2), "zero", "packed")
+    sub_v, B = {"bf16": (16, 2), "u8": (32, 1)}[storage]
+    assert launch.kernel == "tiled" and launch.sub_v == sub_v
+    h_pad = -(-h // launch.th) * launch.th + 2 * sub_v
+    assert launch.pad_hw[0] == h_pad
+    rows = 2 * 2 * C * sub_v * w * B
+    cols = 2 * 2 * C * h_pad * 128 * B
+    assert launch.wire_bytes == rows + cols
+    ghosts = attribution.halo_bytes_total((2, 2), (h, w), 1, fuse, fuse, C,
+                                          storage)["total"]
+    assert launch.wire_bytes >= ghosts
 
 
 def test_op_scopes_map_the_halo_and_kernel_io_ops():

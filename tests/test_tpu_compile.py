@@ -20,6 +20,7 @@ from jax.sharding import SingleDeviceSharding
 
 from parallel_convolution_tpu.ops import pallas_rdma, pallas_stencil
 from parallel_convolution_tpu.ops.filters import get_filter, make_filter
+from parallel_convolution_tpu.parallel import step
 from parallel_convolution_tpu.parallel.mesh import AXES, make_grid_mesh
 
 
@@ -133,3 +134,19 @@ def test_rdma_step_on_2x2_mesh(topo, overlap):
     hlo = _compile(jax.shard_map(step, mesh=mesh, in_specs=P(None, *AXES),
                                  out_specs=P(None, *AXES), check_vma=False), x)
     assert hlo.count("tpu_custom_call") >= 1
+
+
+def test_rgb_2520_cell_launch_on_2x2_mesh(topo):
+    # The rgb_2520.mesh2x2 cell's iterate program: BASELINE config 2's
+    # 1920x2520 RGB image on 2x2 at u8, fuse 16, through the step layer's
+    # runner.  The RDMA form pads each 960x1260 block to whole (32, 128)
+    # u8 tiles, 960x1280; Mosaic refuses the 1260-wide band slices.  The
+    # fuse-16 chunk and the 4-deep tail are the program's two kernels;
+    # it has no collective-permute.
+    mesh = make_grid_mesh(topo.devices, (2, 2))
+    text = step.iterate_text((3, 1920, 2520), get_filter("blur3"), 100,
+                             mesh=mesh, backend="pallas_rdma",
+                             storage="u8", fuse=16)
+    assert text.count("tpu_custom_call") == 2
+    assert "collective-permute" not in text
+    assert "u8[3,960,1280]" in text
