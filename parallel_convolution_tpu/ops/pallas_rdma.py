@@ -94,7 +94,8 @@ from parallel_convolution_tpu.ops.filters import Filter
 from parallel_convolution_tpu.parallel import channels
 from parallel_convolution_tpu.ops.pallas_stencil import (
     DEFAULT_TILE, KERNEL_IO, _from_f32, _iterate_levels, _prefetch_window,
-    _round_mode_for, _round_up, _sublane, _to_f32, on_tpu, tap_form,
+    _round_mode_for, _round_up, _sublane, _to_f32, on_tpu, rank1_exact,
+    tap_form,
 )
 
 # Semaphore slots: one (send, recv) pair per direction.
@@ -1253,8 +1254,8 @@ def fused_rdma_step(
     # is valid).
     kern_valid = (None if (T == 1 or periodic)
                   else (int(valid_hw[0]), int(valid_hw[1])))
-    # The rank-1 split saves little at one level: never requested here.
-    form = tap_form(filt, False, quantize)
+    # The rank-1 form wherever its bytes are the normative order's.
+    form = tap_form(filt, rank1_exact(filt, quantize), quantize)
     taps = tuple(float(t) for t in filt.taps.reshape(-1))
     vma = jax.typeof(block).vma
     cparams = pltpu.CompilerParams(

@@ -166,6 +166,24 @@ class TapForm(NamedTuple):
     args: tuple
 
 
+def rank1_exact(filt: Filter, quantize: bool) -> bool:
+    """True where the rank-1 separable form gives the normative 2D
+    order's bytes: quantize mode, a dyadic filter, and an exact rank-1
+    factorization whose factors are integer multiples of 1/256 with
+    255·L1(col)·L1(row)·2¹⁶ < 2²⁴, so every row- and column-pass
+    partial sum is exact in f32 (DESIGN.md "Where a kernel may leave
+    the normative order")."""
+    if not (quantize and filt.dyadic):
+        return False
+    sep = filt.separable()
+    if sep is None:
+        return False
+    col, row = (np.asarray(f, np.float64) * 256.0 for f in sep)
+    if not (np.all(col == np.rint(col)) and np.all(row == np.rint(row))):
+        return False
+    return bool(255.0 * np.abs(col).sum() * np.abs(row).sum() < 2.0**24)
+
+
 def tap_form(filt: Filter, separable: bool, quantize: bool) -> TapForm:
     """The tap form a kernel build runs: the rank-1 form where requested
     and exact, else the constant-plus-sparse form where exact and

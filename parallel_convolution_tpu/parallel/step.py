@@ -415,13 +415,16 @@ def _build_halo_step(backend: str, filt: Filter, grid, valid_hw, block_hw,
 
 def _tap_form(backend: str, filt: Filter, quantize: bool) -> str:
     """The tap form a backend's stencil is built with: for the Pallas
-    kernels ``pallas_stencil.tap_form`` (only ``pallas_sep`` asks for
-    the rank-1 form), for the XLA forms the one each runs."""
+    kernels ``pallas_stencil.tap_form`` (``pallas_sep`` always asks for
+    the rank-1 form, ``pallas_rdma`` where ``rank1_exact`` holds), for
+    the XLA forms the one each runs."""
     if backend in ("pallas", "pallas_sep", "pallas_rdma"):
         from parallel_convolution_tpu.ops import pallas_stencil
 
-        return pallas_stencil.tap_form(filt, backend == "pallas_sep",
-                                       quantize).name
+        sep = backend == "pallas_sep" or (
+            backend == "pallas_rdma"
+            and pallas_stencil.rank1_exact(filt, quantize))
+        return pallas_stencil.tap_form(filt, sep, quantize).name
     if backend == "separable" and filt.separable() is not None:
         return "separable"
     return "direct"

@@ -451,6 +451,59 @@ def test_rdma_fused_degenerate_tiled_u8_radius2():
     np.testing.assert_array_equal(got, want)
 
 
+def _separable_cases():
+    # Tiled: r*fuse must fit one (sublane, 128) band (bf16 16, u8 32).
+    sublane = {"bf16": 16, "u8": 32}
+    for tiled in (False, True):
+        for name, r in (("blur3", 1), ("gaussian5", 2)):
+            for fuse in (1, 4, 16):
+                for storage in ("u8", "bf16"):
+                    if tiled and r * fuse > sublane[storage]:
+                        continue
+                    for boundary in ("zero", "periodic"):
+                        for image in ("noise", "checker"):
+                            yield pytest.param(
+                                tiled, name, fuse, storage, boundary, image,
+                                id=f"{'tiled' if tiled else 'monolithic'}-"
+                                   f"{name}-fuse{fuse}-{storage}-{boundary}-"
+                                   f"{image}")
+
+
+@pytest.mark.parametrize("tiled,name,fuse,storage,boundary,image",
+                         _separable_cases())
+def test_rdma_separable_form_degenerate(tiled, name, fuse, storage,
+                                        boundary, image, monkeypatch):
+    """Rank-1 dyadic filters in quantize mode run the separable form in
+    both RDMA kernels; two chained launches equal the oracle's bytes."""
+    import ml_dtypes
+
+    from parallel_convolution_tpu.ops import pallas_rdma
+
+    forms = []   # the tap form of every level loop the kernels trace
+    run_levels = pallas_rdma._iterate_levels
+
+    def levels(cur, *, form, **kw):
+        forms.append(form.name)
+        return run_levels(cur, form=form, **kw)
+
+    monkeypatch.setattr(pallas_rdma, "_iterate_levels", levels)
+    jax.clear_caches()   # retrace the jitted step, so the spy sees it
+    filt = filters.get_filter(name)
+    if image == "noise":
+        img = np.random.default_rng(fuse).integers(0, 256, (64, 256),
+                                                   dtype=np.uint8)
+    else:
+        img = (255 * ((np.arange(64)[:, None] + np.arange(256)) % 2)).astype(
+            np.uint8)
+    dtype = {"u8": np.uint8, "bf16": ml_dtypes.bfloat16}[storage]
+    got = _run_rdma_tiled(img, filt, 2, (1, 1), tile=(32, 128),
+                          tiled=tiled, boundary=boundary, fuse=fuse,
+                          storage=dtype)
+    want = oracle.run_serial_u8(img, filt, 2 * fuse, boundary=boundary)
+    np.testing.assert_array_equal(got, want)
+    assert forms and set(forms) == {"separable"}
+
+
 # --- Constraint surface: the fuse guards that replaced the old
 # fuse=1-only ValueError.
 

@@ -41,6 +41,13 @@ def _image(kind):
         np.uint8)
 
 
+def _filter(name):
+    # gaussian(3, 1.0): an exact rank-1 factorization with non-dyadic taps.
+    if name == "gaussian3_sigma1":
+        return filters.gaussian(3, 1.0)
+    return filters.get_filter(name)
+
+
 # --------------------------------------------------------------------------
 # The static plan
 # --------------------------------------------------------------------------
@@ -91,13 +98,34 @@ def test_outside_the_exact_regime_the_direct_loop_stays(filt, quantize):
     assert pallas_stencil.tap_form(filt, True, quantize).name == "direct"
 
 
+@pytest.mark.parametrize("filt,quantize,want", [
+    (filters.BLUR3, True, True),
+    (filters.GAUSSIAN5, True, True),
+    (filters.BLUR3, False, False),
+    (filters.gaussian(3, 1.0), True, False),
+    (filters.SHARPEN3, True, False),
+    # Factors [1, 2, 1]/32: on the 1/256 grid, though the taps are not.
+    (Filter("fine", np.outer([1, 2, 1], [1, 2, 1]) / 1024, dyadic=True),
+     True, True),
+    # Factors [8, 16, 8]: past the 2^24 bound on the 1/256 grid.
+    (Filter("steep", np.outer([1, 2, 1], [1, 2, 1]) * 64.0, dyadic=True),
+     True, False),
+], ids=["blur3", "gaussian5", "float_mode", "non_dyadic", "not_rank1",
+        "fine_factors", "over_2^24"])
+def test_rank1_exact(filt, quantize, want):
+    assert pallas_stencil.rank1_exact(filt, quantize) is want
+
+
 @pytest.mark.parametrize("backend,name,quantize,want", [
     ("pallas_sep", "edge5", True, "box_sparse"),
     ("pallas_sep", "blur3", True, "separable"),
     ("pallas", "edge5", True, "box_sparse"),
     ("pallas", "blur3", True, "direct"),
     ("pallas_rdma", "sharpen3", True, "box_sparse"),
-    ("pallas_rdma", "blur3", True, "direct"),
+    ("pallas_rdma", "blur3", True, "separable"),
+    ("pallas_rdma", "gaussian5", True, "separable"),
+    ("pallas_rdma", "blur3", False, "direct"),
+    ("pallas_rdma", "gaussian3_sigma1", True, "direct"),
     ("pallas_sep", "edge5", False, "direct"),
     ("shifted", "edge5", True, "direct"),
     ("separable", "blur3", True, "separable"),
@@ -105,7 +133,7 @@ def test_outside_the_exact_regime_the_direct_loop_stays(filt, quantize):
 ])
 def test_runner_keeps_its_tap_form(backend, name, quantize, want):
     mesh = make_grid_mesh(jax.devices()[:1], (1, 1))
-    fn = step._build_iterate(mesh, filters.get_filter(name), 4, quantize,
+    fn = step._build_iterate(mesh, _filter(name), 4, quantize,
                              (16, 128), (16, 128), backend, 2)
     assert fn.tap_form == want
 
@@ -137,6 +165,25 @@ def test_build_span_names_the_tap_form(tmp_path):
         jax.profiler.stop_trace()
     assert [s["tap_form"] for s in _build_spans(tmp_path)] == [
         "box_sparse", "separable"]
+
+
+def test_rdma_build_span_names_the_separable_form(tmp_path):
+    x = np.random.default_rng(26).integers(0, 256, (1, 32, 128)).astype(
+        np.float32)
+    mesh = make_grid_mesh(jax.devices()[:1], (1, 1))
+    models = [ConvolutionModel(name, mesh=mesh, backend="pallas_rdma",
+                               storage="u8", fuse=2)
+              for name in ("blur3", "sharpen3")]
+    for m in models:
+        m.run_planar(x, 4).block_until_ready()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        for m in models:
+            m.run_planar(x, 4).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    assert [s["tap_form"] for s in _build_spans(tmp_path)] == [
+        "separable", "box_sparse"]
 
 
 # --------------------------------------------------------------------------

@@ -142,11 +142,19 @@ def test_rgb_2520_cell_launch_on_2x2_mesh(topo):
     # runner.  The RDMA form pads each 960x1260 block to whole (32, 128)
     # u8 tiles, 960x1280; Mosaic refuses the 1260-wide band slices.  The
     # fuse-16 chunk and the 4-deep tail are the program's two kernels;
-    # it has no collective-permute.
+    # it has no collective-permute.  Both run blur3 in the rank-1
+    # separable form, so Mosaic takes its two-pass slices at u8 tiling.
     mesh = make_grid_mesh(topo.devices, (2, 2))
-    text = step.iterate_text((3, 1920, 2520), get_filter("blur3"), 100,
+    filt = get_filter("blur3")
+    text = step.iterate_text((3, 1920, 2520), filt, 100,
                              mesh=mesh, backend="pallas_rdma",
                              storage="u8", fuse=16)
     assert text.count("tpu_custom_call") == 2
     assert "collective-permute" not in text
     assert "u8[3,960,1280]" in text
+    block = step.block_extent((1920, 2520), (2, 2), "pallas_rdma", "u8",
+                              "zero")
+    runner, _ = step._iterate_runner(
+        mesh, filt, 100, True, (1920, 2520), block, 3, "u8", "pallas_rdma",
+        16, "zero", None, False, False, None, None)
+    assert runner.tap_form == "separable"
