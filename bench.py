@@ -102,9 +102,6 @@ def main() -> int:
         # traffic of f32; exact by construction in quantize mode.
         ("pallas_sep", "u8", 16, shape),
         ("pallas_sep", "u8", 32, shape),
-        # Round-4 experiment: unmasked-interior launch split (bit-identical
-        # by construction; a default only if this row beats the flagship).
-        ("pallas_sep+isplit", "bf16", 32, shape),
         # RDMA tier at a tiled-kernel-sized block: degenerate (no remote
         # partner) on a 1x1 mesh, but every driver round re-proves the
         # kernel + barrier compile and run on real silicon.  fuse=4 adds
@@ -119,11 +116,6 @@ def main() -> int:
     candidates = {}
     for backend, storage, fuse, cshape in configs:
         name = f"{backend}/{storage}/fuse{fuse}"
-        isplit = backend.endswith("+isplit")
-        if isplit:
-            # Round 5: the split dispatches per-device edge-class launches,
-            # so the row is meaningful on ANY grid (1x1 included).
-            backend = backend[: -len("+isplit")]
         if cshape != shape:
             # Off-default shape must be visible in the candidate name so
             # wall_s values across rows can't be misread as comparable.
@@ -132,7 +124,6 @@ def main() -> int:
             row = bench.bench_iterate(
                 cshape, filt, iters, mesh=mesh, backend=backend,
                 storage=storage, fuse=fuse, reps=reps,
-                interior_split=isplit,
             )
             candidates[name] = row
             print(f"# {name}: {row}", file=sys.stderr)

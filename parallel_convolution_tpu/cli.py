@@ -89,12 +89,6 @@ def _add_perf_args(p: argparse.ArgumentParser) -> None:
                         "Inert (normalized to 'packed') off the RDMA "
                         "tier; rows and responses stamp the RESOLVED "
                         "value")
-    p.add_argument("--interior-split", action="store_true",
-                   dest="interior_split",
-                   help="unmasked-interior launch split for fused Pallas "
-                        "backends: per-device edge-class launches skip "
-                        "ghost-ring masking on provably-interior tiles "
-                        "(bit-identical; no-op for fuse=1 and periodic)")
     p.add_argument("--fallback", action="store_true",
                    help="graceful backend degradation: probe the backend "
                         "once and walk pallas_rdma -> pallas -> shifted "
@@ -370,7 +364,7 @@ def main(argv: list[str] | None = None) -> int:
         print(f"backends: {', '.join(BACKENDS)}")
         print(f"storages: {', '.join(STORAGES)}  "
               f"boundaries: {', '.join(BOUNDARIES)}")
-        print("perf knobs: --fuse T, --tile TH,TW, --interior-split, "
+        print("perf knobs: --fuse T, --tile TH,TW, "
               "--fast (measured flagship preset)")
         return 0
 
@@ -424,7 +418,6 @@ def main(argv: list[str] | None = None) -> int:
             (args.rows, args.cols), get_filter(args.filter_name),
             args.loops, mesh=mesh,
             channels=3 if args.mode == "rgb" else 1,
-            interior_split=args.interior_split,
             backend=args.backend, storage=args.storage, fuse=args.fuse,
             reps=args.reps, tile=tile, fallback=args.fallback,
             overlap=args.overlap, col_mode=args.col_mode,
@@ -459,8 +452,7 @@ def main(argv: list[str] | None = None) -> int:
             # ValueError in mg_converge); jacobi keeps the historical
             # quantized semantics.
             quantize=not mg, fuse=args.fuse, tile=tile,
-            boundary=args.boundary, storage=args.storage,
-            interior_split=args.interior_split, overlap=args.overlap,
+            boundary=args.boundary, storage=args.storage, overlap=args.overlap,
             col_mode=args.col_mode,
             solver=args.solver, mg_levels=args.mg_levels,
         )
@@ -488,7 +480,6 @@ def main(argv: list[str] | None = None) -> int:
                              backend=args.backend, storage=args.storage,
                              fuse=args.fuse, boundary=args.boundary,
                              tile=tile,
-                             interior_split=args.interior_split,
                              overlap=args.overlap,
                              col_mode=args.col_mode,
                              fallback=args.fallback)
@@ -502,7 +493,7 @@ def main(argv: list[str] | None = None) -> int:
             xs, model.filt, args.loops, mesh, (args.rows, args.cols),
             ckpt_dir=args.checkpoint, every=args.checkpoint_every,
             backend=args.backend, fuse=args.fuse, boundary=args.boundary,
-            tile=tile, interior_split=args.interior_split,
+            tile=tile,
             fallback=args.fallback, overlap=args.overlap,
             col_mode=args.col_mode,
         )

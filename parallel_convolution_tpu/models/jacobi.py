@@ -40,8 +40,6 @@ class JacobiSolver:
     #                None = tune it (backend="auto", resolved in
     #                sharded_converge through the tuning subsystem)
     tile: tuple[int, int] | None = None  # Pallas kernel tile override
-    interior_split: bool = False  # unmasked-interior launch split (see
-    #                ConvolutionModel; fused chunks only)
     overlap: bool | None = None  # interior-first overlapped halo pipeline
     #                (see ConvolutionModel; resolved in sharded_converge)
     col_mode: str | None = None  # RDMA column-slab transport (packed |
@@ -105,7 +103,7 @@ class JacobiSolver:
             quantize=self.quantize, backend=self.backend,
             boundary=self.boundary, storage=self.storage,
             fuse=self.fuse, tile=self.tile,
-            interior_split=self.interior_split, overlap=self.overlap,
+            overlap=self.overlap,
             col_mode=self.col_mode,
         )
         return np.asarray(out), iters

@@ -57,8 +57,6 @@ class ConvolutionModel:
     boundary: str = "zero"  # 'periodic' = torus wrap (ring topology)
     tile: tuple[int, int] | None = None  # Pallas kernel output-tile (TH, TW)
     #                override; None = per-kernel tuned default
-    interior_split: bool = False  # unmasked-interior launch split (fused
-    #                Pallas on a 1x1 grid; bit-identical, opt-in experiment)
     overlap: bool | None = None  # interior-first overlapped halo pipeline
     #                (RDMA kernels): None = off for explicit backends /
     #                tuned for backend="auto"; True is a clamped request —
@@ -111,60 +109,22 @@ class ConvolutionModel:
         self.effective_col_mode = None
         return self
 
-    def _resolved_knobs(
-            self, hw: tuple[int, int],
-            channels: int = 1) -> tuple[str, int, object, bool, str]:
-        """Resolve for the REAL (H, W) workload: the probe must compile
-        the same kernel family (block geometry + storage dtype) the run
-        will, or it could pass while the run crashes.
-
-        ``backend="auto"`` resolves through the tuning subsystem FIRST
-        (plan cache, else cost model); the degradation walk then guards
-        the resolved backend like any explicitly-named one.  The overlap
-        knob resolves alongside (tuned for auto, clamped request
-        otherwise) and is re-clamped if degradation leaves the RDMA tier.
-        """
-        backend, fuse, tile = self.backend, self.fuse, self.tile
-        overlap, col_mode = self.overlap, self.col_mode
-        if backend == "auto":
-            from parallel_convolution_tpu import tuning
-
-            res = tuning.resolve(
-                self.mesh, self.filt, (channels, *hw),
+    def _resolved_knobs(self, shape, block_hw=None) -> step_lib.Launch:
+        """The launch for a (C, H, W) input of ``shape``, resolved once
+        (``step.resolve_launch``) inside the ``pctpu.resolve`` span, with
+        what it decided stamped on the model."""
+        with obs_trace.span("pctpu.resolve"):
+            launch = step_lib.resolve_launch(
+                self.mesh, self.filt, shape, backend=self.backend,
                 storage=self.storage, quantize=self.quantize,
-                boundary=self.boundary, fuse=fuse,
-                tile=step_lib._norm_tile(tile), overlap=overlap,
-                col_mode=col_mode)
-            backend, fuse, tile = res.backend, res.fuse, res.tile
-            overlap, col_mode = res.overlap, res.col_mode
-            self.plan_source = res.source
-        else:
-            fuse = 1 if fuse is None else fuse
-            self.plan_source = "explicit"
-        overlap = step_lib.resolve_overlap(overlap, backend, self.mesh)
-        from parallel_convolution_tpu.parallel.mesh import grid_shape
-
-        block_hw = step_lib.block_extent(hw, grid_shape(self.mesh), backend,
-                                         self.storage, self.boundary)
-        col_mode = step_lib.resolve_col_mode(
-            col_mode, backend, self.mesh, block_hw, self.filt.radius,
-            int(fuse), self.storage)
-        if not self.fallback:
-            self.effective_backend = backend
-            self.effective_overlap = overlap
-            self.effective_col_mode = col_mode
-            return backend, fuse, tile, overlap, col_mode
-        eff = step_lib._resolve_fallback(
-            self.mesh, self.filt, backend, self.quantize, fuse,
-            self.boundary, step_lib._norm_tile(tile),
-            self.interior_split, self.storage, block_hw=block_hw,
-            overlap=overlap, col_mode=col_mode)
-        overlap = overlap and eff == "pallas_rdma"
-        col_mode = step_lib.clamp_col_mode(col_mode, eff)
-        self.effective_backend = eff
-        self.effective_overlap = overlap
-        self.effective_col_mode = col_mode
-        return eff, fuse, tile, overlap, col_mode
+                boundary=self.boundary, fuse=self.fuse, tile=self.tile,
+                overlap=self.overlap, col_mode=self.col_mode,
+                fallback=self.fallback, block_hw=block_hw)
+        self.plan_source = launch.plan_source
+        self.effective_backend = launch.backend
+        self.effective_overlap = launch.overlap
+        self.effective_col_mode = launch.col_mode
+        return launch
 
     # -- array-level API ----------------------------------------------------
     def run_planar(self, x, iters: int) -> jnp.ndarray:
@@ -173,26 +133,17 @@ class ConvolutionModel:
         Its host work sits in the profiler span ``pctpu.run_planar``, with
         ``pctpu.resolve`` and the step layer's ``pctpu.step.*`` inside."""
         with obs_trace.span("pctpu.run_planar"):
-            return step_lib.sharded_iterate(
-                x, self.filt, iters, mesh=self.mesh,
-                **self._launch_knobs(x.shape[-2:], x.shape[0]))
+            return step_lib._run_launch(
+                x, self.filt, iters, self.mesh,
+                self._resolved_knobs(x.shape), self.quantize)
 
     def iterate_text(self, shape, iters: int) -> str:
         """The compiled text of the iterate executable ``run_planar`` runs
         for a (C, H, W) input of ``shape``: ``step.op_scopes`` maps its
         instructions, as a device trace names them, to their layer."""
-        return step_lib.iterate_text(
-            shape, self.filt, iters, mesh=self.mesh,
-            **self._launch_knobs(shape[-2:], shape[0]))
-
-    def _launch_knobs(self, hw, channels) -> dict:
-        with obs_trace.span("pctpu.resolve"):
-            backend, fuse, tile, overlap, col_mode = self._resolved_knobs(
-                hw, channels)
-        return dict(quantize=self.quantize, backend=backend,
-                    storage=self.storage, fuse=fuse, boundary=self.boundary,
-                    tile=tile, interior_split=self.interior_split,
-                    overlap=overlap, col_mode=col_mode)
+        return step_lib._launch_text(shape, self.filt, iters, self.mesh,
+                                     self._resolved_knobs(shape),
+                                     self.quantize)
 
     def run_image(self, img: np.ndarray, iters: int) -> np.ndarray:
         """uint8 (H, W[, 3]) in → uint8 out; the one-call user entrypoint."""
@@ -235,6 +186,7 @@ class ConvolutionModel:
         in one host buffer (the MPI-IO workflow, SURVEY.md §7)."""
         import numpy as np
 
+        from parallel_convolution_tpu.parallel.mesh import grid_shape
         from parallel_convolution_tpu.parallel.step import STORAGE_DTYPES
         from parallel_convolution_tpu.utils import sharded_io
 
@@ -242,13 +194,10 @@ class ConvolutionModel:
             src, rows, cols, mode, self.mesh,
             dtype=np.dtype(STORAGE_DTYPES[self.storage]),
         )
-        backend, fuse, tile, overlap, col_mode = self._resolved_knobs(
-            (rows, cols), 3 if mode == "rgb" else 1)
-        out = step_lib.iterate_prepared(
-            xs, self.filt, iters, self.mesh, (rows, cols),
-            quantize=self.quantize, backend=backend,
-            fuse=fuse, boundary=self.boundary, tile=tile,
-            interior_split=self.interior_split, overlap=overlap,
-            col_mode=col_mode,
-        )
+        R, C = grid_shape(self.mesh)
+        launch = self._resolved_knobs(
+            (xs.shape[0], rows, cols),
+            block_hw=(xs.shape[1] // R, xs.shape[2] // C))
+        out = step_lib._iterate_launch(xs, self.filt, iters, self.mesh,
+                                       (rows, cols), launch, self.quantize)
         sharded_io.save_sharded(dst, out, rows, cols, mode)

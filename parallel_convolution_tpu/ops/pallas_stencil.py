@@ -545,46 +545,6 @@ def correlate_shifted_pallas(x: jnp.ndarray, filt: Filter, **kw) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 
 
-def _norm_block_off(block_off):
-    """Normalize a static block offset to ((r0_lo, r0_hi), (c0_lo, c0_hi)).
-
-    Accepts the exact-offset shorthand ``(r0, c0)`` or the range form — the
-    range form exists because one SPMD program can serve a *class* of
-    device positions (e.g. every non-edge row of a grid), whose offsets
-    share interior geometry without being a single static value.
-    """
-    r, c = block_off
-    r = (int(r), int(r)) if not hasattr(r, "__len__") else (int(r[0]), int(r[1]))
-    c = (int(c), int(c)) if not hasattr(c, "__len__") else (int(c[0]), int(c[1]))
-    return r, c
-
-
-def _interior_range(valid_hw, tile_hw, depth, grid_hw, block_off=(0, 0)):
-    """Inclusive (i, j) grid ranges whose level-0 windows sit fully inside
-    the image, for a block at static global offset ``block_off``.
-
-    Tile (i, j) of a block at offset (r0, c0) covers image rows
-    [r0 + i*th - depth, r0 + i*th + th + depth); it is interior iff that
-    range lies in [0, H) (ditto columns).  ``block_off`` components may be
-    (lo, hi) ranges — the bounds are then conservative over every offset
-    in the range (lo decides the low edge, hi the high edge), so one
-    result serves a whole class of device positions.  Returns None when no
-    tile qualifies (then the split is pointless).
-    """
-    H, W = valid_hw
-    th, tw = tile_hw
-    gh, gw = grid_hw
-    (r0l, r0h), (c0l, c0h) = _norm_block_off(block_off)
-    i_lo = max(0, -(-(depth - r0l) // th))   # smallest i: r0 + i*th >= depth
-    i_hi = (H - r0h - th - depth) // th      # largest i: end <= H
-    j_lo = max(0, -(-(depth - c0l) // tw))
-    j_hi = (W - c0h - tw - depth) // tw
-    i_hi, j_hi = min(i_hi, gh - 1), min(j_hi, gw - 1)
-    if i_lo > i_hi or j_lo > j_hi:
-        return None
-    return (i_lo, i_hi), (j_lo, j_hi)
-
-
 def fused_tile_grid(valid_hw, dtype, tile, sep=None):
     """Static (th, tw, gh, gw) the fused launch uses for a block of valid
     extent ``valid_hw``: the requested tile rounded to the dtype's
@@ -599,50 +559,6 @@ def fused_tile_grid(valid_hw, dtype, tile, sep=None):
     th = min(_round_up(tile[0], sub), _round_up(h, sub))
     tw = min(_round_up(tile[1], 128), _round_up(w, 128))
     return th, tw, -(-h // th), -(-w // tw)
-
-
-def split_patches(split, grid_hw):
-    """The 9-patch launch plan for an interior box: a list of
-    ``((i0, i1), (j0, j1), (mask_rows, mask_cols))`` patches partitioning
-    the ``grid_hw`` tile grid (half-open band bounds), with per-patch
-    static mask axes — the middle×middle patch unmasked, pure-edge
-    patches keeping only the axis their tiles can cross, corners both.
-
-    Single source of truth shared by the ``fused_iterate_pallas`` launch
-    and the geometry-prediction tooling (scripts/profile_flagship.py), so
-    the op-ledger prediction cannot drift from the real launch (the
-    5-strip → 9-patch refinement did exactly that to the old inline
-    formula)."""
-    (i_lo, i_hi), (j_lo, j_hi) = split
-    gh, gw = grid_hw
-    row_bands = [(0, i_lo, True), (i_lo, i_hi + 1, False),
-                 (i_hi + 1, gh, True)]
-    col_bands = [(0, j_lo, True), (j_lo, j_hi + 1, False),
-                 (j_hi + 1, gw, True)]
-    return [((r0, r1), (c0, c1), (mr, mc))
-            for r0, r1, mr in row_bands if r1 > r0
-            for c0, c1, mc in col_bands if c1 > c0]
-
-
-def axis_offset_classes(n_dev: int, block: int):
-    """Static block-offset classes along one grid axis, as (lo, hi) ranges.
-
-    Under shard_map a device's block offset ``a * block`` is dynamic, but
-    its *interior geometry* only depends on which image edges the block
-    can touch — so devices collapse into at most three static classes per
-    axis: first row (offset exactly 0), last row (exactly (n-1)*block),
-    and the middle band (offsets in [block, (n-2)*block], which
-    ``_interior_range`` treats conservatively).  The caller dispatches on
-    the dynamic axis index (``step._axis_class_index``) to the per-class
-    specialized launch; this is what makes the unmasked-interior split
-    reachable on any grid, not just 1×1.
-    """
-    if n_dev == 1:
-        return [(0, 0)]
-    if n_dev == 2:
-        return [(0, 0), (block, block)]
-    last = (n_dev - 1) * block
-    return [(0, 0), (block, last - block), (last, last)]
 
 
 def _iterate_levels(cur, *, form, k, r, T, out_hw, quantize, convex,
@@ -660,7 +576,7 @@ def _iterate_levels(cur, *, form, k, r, T, out_hw, quantize, convex,
     when present, out-of-``valid_hw`` positions of every level are
     re-zeroed with the cheap rank-1 broadcast multiplies — exactly the
     oracle's ghost ring at every intermediate level.  ``None`` statically
-    drops that mask axis (periodic torus, or a provably-interior launch).
+    drops that mask axis (the periodic torus).
     Every level-0 value must already be finite (the caller's select tier)
     — a multiplicative mask would leak NaN otherwise.
     """
@@ -684,8 +600,7 @@ def _iterate_levels(cur, *, form, k, r, T, out_hw, quantize, convex,
 
 def _fused_kernel(off_ref, hbm_ref, out_ref, scratch, sems, *,
                   form, k, r, T, th, tw, ext_h, ext_w, valid_hw,
-                  quantize, convex, round_mode, grid_off=(0, 0),
-                  mask_rows=True, mask_cols=True):
+                  quantize, convex, round_mode):
     """T in-VMEM stencil levels on one (th + 2rT, tw + 2rT) window.
 
     The window shrinks by r per level; after each level, positions outside
@@ -693,21 +608,12 @@ def _fused_kernel(off_ref, hbm_ref, out_ref, scratch, sems, *,
     intermediate level) using the shard's global offset from SMEM.  One HBM
     read + one HBM write buy T iterations — the bandwidth analog of the
     fuse=T collective saving.
-
-    ``mask_rows`` / ``mask_cols`` statically drop one masking axis for
-    launches whose tiles provably cannot cross that pair of image edges
-    (the 9-patch interior split): a top-band middle tile needs only row
-    masking, a left-band middle only column masking.  Sound for the same
-    reason the fully-unmasked interior is: the skipped mask is the
-    identity there.
     """
-    gi0, gj0 = grid_off
-    i, j = pl.program_id(1) + gi0, pl.program_id(2) + gj0
+    i, j = pl.program_id(1), pl.program_id(2)
 
     def window_copy(cc, ii, jj, slot):
         return pltpu.make_async_copy(
-            hbm_ref.at[cc, pl.ds((ii + gi0) * th, ext_h),
-                       pl.ds((jj + gj0) * tw, ext_w)],
+            hbm_ref.at[cc, pl.ds(ii * th, ext_h), pl.ds(jj * tw, ext_w)],
             scratch.at[slot],
             sems.at[slot],
         )
@@ -720,22 +626,18 @@ def _fused_kernel(off_ref, hbm_ref, out_ref, scratch, sems, *,
     row0 = off_ref[0] - r * T + i * th
     col0 = off_ref[1] - r * T + j * tw
     cur = _to_f32(scratch[slot][: th + 2 * r * T, : tw + 2 * r * T])
-    mask_rows = mask_rows and valid_hw is not None
-    mask_cols = mask_cols and valid_hw is not None
     rows0 = cols0 = None
-    if mask_rows or mask_cols:
-        # Ghost-ring masking in two tiers (no tier at all = periodic
-        # torus or a provably-interior launch):
+    if valid_hw is not None:
+        # Ghost-ring masking in two tiers (none at all on the periodic
+        # torus):
         #
         # 1. ONE select on the level-0 window: out-of-image positions
         #    (halo beyond the image edge, pad rim) are forced to exactly 0,
         #    so any non-finite garbage the DMA may have carried dies here
         #    (a multiplicative mask alone would leak it: 0 * NaN = NaN).
-        #    Restricted to the statically-live axes: a skipped axis is
-        #    provably in-image, hence genuine finite data.
         # 2. Per level, the cheap rank-1 form: the out-of-image region of
         #    any level's window is a row band ⊗ column band, so re-zeroing
-        #    is one broadcast multiply per live axis (~1 VPU op/px each).
+        #    is one broadcast multiply per axis (~1 VPU op/px each).
         #    Exact because tier 1 guarantees every intermediate is finite.
         #    Measured on v5e: per-level 2D select instead cost ~20%
         #    throughput at fuse=16 AND ~2× Mosaic compile time per
@@ -743,18 +645,15 @@ def _fused_kernel(off_ref, hbm_ref, out_ref, scratch, sems, *,
         #
         # Branching around the mask for interior tiles is NOT worth it:
         # one lax.cond per program measured 40% slower on Mosaic than
-        # unconditional masking (it stalls the DMA/compute pipeline) —
-        # the launch split exists precisely to make this static.
+        # unconditional masking (it stalls the DMA/compute pipeline), and
+        # a static unmasked-interior launch split measured 1.004x on v5e
+        # (DESIGN.md): the multiplies are free on the VPU.
         H, W = valid_hw
         w0h, w0w = th + 2 * r * T, tw + 2 * r * T
-        ok0 = None
-        if mask_rows:
-            rows0 = row0 + jax.lax.broadcasted_iota(jnp.int32, (w0h, 1), 0)
-            ok0 = (rows0 >= 0) & (rows0 < H)
-        if mask_cols:
-            cols0 = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, w0w), 1)
-            okc0 = (cols0 >= 0) & (cols0 < W)
-            ok0 = okc0 if ok0 is None else (ok0 & okc0)
+        rows0 = row0 + jax.lax.broadcasted_iota(jnp.int32, (w0h, 1), 0)
+        ok0 = (rows0 >= 0) & (rows0 < H)
+        cols0 = col0 + jax.lax.broadcasted_iota(jnp.int32, (1, w0w), 1)
+        ok0 = ok0 & ((cols0 >= 0) & (cols0 < W))
         cur = jnp.where(ok0, cur, 0.0)
     cur = _iterate_levels(cur, form=form, k=k, r=r, T=T,
                           out_hw=(th, tw), quantize=quantize, convex=convex,
@@ -766,8 +665,7 @@ def _fused_kernel(off_ref, hbm_ref, out_ref, scratch, sems, *,
 @functools.partial(
     jax.jit,
     static_argnames=("filt", "T", "valid_hw", "tile", "interpret",
-                     "quantize", "out_dtype", "separable", "interior_split",
-                     "block_off"),
+                     "quantize", "out_dtype", "separable"),
 )
 def fused_iterate_pallas(
     padded: jnp.ndarray,
@@ -780,8 +678,6 @@ def fused_iterate_pallas(
     quantize: bool = True,
     out_dtype=None,
     separable: bool = False,
-    interior_split: bool = False,
-    block_off: tuple | None = None,
 ) -> jnp.ndarray:
     """T stencil iterations of a deep-padded (C, h+2rT, w+2rT) block.
 
@@ -790,25 +686,6 @@ def fused_iterate_pallas(
     shard_map — used for per-level ghost-ring masking against ``valid_hw``.
     Bit-exact with T applications of the one-step kernel (same op order,
     intermediates at full f32 in VMEM).
-
-    ``interior_split=True`` splits the launch into a 9-patch: an UNMASKED
-    interior call, pure-edge band calls that statically keep only ONE
-    mask axis (a top-band middle tile can only cross the top edge, so
-    column masking is provably the identity there — rows-only; left/right
-    middles mirror it cols-only), and corner patches keeping both.
-    Interior tiles skip the per-level ghost-ring multiplies (~2 of ~9
-    VPU ops/px/level) and the level-0 select; pure-edge tiles skip one.
-    It requires ``block_off`` — the STATIC global offset of this block,
-    either exact ``(r0, c0)`` or per-component ``(lo, hi)`` ranges
-    covering every offset one SPMD program may see (see
-    ``axis_offset_classes``); the runtime ``offsets`` array must lie
-    within it.  Raises ValueError if ``block_off`` is missing, so a
-    caller on a sharded layout cannot silently skip ghost-ring masking
-    with offsets the classification never saw.  The masked border calls
-    keep using the dynamic ``offsets``, so offset *ranges* are exact, not
-    approximate.  Bit-identical by construction (the masks it skips are
-    the identity there); measured on its own bench row before ever
-    becoming a default.
     """
     if interpret is None:
         interpret = not on_tpu()
@@ -833,70 +710,31 @@ def fused_iterate_pallas(
                              ((0, 0), (0, max(eh, 0)), (0, max(ew, 0))))
 
     taps = tuple(float(t) for t in filt.taps.reshape(-1))
-    form = tap_form(filt, separable, quantize)
-    vma = jax.typeof(padded).vma
-    off32 = offsets.astype(jnp.int32)
-
-    def call(grid_hw, grid_off, mask_axes=(True, True)):
-        mr, mc = mask_axes
-        kernel = functools.partial(
-            _fused_kernel, form=form,
-            k=k, r=r, T=T, th=th, tw=tw, ext_h=ext_h, ext_w=ext_w,
-            valid_hw=(tuple(valid_hw)
-                      if (mr or mc) and valid_hw is not None else None),
-            quantize=quantize, convex=filt.convex,
-            round_mode=(_round_mode_for(taps, interpret) if quantize
-                        else "rint"),  # dead when not quantizing
-            grid_off=grid_off,
-            mask_rows=mr, mask_cols=mc,
-        )
-        cgh, cgw = grid_hw
-        return pl.pallas_call(
-            kernel,
-            grid=(C, cgh, cgw),
-            in_specs=[
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pl.ANY),
-            ],
-            out_specs=pl.BlockSpec((1, th, tw), lambda c, i, j: (c, i, j)),
-            out_shape=jax.ShapeDtypeStruct((C, cgh * th, cgw * tw),
-                                           out_dtype, vma=vma),
-            scratch_shapes=[
-                pltpu.VMEM((2, ext_h, ext_w), padded.dtype),
-                pltpu.SemaphoreType.DMA((2,)),
-            ],
-            compiler_params=pltpu.CompilerParams(
-                vmem_limit_bytes=FUSED_VMEM_LIMIT),
-            interpret=interpret,
-        )(off32, padded)
-
-    split = None
-    if interior_split and valid_hw is not None:
-        if block_off is None:
-            raise ValueError(
-                "interior_split requires a static block_off — the global "
-                "(row0, col0) of this block, exact or as (lo, hi) ranges; "
-                "without it the unmasked-interior classification cannot be "
-                "sound for arbitrary runtime offsets"
-            )
-        split = _interior_range(valid_hw, (th, tw), r * T, (gh, gw),
-                                block_off)
-    if split is None:
-        out = call((gh, gw), (0, 0))
-        with jax.named_scope(KERNEL_IO):
-            return out[:, :h, :w]
-
-    # 9-patch launch (split_patches is the shared plan): the
-    # middle×middle patch runs fully unmasked; a pure-edge patch (middle
-    # on one axis) statically drops the other axis's mask — its tiles
-    # are interior along that axis by the box construction.
-    patches = split_patches(split, (gh, gw))
-    rows: dict = {}
-    for (r0b, r1b), (c0b, c1b), axes in patches:
-        rows.setdefault((r0b, r1b), []).append(
-            call((r1b - r0b, c1b - c0b), (r0b, c0b), axes))
+    kernel = functools.partial(
+        _fused_kernel, form=tap_form(filt, separable, quantize),
+        k=k, r=r, T=T, th=th, tw=tw, ext_h=ext_h, ext_w=ext_w,
+        valid_hw=tuple(valid_hw) if valid_hw is not None else None,
+        quantize=quantize, convex=filt.convex,
+        round_mode=(_round_mode_for(taps, interpret) if quantize
+                    else "rint"),  # dead when not quantizing
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid=(C, gh, gw),
+        in_specs=[
+            pl.BlockSpec(memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, th, tw), lambda c, i, j: (c, i, j)),
+        out_shape=jax.ShapeDtypeStruct((C, gh * th, gw * tw), out_dtype,
+                                       vma=jax.typeof(padded).vma),
+        scratch_shapes=[
+            pltpu.VMEM((2, ext_h, ext_w), padded.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+        ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=FUSED_VMEM_LIMIT),
+        interpret=interpret,
+    )(offsets.astype(jnp.int32), padded)
     with jax.named_scope(KERNEL_IO):
-        bands = [jnp.concatenate(calls, axis=2) if len(calls) > 1
-                 else calls[0] for calls in rows.values()]
-        out = jnp.concatenate(bands, axis=1) if len(bands) > 1 else bands[0]
         return out[:, :h, :w]

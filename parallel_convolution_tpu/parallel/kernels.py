@@ -62,13 +62,9 @@ STENCIL_FORMS = ("smooth", "restrict", "prolong", "physics")
 class KernelForm:
     """One registered stencil program family.
 
-    ``build`` is the step factory; its signature is owned by the
-    registering module (for smoothers it is exactly the historical
-    ``step._make_block_step`` contract: ``build(filt, grid, valid_hw,
-    block_hw, quantize, fuse, boundary, tile, interpret,
-    interior_split, overlap) -> step``, where ``step`` maps one
-    device's planar block to the next).  The registry stores and
-    resolves; it never calls.
+    ``build`` is the step factory, with the signature its registering
+    module calls it by (``step._make_block_step`` for smoothers).  The
+    registry stores and resolves; it never calls.
     """
 
     name: str

@@ -24,6 +24,7 @@ ring: outputs stay bit-identical to the serial oracle for any mesh shape.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import re
 import time
@@ -55,8 +56,9 @@ from parallel_convolution_tpu.utils.config import (  # canonical registries
 )
 from jax import shard_map
 
-__all__ = ["BACKENDS", "STORAGE_DTYPES", "block_extent", "sharded_iterate",
-           "sharded_converge", "sharded_converge_stream", "iterate_prepared",
+__all__ = ["BACKENDS", "STORAGE_DTYPES", "Launch", "block_extent",
+           "resolve_launch", "sharded_iterate", "sharded_converge",
+           "sharded_converge_stream", "iterate_prepared",
            "reshard_prepared",
            "resolve_overlap", "resolve_col_mode", "clamp_col_mode",
            "iterate_text", "op_scopes"]
@@ -239,16 +241,6 @@ def clamp_col_mode(col_mode: str, backend: str) -> str:
             else "packed")
 
 
-def _axis_class_index(a, n: int):
-    """Dynamic index of device ``a``'s offset class along an ``n``-device
-    axis, matching ``pallas_stencil.axis_offset_classes`` order."""
-    if n == 1:
-        return jnp.int32(0)
-    if n == 2:
-        return a.astype(jnp.int32)
-    return jnp.where(a == 0, 0, jnp.where(a == n - 1, 2, 1)).astype(jnp.int32)
-
-
 def _boundary_geometry(grid, valid_hw, block_hw, boundary: str):
     """Shared geometry checks of every rank-2 form: periodic divisibility
     and whether the pad-to-multiple rim needs re-masking."""
@@ -268,7 +260,6 @@ def _build_rdma_step(filt: Filter, grid, valid_hw, block_hw, quantize: bool,
                      fuse: int = 1, boundary: str = "zero",
                      tile: tuple[int, int] | None = None,
                      interpret: bool | None = None,
-                     interior_split: bool = False,
                      overlap: bool = False,
                      col_mode: str = "strided"):
     """The ``pallas_rdma`` kernel form: exchange + stencil fused in ONE
@@ -321,7 +312,6 @@ def _build_halo_step(backend: str, filt: Filter, grid, valid_hw, block_hw,
                      quantize: bool, fuse: int = 1, boundary: str = "zero",
                      tile: tuple[int, int] | None = None,
                      interpret: bool | None = None,
-                     interior_split: bool = False,
                      overlap: bool = False,
                      col_mode: str = "strided"):
     """The halo-exchange kernel forms (every backend but ``pallas_rdma``):
@@ -372,37 +362,11 @@ def _build_halo_step(backend: str, filt: Filter, grid, valid_hw, block_hw,
                 lax.axis_index("y") * block_hw[1],
             ]).astype(jnp.int32)
 
-            def fused(p, off, block_off):
-                return pallas_stencil.fused_iterate_pallas(
-                    p, off, filt, fuse,
-                    None if periodic else tuple(valid_hw),
-                    quantize=quantize, out_dtype=v.dtype, separable=sep,
-                    tile=tile, interpret=interpret,
-                    interior_split=block_off is not None,
-                    block_off=block_off,
-                )
-
-            if not interior_split or periodic:
-                return fused(p, off, None)
-            # Interior split on any grid: a device's offset is dynamic
-            # under SPMD, but its interior geometry depends only on which
-            # image edges its block can touch — at most 3 static offset
-            # classes per axis (pallas_stencil.axis_offset_classes).
-            # One lax.switch per chunk picks this device's specialized
-            # launch; the masked border calls inside each branch still use
-            # the dynamic `off`, so class offset *ranges* stay exact.
-            rcls = pallas_stencil.axis_offset_classes(grid[0], block_hw[0])
-            ccls = pallas_stencil.axis_offset_classes(grid[1], block_hw[1])
-            if len(rcls) == 1 and len(ccls) == 1:
-                return fused(p, off, (rcls[0], ccls[0]))
-            branches = [
-                (lambda bo: lambda pp, oo: fused(pp, oo, bo))((rr, cc))
-                for rr in rcls for cc in ccls
-            ]
-            idx = (_axis_class_index(lax.axis_index("x"), grid[0])
-                   * len(ccls)
-                   + _axis_class_index(lax.axis_index("y"), grid[1]))
-            return lax.switch(idx, branches, p, off)
+            return pallas_stencil.fused_iterate_pallas(
+                p, off, filt, fuse, None if periodic else tuple(valid_hw),
+                quantize=quantize, out_dtype=v.dtype, separable=sep,
+                tile=tile, interpret=interpret,
+            )
         for t in range(fuse):
             margin = depth - r * (t + 1)
             p = correlate_level(p, v.dtype)
@@ -434,7 +398,6 @@ def _make_block_step(filt: Filter, grid, valid_hw, block_hw, quantize: bool,
                      backend: str, fuse: int = 1, boundary: str = "zero",
                      tile: tuple[int, int] | None = None,
                      interpret: bool | None = None,
-                     interior_split: bool = False,
                      overlap: bool = False,
                      col_mode: str = "strided"):
     """One smoothing-step builder, dispatched through the kernel-form
@@ -450,8 +413,7 @@ def _make_block_step(filt: Filter, grid, valid_hw, block_hw, quantize: bool,
             "not a smoother; transfer operators are driven by "
             "solvers.multigrid, not the iterate path")
     return form.build(filt, grid, valid_hw, block_hw, quantize, fuse,
-                      boundary, tile, interpret, interior_split, overlap,
-                      col_mode)
+                      boundary, tile, interpret, overlap, col_mode)
 
 
 def _mesh_interpret(mesh: Mesh) -> bool:
@@ -480,7 +442,6 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
                    valid_hw, block_hw, backend: str, fuse: int = 1,
                    boundary: str = "zero",
                    tile: tuple[int, int] | None = None,
-                   interior_split: bool = False,
                    overlap: bool = False,
                    col_mode: str = "strided"):
     """Compile the fixed-count iteration runner for one (mesh, config).
@@ -511,13 +472,13 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     _note_compile("iterate", backend, grid, iters, fuse, boundary, block_hw)
     interp = _mesh_interpret(mesh)
     chunk = _make_block_step(filt, grid, valid_hw, block_hw, quantize,
-                             backend, fuse, boundary, tile, interp,
-                             interior_split, overlap, col_mode)
+                             backend, fuse, boundary, tile, interp, overlap,
+                             col_mode)
     launch_args = getattr(chunk, "launch_args", None)
     n_chunks, rem = divmod(iters, fuse)
     tail = (_make_block_step(filt, grid, valid_hw, block_hw, quantize,
-                             backend, rem, boundary, tile, interp,
-                             interior_split, overlap, col_mode)
+                             backend, rem, boundary, tile, interp, overlap,
+                             col_mode)
             if rem else None)
 
     counted: dict = {}   # the last trace of each step wins: no doubling
@@ -561,7 +522,6 @@ def _build_converge(mesh: Mesh, filt: Filter, tol: float, max_iters: int,
                     check_every: int, quantize: bool, valid_hw, block_hw,
                     backend: str, boundary: str = "zero", fuse: int = 1,
                     tile: tuple[int, int] | None = None,
-                    interior_split: bool = False,
                     overlap: bool = False,
                     col_mode: str = "strided"):
     """Compile the run-to-convergence runner (C6: every-N diff + allreduce).
@@ -598,8 +558,8 @@ def _build_converge(mesh: Mesh, filt: Filter, tol: float, max_iters: int,
                             boundary=boundary, tile=tile, interpret=interp,
                             overlap=overlap, col_mode=col_mode)
     fused = (_make_block_step(filt, grid, valid_hw, block_hw, quantize,
-                              backend, fuse, boundary, tile, interp,
-                              interior_split, overlap, col_mode)
+                              backend, fuse, boundary, tile, interp, overlap,
+                              col_mode)
              if fuse > 1 else None)
 
     def body(block):
@@ -646,7 +606,6 @@ def _build_converge_chunk(mesh: Mesh, filt: Filter, n: int, quantize: bool,
                           valid_hw, block_hw, backend: str,
                           boundary: str = "zero", fuse: int = 1,
                           tile: tuple[int, int] | None = None,
-                          interior_split: bool = False,
                           overlap: bool = False,
                           col_mode: str = "strided"):
     """Compile ONE convergence chunk: ``n`` iterations + the (prev, cur)
@@ -680,8 +639,8 @@ def _build_converge_chunk(mesh: Mesh, filt: Filter, n: int, quantize: bool,
                             boundary=boundary, tile=tile, interpret=interp,
                             overlap=overlap, col_mode=col_mode)
     fused = (_make_block_step(filt, grid, valid_hw, block_hw, quantize,
-                              backend, fuse, boundary, tile, interp,
-                              interior_split, overlap, col_mode)
+                              backend, fuse, boundary, tile, interp, overlap,
+                              col_mode)
              if fuse > 1 and n > 1 else None)
 
     def body(block):
@@ -826,15 +785,18 @@ def block_extent(hw, grid, backend: str = "shifted", storage: str = "f32",
     return padded_extent(br, ar), padded_extent(bc, ac)
 
 
-def _prepare(x, mesh: Mesh, r: int, storage: str = "f32",
-             backend: str = "shifted", boundary: str = "zero"):
-    """Pad a global (C, H, W) image to whole blocks (:func:`block_extent`)
-    and shard it."""
-    x = jnp.asarray(x, STORAGE_DTYPES[storage])
+def _prepare(x, mesh: Mesh, launch: "Launch"):
+    """Pad a global (C, H, W) image to the launch's blocks and shard it:
+    the block a runner is built for is always the block it is fed."""
+    x = jnp.asarray(x, STORAGE_DTYPES[launch.storage])
     C, H, W = x.shape
     R, Cc = grid_shape(mesh)
-    bh, bw = block_extent((H, W), (R, Cc), backend, storage, boundary)
+    bh, bw = launch.block_hw
     Hp, Wp = bh * R, bw * Cc
+    if Hp < H or Wp < W:
+        raise ValueError(
+            f"launch block {launch.block_hw} on grid {(R, Cc)} does not "
+            f"hold a {H}x{W} image")
     if (Hp, Wp) != (H, W):
         x = jnp.pad(x, ((0, 0), (0, Hp - H), (0, Wp - W)))
     x = jax.device_put(x, block_sharding(mesh))
@@ -887,61 +849,83 @@ def _storage_name(dtype) -> str:
     return "f32"
 
 
-def _resolve_auto(mesh, filt, backend, fuse, tile, storage, quantize,
-                  boundary, valid_hw, channels, check_every=None,
-                  overlap=None, col_mode=None):
-    """``backend='auto'`` -> concrete
-    ``(backend, fuse, tile, overlap, col_mode, source)``.
+@dataclasses.dataclass(frozen=True)
+class Launch:
+    """One resolved launch: the knobs its runner is built with and the
+    per-device block the image is padded to, as :func:`resolve_launch`
+    decides them.  ``plan_source`` is where an ``auto`` backend's plan
+    came from (measured|interpolated|predicted), else ``explicit``."""
 
-    Resolution goes through the tuning subsystem (plan cache if a
-    ``PCTPU_PLAN_FILE`` is armed, else the cost model) and happens
-    BEFORE the resilience degrade walk — auto picks the tier, the
-    fallback probe then guards the resolved launch exactly as it guards
-    an explicitly-named one.  Explicit backends pass through untouched
-    (``fuse=None`` then just normalizes to 1, the historical default;
-    ``overlap`` stays as requested for :func:`resolve_overlap` to
-    settle against the mesh).
+    backend: str
+    fuse: int
+    tile: tuple[int, int] | None
+    overlap: bool
+    col_mode: str
+    storage: str
+    boundary: str
+    block_hw: tuple[int, int]
+    plan_source: str = "explicit"
 
-    ``check_every`` (the convergence path only) is part of the tuning
-    identity: it bounds the legal fusion depth (a chunk fuses at most
-    its n-1 pre-pair iterations) and keys the plan cache, so a tuned
-    convergence run resolves its own plan rather than a fixed-count one.
+
+def resolve_launch(mesh: Mesh, filt: Filter, shape, *,
+                   backend: str = "shifted", storage: str = "f32",
+                   quantize: bool = True, boundary: str = "zero",
+                   fuse: int | None = 1,
+                   tile: tuple[int, int] | None = None,
+                   overlap: bool | None = None, col_mode: str | None = None,
+                   fallback: bool = False, check_every: int | None = None,
+                   plans=None, block_hw=None) -> Launch:
+    """The one place a launch of a (C, H, W) image of ``shape`` is decided.
+
+    In order: ``backend="auto"`` resolves through the tuning subsystem
+    (``plans``, else the ambient plan cache, else the cost model;
+    ``fuse=None``/``tile=None`` are then tuned too, other values are
+    pins; ``check_every`` marks a convergence workload); the overlap
+    request is clamped (:func:`resolve_overlap`); the block is
+    :func:`block_extent`'s unless the caller states its own
+    ``block_hw``; the column transport is chosen
+    (:func:`resolve_col_mode`); with ``fallback`` the degrade walk
+    (``resilience.degrade``) probes the launch on that block and may
+    land on a lower tier, and overlap and column transport are then
+    re-clamped for the tier that survived.
     """
+    if storage not in STORAGE_DTYPES:
+        raise ValueError(f"storage must be one of {tuple(STORAGE_DTYPES)}, "
+                         f"got {storage!r}")
+    C, H, W = (int(v) for v in shape)
+    tile = _norm_tile(tile)
     if backend != AUTO:
-        return (backend, (1 if fuse is None else int(fuse)), tile, overlap,
-                col_mode, None)
-    from parallel_convolution_tpu import tuning
+        source = "explicit"
+    else:
+        from parallel_convolution_tpu import tuning
 
-    res = tuning.resolve(
-        mesh, filt, (channels, valid_hw[0], valid_hw[1]), storage=storage,
-        quantize=quantize, boundary=boundary, fuse=fuse,
-        tile=_norm_tile(tile), overlap=overlap, col_mode=col_mode,
-        check_every=check_every)
-    return (res.backend, res.fuse, res.tile, res.overlap, res.col_mode,
-            res.source)
+        res = tuning.resolve(
+            mesh, filt, (C, H, W), storage=storage, quantize=quantize,
+            boundary=boundary, fuse=fuse, tile=tile, overlap=overlap,
+            col_mode=col_mode, plans=plans, check_every=check_every)
+        backend, fuse, tile, source = (res.backend, res.fuse,
+                                       _norm_tile(res.tile), res.source)
+        overlap, col_mode = res.overlap, res.col_mode
+    fuse = 1 if fuse is None else int(fuse)
+    overlap = resolve_overlap(overlap, backend, mesh)
+    if block_hw is None:
+        block_hw = block_extent((H, W), grid_shape(mesh), backend, storage,
+                                boundary)
+    block_hw = (int(block_hw[0]), int(block_hw[1]))
+    col_mode = resolve_col_mode(col_mode, backend, mesh, block_hw,
+                                filt.radius, fuse, storage)
+    if fallback:
+        from parallel_convolution_tpu.resilience import degrade
 
-
-def _resolve_fallback(mesh, filt, backend, quantize, fuse, boundary, tile,
-                      interior_split, storage="f32",
-                      block_hw=None, overlap: bool = False,
-                      col_mode: str = "packed") -> str:
-    """Walk the degradation chain (resilience.degrade) for this config.
-
-    ``block_hw``/``storage`` must describe the REAL run: kernel selection
-    depends on both (e.g. pallas_rdma's tiled-vs-monolithic switch), so a
-    probe on a different geometry or dtype could pass while the real
-    launch crashes — exactly the gap this probe exists to close.
-    ``overlap`` likewise: the overlapped RDMA program is a different
-    kernel than the serialized one, so the probe must compile the same
-    form (degrade clamps it per walked tier — only the RDMA tier has an
-    overlapped form).
-    """
-    from parallel_convolution_tpu.resilience import degrade
-
-    return degrade.resolve_backend(
-        mesh, filt, backend, quantize=quantize, fuse=fuse, boundary=boundary,
-        tile=tile, interior_split=interior_split, storage=storage,
-        block_hw=block_hw, overlap=overlap, col_mode=col_mode)
+        backend = degrade.resolve_backend(
+            mesh, filt, backend, quantize=quantize, fuse=fuse,
+            boundary=boundary, tile=tile, storage=storage,
+            block_hw=block_hw, overlap=overlap, col_mode=col_mode)
+        overlap = kernel_forms.clamp_overlap(overlap, backend)
+        col_mode = clamp_col_mode(col_mode, backend)
+    return Launch(backend=backend, fuse=fuse, tile=tile, overlap=overlap,
+                  col_mode=col_mode, storage=storage, boundary=boundary,
+                  block_hw=block_hw, plan_source=source)
 
 
 def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
@@ -949,7 +933,6 @@ def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
                      backend: str = "shifted", fuse: int | None = 1,
                      boundary: str = "zero",
                      tile: tuple[int, int] | None = None,
-                     interior_split: bool = False,
                      check_contract: bool = True,
                      fallback: bool = False,
                      overlap: bool | None = None,
@@ -966,42 +949,43 @@ def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
     once and whose chunk inputs are in contract by induction (quantized
     outputs are always in [0, 255]).
 
-    ``fallback=True`` probes ``backend`` once per (mesh, config) per
+    The knobs resolve through :func:`resolve_launch` on the array's own
+    block: ``backend="auto"`` through the tuning subsystem, ``overlap``
+    (the RDMA kernels' interior-first pipeline; None = off for explicit
+    backends) and ``col_mode`` (the RDMA column-slab transport, packed |
+    strided | auto; None = auto) to what actually compiles.
+    ``fallback=True`` probes the launch once per (mesh, config) per
     process BEFORE the real (donating) run and, on a classified-transient
     compile/launch failure, walks the degradation chain
     ``pallas_rdma → pallas → shifted`` (resilience.degrade) — emitting a
-    BackendDegradedWarning rather than dying with the first failed tier.
-    Probing first also means the donated input is never lost to a launch
-    that was going to fail.
-
-    ``backend="auto"`` resolves through the tuning subsystem first
-    (plan cache, else cost model; ``fuse=None``/``tile=None`` are then
-    tuned too, non-None values are pins) — the degrade walk below
-    applies to the *resolved* backend.
-
-    ``overlap`` selects the interior-first overlapped halo pipeline in
-    the RDMA kernels (None = off for explicit backends, tuned for
-    ``backend="auto"``); the resolved bool — clamped by
-    :func:`resolve_overlap` and re-clamped to False if the degrade walk
-    leaves the RDMA tier — is what actually compiles.
-
-    ``col_mode`` selects the RDMA column-slab transport
-    (packed | strided | auto; None = auto) — resolved by
-    :func:`resolve_col_mode` (cost-model pick for the RDMA tier, inert
-    'packed' elsewhere), re-clamped if the degrade walk leaves the tier.
+    BackendDegradedWarning rather than dying with the first failed tier,
+    and never losing the donated input to a launch that was going to
+    fail.
     """
     if jnp.dtype(xs.dtype) == jnp.uint8 and not quantize:
         _check_storage("u8", quantize)  # public entry: same guard as above
     R, Cc = grid_shape(mesh)
-    block_hw = (xs.shape[1] // R, xs.shape[2] // Cc)
-    block = ((xs.shape[0], *block_hw), jnp.dtype(xs.dtype))
+    with obs_trace.span("pctpu.resolve"):
+        launch = resolve_launch(
+            mesh, filt, (xs.shape[0], *valid_hw), backend=backend,
+            storage=_storage_name(xs.dtype), quantize=quantize,
+            boundary=boundary, fuse=fuse, tile=tile, overlap=overlap,
+            col_mode=col_mode, fallback=fallback,
+            block_hw=(xs.shape[1] // R, xs.shape[2] // Cc))
+    return _iterate_launch(xs, filt, iters, mesh, valid_hw, launch,
+                           quantize, check_contract)
+
+
+def _iterate_launch(xs, filt: Filter, iters: int, mesh: Mesh, valid_hw,
+                    launch: Launch, quantize: bool,
+                    check_contract: bool = True):
+    """:func:`iterate_prepared` on a resolved launch: look up the runner
+    and dispatch it."""
+    block = ((xs.shape[0], *launch.block_hw), jnp.dtype(xs.dtype))
     with obs_trace.span("pctpu.step.build") as sp:
         if check_contract:
             _check_quantize_contract(xs, filt, quantize)
-        fn, backend = _iterate_runner(
-            mesh, filt, iters, quantize, valid_hw, block_hw, xs.shape[0],
-            _storage_name(xs.dtype), backend, fuse, boundary, tile,
-            interior_split, fallback, overlap, col_mode)
+        fn = _iterate_runner(mesh, filt, iters, quantize, valid_hw, launch)
         sp.set(tap_form=fn.tap_form)
         if fn.launch_args is not None and obs_metrics.enabled():
             sp.set(**fn.launch_args(xs.shape[0], block[1]))
@@ -1021,35 +1005,17 @@ def iterate_prepared(xs, filt: Filter, iters: int, mesh: Mesh,
     if obs_metrics.enabled():
         from parallel_convolution_tpu.obs import attribution
 
-        attribution.record_halo(backend, count.bytes, count.rounds, iters,
-                                count.wire_bytes)
+        attribution.record_halo(launch.backend, count.bytes, count.rounds,
+                                iters, count.wire_bytes)
     return out
 
 
-def _iterate_runner(mesh, filt, iters, quantize, valid_hw, block_hw,
-                    channels, storage, backend, fuse, boundary, tile,
-                    interior_split, fallback, overlap, col_mode):
-    """Resolve a launch's knobs and look up its runner: ``(runner,
-    backend)`` — the shared build step of :func:`iterate_prepared` and
-    :func:`iterate_text`."""
-    backend, fuse, tile, overlap, col_mode, _ = _resolve_auto(
-        mesh, filt, backend, fuse, tile, storage, quantize, boundary,
-        tuple(valid_hw), channels, overlap=overlap, col_mode=col_mode)
-    overlap = resolve_overlap(overlap, backend, mesh)
-    col_mode = resolve_col_mode(col_mode, backend, mesh, block_hw,
-                                filt.radius, fuse, storage)
-    if fallback:
-        backend = _resolve_fallback(mesh, filt, backend, quantize, fuse,
-                                    boundary, _norm_tile(tile),
-                                    interior_split, storage=storage,
-                                    block_hw=block_hw, overlap=overlap,
-                                    col_mode=col_mode)
-        overlap = kernel_forms.clamp_overlap(overlap, backend)
-        col_mode = clamp_col_mode(col_mode, backend)
-    fn = _build_iterate(mesh, filt, iters, quantize, tuple(valid_hw),
-                        block_hw, backend, fuse, boundary, _norm_tile(tile),
-                        interior_split, overlap, col_mode)
-    return fn, backend
+def _iterate_runner(mesh, filt, iters, quantize, valid_hw, launch: Launch):
+    """The fixed-count runner a resolved launch builds (cached)."""
+    return _build_iterate(mesh, filt, iters, quantize, tuple(valid_hw),
+                          launch.block_hw, launch.backend, launch.fuse,
+                          launch.boundary, launch.tile, launch.overlap,
+                          launch.col_mode)
 
 
 def iterate_text(shape, filt: Filter, iters: int, mesh: Mesh | None = None,
@@ -1057,7 +1023,7 @@ def iterate_text(shape, filt: Filter, iters: int, mesh: Mesh | None = None,
                  storage: str = "f32", fuse: int | None = 1,
                  boundary: str = "zero",
                  tile: tuple[int, int] | None = None,
-                 interior_split: bool = False, fallback: bool = False,
+                 fallback: bool = False,
                  overlap: bool | None = None,
                  col_mode: str | None = None) -> str:
     """The compiled text of the iterate executable that
@@ -1067,14 +1033,22 @@ def iterate_text(shape, filt: Filter, iters: int, mesh: Mesh | None = None,
     the executable, so this compiles nothing new."""
     if mesh is None:
         mesh = make_grid_mesh()
+    launch = resolve_launch(
+        mesh, filt, shape, backend=backend, storage=storage,
+        quantize=quantize, boundary=boundary, fuse=fuse, tile=tile,
+        overlap=overlap, col_mode=col_mode, fallback=fallback)
+    return _launch_text(shape, filt, iters, mesh, launch, quantize)
+
+
+def _launch_text(shape, filt: Filter, iters: int, mesh: Mesh,
+                 launch: Launch, quantize: bool) -> str:
+    """:func:`iterate_text` of a resolved launch."""
     C, H, W = (int(v) for v in shape)
     R, Cc = grid_shape(mesh)
-    bh, bw = block_extent((H, W), (R, Cc), backend, storage, boundary)
-    fn, _ = _iterate_runner(
-        mesh, filt, iters, quantize, (H, W), (bh, bw), C, storage,
-        backend, fuse, boundary, tile, interior_split, fallback, overlap,
-        col_mode)
-    arg = jax.ShapeDtypeStruct((C, bh * R, bw * Cc), STORAGE_DTYPES[storage],
+    bh, bw = launch.block_hw
+    fn = _iterate_runner(mesh, filt, iters, quantize, (H, W), launch)
+    arg = jax.ShapeDtypeStruct((C, bh * R, bw * Cc),
+                               STORAGE_DTYPES[launch.storage],
                                sharding=block_sharding(mesh))
     return fn.lower(arg).compile().as_text()
 
@@ -1131,7 +1105,6 @@ def sharded_iterate(x, filt: Filter, iters: int, mesh: Mesh | None = None,
                     storage: str = "f32", fuse: int | None = 1,
                     boundary: str = "zero",
                     tile: tuple[int, int] | None = None,
-                    interior_split: bool = False,
                     fallback: bool = False,
                     overlap: bool | None = None,
                     col_mode: str | None = None):
@@ -1144,7 +1117,8 @@ def sharded_iterate(x, filt: Filter, iters: int, mesh: Mesh | None = None,
     values are exact in bf16); in float mode it is a documented
     precision/bandwidth trade.  ``tile=(TH, TW)`` overrides the Pallas
     kernels' VMEM output-tile shape (the scripts/tune_pallas.py knob);
-    None = the per-kernel tuned default.
+    None = the per-kernel tuned default.  The knobs resolve through
+    :func:`resolve_launch`.
 
     ``quantize=True`` is the u8 store-back semantics and assumes pixel
     values in [0, 255] (a decoded u8 image): convex filters elide the
@@ -1156,16 +1130,38 @@ def sharded_iterate(x, filt: Filter, iters: int, mesh: Mesh | None = None,
     if mesh is None:
         mesh = make_grid_mesh()
     _check_storage(storage, quantize)
+    with obs_trace.span("pctpu.resolve"):
+        launch = resolve_launch(
+            mesh, filt, x.shape, backend=backend, storage=storage,
+            quantize=quantize, boundary=boundary, fuse=fuse, tile=tile,
+            overlap=overlap, col_mode=col_mode, fallback=fallback)
+    return _run_launch(x, filt, iters, mesh, launch, quantize)
+
+
+def _run_launch(x, filt: Filter, iters: int, mesh: Mesh, launch: Launch,
+                quantize: bool):
+    """:func:`sharded_iterate` on a resolved launch: pad, iterate, crop."""
     with obs_trace.span("pctpu.step.prepare"):
-        xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage,
-                                          backend, boundary)
-    out = iterate_prepared(xs, filt, iters, mesh, valid_hw,
-                           quantize=quantize, backend=backend, fuse=fuse,
-                           boundary=boundary, tile=tile,
-                           interior_split=interior_split, fallback=fallback,
-                           overlap=overlap, col_mode=col_mode)
+        xs, valid_hw, _ = _prepare(x, mesh, launch)
+    out = _iterate_launch(xs, filt, iters, mesh, valid_hw, launch, quantize)
     with obs_trace.span("pctpu.step.crop"):
         return out[:, : valid_hw[0], : valid_hw[1]].astype(jnp.float32)
+
+
+def _converge_setup(x, filt: Filter, mesh: Mesh, check_every: int, *,
+                    backend, storage, quantize, boundary, fuse, tile,
+                    fallback, overlap, col_mode):
+    """The shared set-up of both Jacobi convergence entries: the launch,
+    resolved as a convergence workload, and the padded, checked state."""
+    _check_storage(storage, quantize)
+    launch = resolve_launch(
+        mesh, filt, np.shape(x), backend=backend, storage=storage,
+        quantize=quantize, boundary=boundary, fuse=fuse, tile=tile,
+        overlap=overlap, col_mode=col_mode, fallback=fallback,
+        check_every=int(check_every))
+    xs, valid_hw, _ = _prepare(x, mesh, launch)
+    _check_quantize_contract(xs, filt, quantize)
+    return launch, xs, valid_hw
 
 
 def sharded_converge(x, filt: Filter, tol: float, max_iters: int,
@@ -1174,7 +1170,7 @@ def sharded_converge(x, filt: Filter, tol: float, max_iters: int,
                      storage: str = "f32", boundary: str = "zero",
                      fuse: int | None = 1,
                      tile: tuple[int, int] | None = None,
-                     interior_split: bool = False, fallback: bool = False,
+                     fallback: bool = False,
                      overlap: bool | None = None, solver: str = "jacobi",
                      mg_levels: int | None = None,
                      col_mode: str | None = None):
@@ -1207,29 +1203,15 @@ def sharded_converge(x, filt: Filter, tol: float, max_iters: int,
         raise ValueError(f"solver must be one of {SOLVERS}, got {solver!r}")
     if mesh is None:
         mesh = make_grid_mesh()
-    _check_storage(storage, quantize)
-    xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage,
-                                      backend, boundary)
-    backend, fuse, tile, overlap, col_mode, _ = _resolve_auto(
-        mesh, filt, backend, fuse, tile, storage, quantize, boundary,
-        tuple(valid_hw), xs.shape[0], check_every=int(check_every),
-        overlap=overlap, col_mode=col_mode)
-    overlap = resolve_overlap(overlap, backend, mesh)
-    col_mode = resolve_col_mode(col_mode, backend, mesh, block_hw,
-                                filt.radius, int(fuse), storage)
-    if fallback:
-        backend = _resolve_fallback(mesh, filt, backend, quantize, fuse,
-                                    boundary, _norm_tile(tile),
-                                    interior_split, storage,
-                                    block_hw=block_hw, overlap=overlap,
-                                    col_mode=col_mode)
-        overlap = kernel_forms.clamp_overlap(overlap, backend)
-        col_mode = clamp_col_mode(col_mode, backend)
-    _check_quantize_contract(xs, filt, quantize)
+    launch, xs, valid_hw = _converge_setup(
+        x, filt, mesh, check_every, backend=backend, storage=storage,
+        quantize=quantize, boundary=boundary, fuse=fuse, tile=tile,
+        fallback=fallback, overlap=overlap, col_mode=col_mode)
+    backend, block_hw = launch.backend, launch.block_hw
     fn = _build_converge(mesh, filt, float(tol), int(max_iters),
                          int(check_every), quantize, valid_hw, block_hw,
-                         backend, boundary, int(fuse), _norm_tile(tile),
-                         interior_split, overlap, col_mode)
+                         backend, boundary, launch.fuse, launch.tile,
+                         launch.overlap, launch.col_mode)
     channels, shape = xs.shape[0], tuple(xs.shape)
     t0 = time.perf_counter()
     # The convergence run is fenced (the count readback), so it gets a
@@ -1244,12 +1226,13 @@ def sharded_converge(x, filt: Filter, tol: float, max_iters: int,
     if obs_metrics.enabled():
         with obs_trace.attach(dsp.context):
             _record_step_obs(backend, mesh, block_hw, filt.radius,
-                             max(1, min(int(fuse),
+                             max(1, min(launch.fuse,
                                         max(1, check_every - 1))),
                              done, channels, storage, boundary,
                              time.perf_counter() - t0, shape, quantize,
-                             _norm_tile(tile), source="sharded_converge",
-                             overlap=overlap, col_mode=col_mode)
+                             launch.tile, source="sharded_converge",
+                             overlap=launch.overlap,
+                             col_mode=launch.col_mode)
     return out[:, : valid_hw[0], : valid_hw[1]].astype(jnp.float32), done
 
 
@@ -1259,7 +1242,6 @@ def sharded_converge_stream(x, filt: Filter, tol: float, max_iters: int,
                             storage: str = "f32", boundary: str = "zero",
                             fuse: int | None = 1,
                             tile: tuple[int, int] | None = None,
-                            interior_split: bool = False,
                             fallback: bool = False,
                             overlap: bool | None = None,
                             solver: str = "jacobi",
@@ -1301,33 +1283,18 @@ def sharded_converge_stream(x, filt: Filter, tol: float, max_iters: int,
         raise ValueError(f"solver must be one of {SOLVERS}, got {solver!r}")
     if mesh is None:
         mesh = make_grid_mesh()
-    _check_storage(storage, quantize)
-    xs, valid_hw, block_hw = _prepare(x, mesh, filt.radius, storage,
-                                      backend, boundary)
-    backend, fuse, tile, overlap, col_mode, _ = _resolve_auto(
-        mesh, filt, backend, fuse, tile, storage, quantize, boundary,
-        tuple(valid_hw), xs.shape[0], check_every=int(check_every),
-        overlap=overlap, col_mode=col_mode)
-    overlap = resolve_overlap(overlap, backend, mesh)
-    col_mode = resolve_col_mode(col_mode, backend, mesh, block_hw,
-                                filt.radius, int(fuse), storage)
-    if fallback:
-        backend = _resolve_fallback(mesh, filt, backend, quantize, fuse,
-                                    boundary, _norm_tile(tile),
-                                    interior_split, storage,
-                                    block_hw=block_hw, overlap=overlap,
-                                    col_mode=col_mode)
-        overlap = kernel_forms.clamp_overlap(overlap, backend)
-        col_mode = clamp_col_mode(col_mode, backend)
-    _check_quantize_contract(xs, filt, quantize)
+    launch, xs, valid_hw = _converge_setup(
+        x, filt, mesh, check_every, backend=backend, storage=storage,
+        quantize=quantize, boundary=boundary, fuse=fuse, tile=tile,
+        fallback=fallback, overlap=overlap, col_mode=col_mode)
     check_every, max_iters = int(check_every), int(max_iters)
     done, diff = 0, float("inf")
     while done < max_iters and diff >= tol:
         n = min(check_every, max_iters - done)
         fn = _build_converge_chunk(mesh, filt, n, quantize, tuple(valid_hw),
-                                   block_hw, backend, boundary, int(fuse),
-                                   _norm_tile(tile), interior_split, overlap,
-                                   col_mode)
+                                   launch.block_hw, launch.backend, boundary,
+                                   launch.fuse, launch.tile, launch.overlap,
+                                   launch.col_mode)
         xs, d = fn(xs)
         diff = float(d)   # the readback fences the chunk
         done += n

@@ -76,17 +76,15 @@ def clear_probe_cache() -> None:
 
 
 def _probe_key(mesh, filt: Filter, backend: str, quantize, fuse, boundary,
-               tile, interior_split, storage, block_hw,
-               overlap=False, col_mode="packed") -> tuple:
+               tile, storage, block_hw, overlap=False,
+               col_mode="packed") -> tuple:
     return (mesh, filt.name, filt.radius, backend, bool(quantize), int(fuse),
-            boundary, tile, bool(interior_split), storage, block_hw,
-            bool(overlap), str(col_mode))
+            boundary, tile, storage, block_hw, bool(overlap), str(col_mode))
 
 
 def probe_backend(mesh, filt: Filter, backend: str, *, quantize: bool = True,
                   fuse: int = 1, boundary: str = "zero",
                   tile: tuple[int, int] | None = None,
-                  interior_split: bool = False,
                   storage: str = "f32",
                   block_hw: tuple[int, int] | None = None,
                   overlap: bool = False,
@@ -106,28 +104,28 @@ def probe_backend(mesh, filt: Filter, backend: str, *, quantize: bool = True,
     compile + ``fuse`` iterations on a zeros block, once per (mesh,
     backend, config) per process.
     """
-    key = _probe_key(mesh, filt, backend, quantize, fuse, boundary, tile,
-                     interior_split, storage, block_hw, overlap, col_mode)
+    launch = _tier_launch(mesh, filt, backend, quantize, fuse, boundary,
+                          tile, storage, block_hw, overlap, col_mode)
+    key = _probe_key(mesh, filt, launch.backend, quantize, launch.fuse,
+                     launch.boundary, launch.tile, launch.storage,
+                     launch.block_hw, launch.overlap, launch.col_mode)
     if key in _PROBE_CACHE:
         err = _PROBE_CACHE[key]
         if err is not None:
             raise err
         return
     try:
-        _run_probe(mesh, filt, backend, quantize, fuse, boundary, tile,
-                   interior_split, storage, block_hw, overlap, col_mode)
+        _run_probe(mesh, filt, launch, quantize)
     except Exception as e:  # noqa: BLE001 — the verdict IS the product
         _PROBE_CACHE[key] = e
         raise
     _PROBE_CACHE[key] = None
 
 
-def _run_probe(mesh, filt, backend, quantize, fuse, boundary, tile,
-               interior_split, storage, block_hw, overlap=False,
-               col_mode="packed") -> None:
-    import jax
-    import numpy as np
-
+def _tier_launch(mesh, filt, backend, quantize, fuse, boundary, tile,
+                 storage, block_hw, overlap, col_mode):
+    """The launch the resolver makes for one walked tier on the caller's
+    block: overlap and column transport clamp to what the tier has."""
     from parallel_convolution_tpu.parallel import step as step_lib
     from parallel_convolution_tpu.parallel.mesh import grid_shape
 
@@ -135,14 +133,28 @@ def _run_probe(mesh, filt, backend, quantize, fuse, boundary, tile,
     fuse = max(1, int(fuse))
     if block_hw is None:
         b = max(8, filt.radius * fuse)
-        block_hw = (b, b)
-    x = np.zeros((1, grid[0] * block_hw[0], grid[1] * block_hw[1]),
-                 np.float32)
-    xs, valid_hw, block_hw = step_lib._prepare(x, mesh, filt.radius, storage)
-    fn = step_lib._build_iterate(mesh, filt, fuse, quantize, valid_hw,
-                                 block_hw, backend, fuse, boundary, tile,
-                                 interior_split, overlap,
-                                 step_lib.clamp_col_mode(col_mode, backend))
+        hw = (grid[0] * b, grid[1] * b)
+    else:
+        hw = (grid[0] * block_hw[0], grid[1] * block_hw[1])
+    return step_lib.resolve_launch(
+        mesh, filt, (1, *hw), backend=backend, storage=storage,
+        quantize=quantize, boundary=boundary, fuse=fuse, tile=tile,
+        overlap=overlap, col_mode=col_mode, block_hw=block_hw)
+
+
+def _run_probe(mesh, filt, launch, quantize) -> None:
+    import jax
+    import numpy as np
+
+    from parallel_convolution_tpu.parallel import step as step_lib
+    from parallel_convolution_tpu.parallel.mesh import grid_shape
+
+    R, C = grid_shape(mesh)
+    bh, bw = launch.block_hw
+    xs, valid_hw, _ = step_lib._prepare(
+        np.zeros((1, R * bh, C * bw), np.float32), mesh, launch)
+    fn = step_lib._iterate_runner(mesh, filt, launch.fuse, quantize,
+                                  valid_hw, launch)
     jax.block_until_ready(fn(xs))
 
 
@@ -161,7 +173,7 @@ def effective_for(requested: str) -> str | None:
 def resolve_backend(mesh, filt: Filter, backend: str, *, quantize: bool = True,
                     fuse: int = 1, boundary: str = "zero",
                     tile: tuple[int, int] | None = None,
-                    interior_split: bool = False, storage: str = "f32",
+                    storage: str = "f32",
                     block_hw: tuple[int, int] | None = None,
                     overlap: bool = False,
                     col_mode: str = "packed",
@@ -185,10 +197,8 @@ def resolve_backend(mesh, filt: Filter, backend: str, *, quantize: bool = True,
     for b in chain:
         try:
             probe_backend(mesh, filt, b, quantize=quantize, fuse=fuse,
-                          boundary=boundary, tile=tile,
-                          interior_split=interior_split, storage=storage,
-                          block_hw=block_hw,
-                          overlap=bool(overlap) and b == "pallas_rdma",
+                          boundary=boundary, tile=tile, storage=storage,
+                          block_hw=block_hw, overlap=overlap,
                           col_mode=col_mode)
         except Exception as e:  # noqa: BLE001
             if classify(e) == TERMINAL:

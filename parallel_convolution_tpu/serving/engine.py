@@ -251,29 +251,27 @@ def bucket_key(key):
 class _Entry:
     """One warm key: resolved backend + compiled runners per batch size."""
 
-    __slots__ = ("key", "effective_backend", "fns", "lock", "plan_source",
-                 "predicted_gpx", "plan_key", "effective_overlap",
-                 "effective_col_mode", "splits", "compile_ref",
-                 "converge_fns", "mg_levels", "compiles")
+    __slots__ = ("key", "launch", "effective_backend", "fns", "lock",
+                 "plan_source", "predicted_gpx", "plan_key",
+                 "effective_overlap", "effective_col_mode", "splits",
+                 "compile_ref", "converge_fns", "mg_levels", "compiles")
 
-    def __init__(self, key: EngineKey, effective_backend: str,
+    def __init__(self, key: EngineKey, launch=None,
                  plan_source: str = "explicit",
                  predicted_gpx: float | None = None,
                  plan_key: str = ""):
         self.key = key
-        self.effective_backend = effective_backend
-        # The overlap knob the executables are ACTUALLY compiled with:
-        # the key's resolved value, re-clamped to False when the degrade
-        # walk left the RDMA tier (only that tier has an overlapped form).
-        self.effective_overlap = bool(
-            key.overlap) and effective_backend == "pallas_rdma"
-        # Same rule for the column transport: re-clamped to the
-        # canonical 'packed' when the degrade walk left the persistent
-        # tier (no column RDMA transport exists elsewhere).
-        from parallel_convolution_tpu.parallel import step as _step_lib
-
-        self.effective_col_mode = _step_lib.clamp_col_mode(
-            key.col_mode, effective_backend)
+        # The launch the executables are ACTUALLY built from
+        # (step.resolve_launch: the degrade walk and its re-clamps, and
+        # the block every prepared batch pads to); None for a rank-3
+        # volume key — one registry program, no lower tier.
+        self.launch = launch
+        self.effective_backend = (launch.backend if launch is not None
+                                  else key.backend)
+        self.effective_overlap = (launch.overlap if launch is not None
+                                  else False)
+        self.effective_col_mode = (launch.col_mode if launch is not None
+                                   else "packed")
         self.plan_source = plan_source       # explicit|measured|
         #                                      interpolated|predicted
         self.predicted_gpx = predicted_gpx   # cost-model Gpx/s/chip
@@ -433,7 +431,6 @@ class WarmEngine:
         from parallel_convolution_tpu.parallel import step as step_lib
 
         kw = dict(kw)
-        plan_source = "explicit"
         if int(kw.get("rank", 2)) == 3:
             # Volumes have no tuning space (one registry path, no tile,
             # no overlap, no column A/B): "auto" normalizes to the
@@ -451,56 +448,31 @@ class WarmEngine:
             if key.fuse > max(1, key.iters):
                 key = dataclasses.replace(key, fuse=max(1, key.iters))
             return key, "explicit"
-        if kw.get("backend") == "auto":
-            from parallel_convolution_tpu import tuning
-
-            res = tuning.resolve(
-                self.mesh, get_filter(kw.get("filter_name", "blur3")),
-                tuple(int(s) for s in shape),
-                storage=kw.get("storage", "f32"),
-                quantize=bool(kw.get("quantize", True)),
-                boundary=kw.get("boundary", "zero"),
-                fuse=kw.get("fuse"), tile=kw.get("tile"),
-                overlap=kw.get("overlap"),
-                col_mode=kw.get("col_mode"),
-                plans=self.plans)
-            kw["backend"] = res.backend
-            kw["fuse"], kw["tile"] = res.fuse, res.tile
-            kw["overlap"] = res.overlap
-            kw["col_mode"] = res.col_mode
-            plan_source = res.source
-        # Settle the overlap knob BEFORE keying (None -> False for
-        # explicit backends; requests clamped to the RDMA tier and the
-        # interpret guard) — two requests that compile the same program
-        # must share one key, and the key must state the compiled form.
-        kw["overlap"] = step_lib.resolve_overlap(
-            kw.get("overlap"), kw.get("backend", "shifted"), self.mesh)
-        # Same pre-keying rule for the column transport: None/'auto'
-        # resolve through the cost model for the persistent tier and
-        # normalize to the canonical 'packed' everywhere else, so an
-        # auto and an explicit request that compile the same program
-        # share one warm executable.
-        from parallel_convolution_tpu.parallel.mesh import (
-            grid_shape as _grid_shape, padded_extent as _padded_extent,
-        )
-
-        (_, _H, _W) = tuple(int(s) for s in shape)
-        _R, _C = _grid_shape(self.mesh)
-        _filt = get_filter(kw.get("filter_name", "blur3"))
-        kw["col_mode"] = step_lib.resolve_col_mode(
-            kw.get("col_mode"), kw.get("backend", "shifted"), self.mesh,
-            (_padded_extent(_H, _R) // _R, _padded_extent(_W, _C) // _C),
-            _filt.radius, max(1, int(kw.get("fuse") or 1)),
-            kw.get("storage", "f32"))
-        if kw.get("fuse") is None and "fuse" in kw:
+        if kw.get("fuse") is None and "fuse" in kw \
+                and kw.get("backend") != "auto":
             # Same contract as RunConfig/ConvolutionModel: fuse=None
             # means 'tune it', which needs backend='auto' — silently
             # running an explicit backend at fuse=1 would accept here
             # what every other entry point rejects as invalid.
             raise ValueError(
                 "fuse=None means 'tune it' and needs backend='auto'")
-        if kw.get("tile") is not None:
-            kw["tile"] = tuple(int(v) for v in kw["tile"])
+        # Key from the resolved launch (backend="auto" through this
+        # engine's plan cache; overlap and column transport settled) —
+        # two requests that compile the same program share one key, and
+        # the key states the compiled form.
+        launch = step_lib.resolve_launch(
+            self.mesh, get_filter(kw.get("filter_name", "blur3")),
+            tuple(int(v) for v in shape),
+            backend=kw.get("backend", "shifted"),
+            storage=kw.get("storage", "f32"),
+            quantize=bool(kw.get("quantize", True)),
+            boundary=kw.get("boundary", "zero"), fuse=kw.get("fuse", 1),
+            tile=kw.get("tile"), overlap=kw.get("overlap"),
+            col_mode=kw.get("col_mode"), plans=self.plans)
+        kw.update(backend=launch.backend, fuse=launch.fuse,
+                  tile=launch.tile, overlap=launch.overlap,
+                  col_mode=launch.col_mode)
+        plan_source = launch.plan_source
         key = EngineKey(shape=tuple(int(s) for s in shape),
                         grid=grid_shape(self.mesh), **kw)
         if key.fuse > max(1, key.iters):
@@ -617,21 +589,21 @@ class WarmEngine:
             plan_key = (f"vol|{key.filter_name}|{key.shape[0]}x"
                         f"{key.shape[1]}x{key.shape[2]}|{key.boundary}"
                         f"|grid={key.grid[0]}x{key.grid[1]}")
-            entry = _Entry(key, key.backend, plan_source="explicit",
+            entry = _Entry(key, plan_source="explicit",
                            predicted_gpx=round(predicted, 3),
                            plan_key=plan_key)
             self._compile_batch(entry, 1)
             return entry
-        effective = key.backend
-        if self.fallback:
-            from parallel_convolution_tpu.resilience import degrade
+        from parallel_convolution_tpu.parallel import step as step_lib
 
-            effective = degrade.resolve_backend(
-                self.mesh, get_filter(key.filter_name), key.backend,
-                quantize=key.quantize, fuse=key.fuse, boundary=key.boundary,
-                tile=key.tile, storage=key.storage,
-                block_hw=self._block_hw(key), overlap=key.overlap,
-                col_mode=key.col_mode)
+        # The key's knobs are already resolved, so this call adds only
+        # the degrade walk (when the engine has one) and its re-clamps.
+        launch = step_lib.resolve_launch(
+            self.mesh, get_filter(key.filter_name), key.shape,
+            backend=key.backend, storage=key.storage,
+            quantize=key.quantize, boundary=key.boundary, fuse=key.fuse,
+            tile=key.tile, overlap=key.overlap, col_mode=key.col_mode,
+            fallback=self.fallback)
         # Cost-model figure for the config actually compiled: every
         # response carries predicted-vs-measured visibility, so a silent
         # mistune (or a degraded tier) shows in per-request artifacts.
@@ -643,16 +615,21 @@ class WarmEngine:
                                quantize=key.quantize,
                                boundary=key.boundary)
         predicted = costmodel.predict_gpx_per_chip(search.predict(
-            w, search.Candidate(
-                effective, key.fuse, key.tile,
-                bool(key.overlap) and effective == "pallas_rdma",
-                key.col_mode)))
+            w, search.Candidate(launch.backend, launch.fuse, launch.tile,
+                                launch.overlap, launch.col_mode)))
         with self._lock:
             source = self._plan_sources.get(key, "explicit")
-        entry = _Entry(key, effective, plan_source=source,
+        launch = dataclasses.replace(launch, plan_source=source)
+        entry = _Entry(key, launch, plan_source=source,
                        predicted_gpx=round(predicted, 3), plan_key=w.key())
         self._compile_batch(entry, 1)
         return entry
+
+    def _entry_block(self, entry: _Entry) -> tuple[int, int]:
+        """The per-device block an entry's executables run on."""
+        if entry.launch is not None:
+            return entry.launch.block_hw
+        return self._block_hw(entry.key)
 
     def _block_hw(self, key: EngineKey) -> tuple[int, int]:
         from parallel_convolution_tpu.parallel.mesh import padded_extent
@@ -675,13 +652,11 @@ class WarmEngine:
             filt = get_filter(key.filter_name)
             # Folded leading dim: batch × channels independent planes.
             probe = np.zeros((batch * C, H, W), np.float32)
-            xs, valid_hw, block_hw = step_lib._prepare(
-                probe, self.mesh, filt.radius, key.storage)
-            fn = step_lib._build_iterate(
-                self.mesh, filt, key.iters, key.quantize, valid_hw,
-                block_hw, entry.effective_backend, key.fuse, key.boundary,
-                key.tile, False, entry.effective_overlap,
-                entry.effective_col_mode)
+            xs, valid_hw, _ = step_lib._prepare(probe, self.mesh,
+                                                entry.launch)
+            fn = step_lib._iterate_runner(self.mesh, filt, key.iters,
+                                          key.quantize, valid_hw,
+                                          entry.launch)
             # Trace + XLA-compile NOW (jit compiles on first call): warm
             # means the request path never sees compilation.
             import jax
@@ -782,8 +757,8 @@ class WarmEngine:
             with obs_trace.span("copy_in", batch=B):
                 folded = np.ascontiguousarray(
                     images.reshape(B * C, H, W).astype(np.float32))
-                xs, valid_hw, _ = step_lib._prepare(
-                    folded, self.mesh, filt.radius, key.storage)
+                xs, valid_hw, _ = step_lib._prepare(folded, self.mesh,
+                                                    entry.launch)
                 jax.block_until_ready(xs)
         # The timer is shared across retry ATTEMPTS (the service re-invokes
         # run_batch with it), so telemetry must charge only THIS call's
@@ -822,7 +797,7 @@ class WarmEngine:
 
             dev0 = self.mesh.devices.flat[0]
             split = attribution.predicted_exchange_split(
-                key.grid, self._block_hw(key), filt.radius,
+                key.grid, self._entry_block(entry), filt.radius,
                 max(1, min(key.fuse, key.iters)),
                 backend=entry.effective_backend, storage=key.storage,
                 shape=(B * C, H, W), tile=key.tile, quantize=key.quantize,
@@ -963,7 +938,7 @@ class WarmEngine:
         dev0 = self.mesh.devices.flat[0]
         attribution.record_step(
             backend=entry.effective_backend, grid=key.grid,
-            block_hw=self._block_hw(key), radius=filt.radius,
+            block_hw=self._entry_block(entry), radius=filt.radius,
             fuse=max(1, min(key.fuse, key.iters)), iters=key.iters,
             channels=B * C, storage=key.storage, boundary=key.boundary,
             wall_s=dev_s, shape=(B * C, H, W), quantize=key.quantize,
@@ -1019,12 +994,13 @@ class WarmEngine:
             key = entry.key
             filt = get_filter(key.filter_name)
             probe = np.zeros(key.shape, np.float32)
-            xs, valid_hw, block_hw = step_lib._prepare(
-                probe, self.mesh, filt.radius, key.storage)
+            launch = entry.launch
+            xs, valid_hw, block_hw = step_lib._prepare(probe, self.mesh,
+                                                       launch)
             fn = step_lib._build_converge_chunk(
                 self.mesh, filt, n, key.quantize, valid_hw, block_hw,
-                entry.effective_backend, key.boundary, key.fuse, key.tile,
-                False, entry.effective_overlap, entry.effective_col_mode)
+                launch.backend, launch.boundary, launch.fuse, launch.tile,
+                launch.overlap, launch.col_mode)
             jax.block_until_ready(fn(xs)[1])  # compile NOW: the stream's
             #                                   first chunk must not pay it
             entry.converge_fns[n] = fn
@@ -1130,7 +1106,7 @@ class WarmEngine:
             return
         xs, valid_hw, _ = step_lib._prepare(
             np.ascontiguousarray(image, dtype=np.float32), self.mesh,
-            filt.radius, key.storage)
+            entry.launch)
         check_every, max_iters = int(check_every), int(max_iters)
         done, diff = start_done, float("inf")   # start_diff >= tol here:
         #                                         the chunk loop re-reads

@@ -338,7 +338,7 @@ def _build_smooth_rhs(mesh: Mesh, filt: Filter, n: int, valid_hw, block_hw,
                            block_hw)
     step = step_lib._make_block_step(
         filt, grid, valid_hw, block_hw, False, backend, 1, boundary, tile,
-        step_lib._mesh_interpret(mesh), False, False, col_mode)
+        step_lib._mesh_interpret(mesh), False, col_mode)
 
     def body(e, r):
         def sweep(_, v):
@@ -380,7 +380,7 @@ def _build_fine_smooth(mesh: Mesh, filt: Filter, n: int, valid_hw, block_hw,
                            block_hw)
     step = step_lib._make_block_step(
         filt, grid, valid_hw, block_hw, False, backend, 1, boundary, tile,
-        step_lib._mesh_interpret(mesh), False, overlap, col_mode)
+        step_lib._mesh_interpret(mesh), overlap, col_mode)
 
     def damped(v, s):
         return ((1.0 - OMEGA) * v + OMEGA * s).astype(v.dtype)
@@ -436,7 +436,7 @@ def _build_residual_restrict(mesh: Mesh, filt: Filter, valid_hw, block_hw,
                            block_hw)
     step = step_lib._make_block_step(
         filt, grid, valid_hw, block_hw, False, backend, 1, boundary, tile,
-        step_lib._mesh_interpret(mesh), False, False, col_mode)
+        step_lib._mesh_interpret(mesh), False, col_mode)
     restrict = kernel_forms.resolve(2, "restrict_fw", boundary).build(
         grid, valid_hw, block_hw, boundary)
 
@@ -575,6 +575,22 @@ def mg_converge_stream(x, filt: Filter, tol: float, max_iters: int,
     form (``auto`` resolves through the tuning subsystem); transfer
     operators always run their registered stencils.
     """
+    if mesh is None:
+        mesh = make_grid_mesh()
+    x = np.asarray(x, np.float32)
+    levels, launch = _mg_resolve(
+        x, filt, mesh, quantize=quantize, backend=backend, storage=storage,
+        boundary=boundary, tile=tile, fallback=fallback, overlap=overlap,
+        mg_levels=mg_levels, col_mode=col_mode)
+    yield from _mg_cycles(x, filt, tol, max_iters, levels, launch,
+                          nu_pre, nu_post, nu_coarse)
+
+
+def _mg_resolve(x, filt: Filter, mesh: Mesh, *, quantize, backend, storage,
+                boundary, tile, fallback, overlap, mg_levels, col_mode):
+    """The level schedule and the smoother's launch, resolved once
+    (``step.resolve_launch``) on the fine level's block at fuse 1 — the
+    sweeps are unfused."""
     if quantize:
         raise ValueError(
             "solver='multigrid' requires quantize=False: corrections are "
@@ -584,31 +600,25 @@ def mg_converge_stream(x, filt: Filter, tol: float, max_iters: int,
         raise ValueError(
             f"solver='multigrid' requires storage='f32', got {storage!r} "
             "(residual/correction fields need full float carries)")
-    if mesh is None:
-        mesh = make_grid_mesh()
-    x = np.asarray(x, np.float32)
+    levels = plan_levels(mesh, x.shape[1:], filt.radius, boundary, mg_levels)
+    launch = step_lib.resolve_launch(
+        mesh, filt, x.shape, backend=backend, storage=storage,
+        quantize=quantize, boundary=boundary, fuse=1, tile=tile,
+        overlap=overlap, col_mode=col_mode, fallback=fallback,
+        block_hw=levels[0].block_hw)
+    return levels, launch
+
+
+def _mg_cycles(x, filt: Filter, tol: float, max_iters: int, levels,
+               launch, nu_pre: int, nu_post: int, nu_coarse: int):
+    """The V-cycle loop of :func:`mg_converge_stream` on a resolved
+    launch."""
     channels, H, W = x.shape
     valid_hw = (int(H), int(W))
-    backend, _, tile, overlap, col_mode, _ = step_lib._resolve_auto(
-        mesh, filt, backend, fuse, tile, storage, quantize, boundary,
-        valid_hw, channels, overlap=overlap, col_mode=col_mode)
-    overlap = step_lib.resolve_overlap(overlap, backend, mesh)
-    tile = step_lib._norm_tile(tile)
-    levels = plan_levels(mesh, valid_hw, filt.radius, boundary, mg_levels)
     fine = levels[0]
-    col_mode = step_lib.resolve_col_mode(
-        col_mode, backend, mesh, fine.block_hw, filt.radius, 1, storage)
-    if fallback:
-        # Probe on the REAL fine-level block (plan_levels pads even only
-        # when a coarser level follows) — kernel-family selection keys on
-        # block_hw, so a mult=2 guess could pass a probe the mult=1
-        # launch then fails.
-        backend = step_lib._resolve_fallback(
-            mesh, filt, backend, quantize, 1, boundary, tile, False,
-            storage=storage, block_hw=fine.block_hw, overlap=overlap,
-            col_mode=col_mode)
-        overlap = kernel_forms.clamp_overlap(overlap, backend)
-        col_mode = step_lib.clamp_col_mode(col_mode, backend)
+    backend, tile, storage = launch.backend, launch.tile, launch.storage
+    overlap, col_mode, boundary = (launch.overlap, launch.col_mode,
+                                   launch.boundary)
     if kernel_forms.persistent_capable(backend):
         # Cache each level's exchange identity on the schedule up front:
         # every cycle's smoother kernels bind these SAME plans.
@@ -708,45 +718,28 @@ def mg_converge(x, filt: Filter, tol: float, max_iters: int,
     if mesh is None:
         mesh = make_grid_mesh()
     x = np.asarray(x, np.float32)
-    channels = x.shape[0]
-    levels = plan_levels(mesh, x.shape[1:], filt.radius, boundary,
-                         mg_levels)
+    levels, launch = _mg_resolve(
+        x, filt, mesh, quantize=quantize, backend=backend, storage=storage,
+        boundary=boundary, tile=tile, fallback=fallback, overlap=overlap,
+        mg_levels=mg_levels, col_mode=col_mode)
     sweeps = _level_sweeps(levels, nu_pre, nu_post, nu_coarse)
     t0 = time.perf_counter()
     out, cycles, diff, wu = x, 0, float("inf"), 0.0
-    stream = mg_converge_stream(
-        x, filt, tol, max_iters, mesh=mesh, quantize=quantize,
-        backend=backend, storage=storage, boundary=boundary, fuse=fuse,
-        tile=tile, fallback=fallback, overlap=overlap, mg_levels=mg_levels,
-        col_mode=col_mode, nu_pre=nu_pre, nu_post=nu_post,
-        nu_coarse=nu_coarse)
-    for out, cycles, diff, wu in stream:
+    for out, cycles, diff, wu in _mg_cycles(x, filt, tol, max_iters, levels,
+                                            launch, nu_pre, nu_post,
+                                            nu_coarse):
         pass
-    # Post-resolution stamps: re-derive what the stream compiled with
-    # (same resolution path, idempotent) so the result row can never
-    # disagree with the program that produced it.
-    b, _, tl, ov, cm, _ = step_lib._resolve_auto(
-        mesh, filt, backend, fuse, tile, storage, quantize, boundary,
-        tuple(int(v) for v in x.shape[1:]), channels, overlap=overlap,
-        col_mode=col_mode)
-    ov = step_lib.resolve_overlap(ov, b, mesh)
-    cm = step_lib.resolve_col_mode(cm, b, mesh, levels[0].block_hw,
-                                   filt.radius, 1, storage)
-    if fallback:
-        from parallel_convolution_tpu.resilience import degrade
-
-        b = degrade.effective_for(b) or b
-        ov = kernel_forms.clamp_overlap(ov, b)
-        cm = step_lib.clamp_col_mode(cm, b)
-    eff_backend, eff_overlap = b, ov
+    # The stamps are the launch the cycles ran, so the result row can
+    # never disagree with the program that produced it.
     res = MGResult(
         cycles=cycles, work_units=round(wu, 3), residual=diff,
         converged=diff < tol, levels=len(levels),
         level_grids=[f"{lv.grid[0]}x{lv.grid[1]}" for lv in levels],
         level_shapes=[f"{lv.valid_hw[0]}x{lv.valid_hw[1]}" for lv in levels],
-        backend=eff_backend, overlap=eff_overlap, col_mode=cm,
+        backend=launch.backend, overlap=launch.overlap,
+        col_mode=launch.col_mode,
         wall_s=round(time.perf_counter() - t0, 4),
         predicted_s_per_cycle=_predict_cycle_seconds(
-            levels, sweeps, filt, eff_backend, channels, False,
-            step_lib._norm_tile(tile)))
+            levels, sweeps, filt, launch.backend, x.shape[0], False,
+            launch.tile))
     return out, res

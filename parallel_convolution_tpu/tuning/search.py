@@ -288,7 +288,7 @@ class TuneResult:
 
 
 def measure(w: Workload, c: Candidate, mesh, *, iters: int = 8,
-            reps: int = 2, interior_split: bool = False) -> dict:
+            reps: int = 2) -> dict:
     """One measured point: a ``bench_iterate`` row for this candidate
     (resolved tile/fuse stamped by bench itself), plus the model's
     prediction for measured-vs-predicted visibility."""
@@ -304,7 +304,7 @@ def measure(w: Workload, c: Candidate, mesh, *, iters: int = 8,
         mesh=mesh, channels=w.shape[0], backend=c.backend,
         quantize=w.quantize, storage=w.storage, fuse=c.fuse,
         boundary=w.boundary, reps=reps, tile=c.tile,
-        interior_split=interior_split, overlap=c.overlap,
+        overlap=c.overlap,
         col_mode=c.col_mode)
     row["predicted_gpx_per_chip"] = round(
         costmodel.predict_gpx_per_chip(predict(w, c)), 3)
@@ -315,8 +315,8 @@ def tune(w: Workload, mesh=None, *, dry_run: bool = False,
          backends=None, fuses=None, tiles=None, overlap: bool | None = None,
          col_mode: str | None = None,
          iters: int = 8,
-         reps: int = 2, max_measure: int = 8, prune_factor: float = 4.0,
-         interior_split: bool = False) -> TuneResult:
+         reps: int = 2, max_measure: int = 8,
+         prune_factor: float = 4.0) -> TuneResult:
     """Tune one workload: rank the legal space, optionally measure.
 
     ``dry_run=True`` (or ``mesh=None``) returns the model's pick with
@@ -346,8 +346,7 @@ def tune(w: Workload, mesh=None, *, dry_run: bool = False,
                  if t <= best_t * prune_factor][:max(1, int(max_measure))]
     for t, c in shortlist:
         try:
-            row = measure(w, c, mesh, iters=iters, reps=reps,
-                          interior_split=interior_split)
+            row = measure(w, c, mesh, iters=iters, reps=reps)
         except Exception as e:  # noqa: BLE001 — an illegal point is data
             rows.append({"backend": c.backend, "fuse": c.fuse,
                          "tile": (f"{c.tile[0]}x{c.tile[1]}" if c.tile

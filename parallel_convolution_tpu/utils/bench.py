@@ -68,7 +68,6 @@ def bench_iterate(
     boundary: str = "zero",
     reps: int = 3,
     tile: tuple[int, int] | None = None,
-    interior_split: bool = False,
     fallback: bool = False,
     overlap: bool | None = None,
     col_mode: str | None = None,
@@ -78,8 +77,7 @@ def bench_iterate(
     ``tile`` overrides the Pallas output-tile shape (None = per-kernel
     default) — passed explicitly because it is a static jit argument;
     monkeypatching the module defaults does NOT reach already-traced
-    kernels.  ``interior_split`` benches the unmasked-interior launch
-    split (fused Pallas backends; any grid since round 5).
+    kernels.
 
     Every row is stamped with ``platform`` and ``device_kind`` (the mesh
     devices' — a CPU row can never read as a chip record) and
@@ -106,40 +104,24 @@ def bench_iterate(
     rng = np.random.default_rng(0)
     x = rng.integers(0, 256, size=(channels, H, W)).astype(np.float32)
 
+    # The knobs the executable will ACTUALLY be compiled with
+    # (step.resolve_launch: auto-resolution, clamps, the degrade walk on
+    # the real block and storage) — stamped below, so a row can never
+    # disagree with the compiled program.
+    launch = step_lib.resolve_launch(
+        mesh, filt, x.shape, backend=backend, storage=storage,
+        quantize=quantize, boundary=boundary, fuse=fuse, tile=tile,
+        overlap=overlap, col_mode=col_mode, fallback=fallback)
+    effective, fuse, tile = launch.backend, launch.fuse, launch.tile
+    overlap, col_mode = launch.overlap, launch.col_mode
+    plan_source = launch.plan_source
     # Time ONLY the on-device iteration: host->device transfer happens
-    # once, and because the runner
-    # donates its input, repetitions chain output->input — padded shape,
-    # dtype and sharding are invariant, exactly the double-buffer reuse the
-    # real pipeline gets.
-    xs, valid_hw, block_hw = step_lib._prepare(x, mesh, filt.radius, storage)
-    effective, fuse, tile, overlap, col_mode, plan_source = (
-        step_lib._resolve_auto(
-            mesh, filt, backend, fuse, tile, storage, quantize, boundary,
-            valid_hw, channels, overlap=overlap, col_mode=col_mode))
-    plan_source = plan_source or "explicit"
-    # The overlap/col_mode knobs the executable will ACTUALLY be
-    # compiled with — stamped below exactly like tile/fuse (post-auto-
-    # resolution, post-clamp), so a row can never disagree with the
-    # compiled program.
-    overlap = step_lib.resolve_overlap(overlap, effective, mesh)
-    col_mode = step_lib.resolve_col_mode(col_mode, effective, mesh,
-                                         block_hw, filt.radius, fuse,
-                                         storage)
-    if fallback:
-        from parallel_convolution_tpu.resilience import degrade
-
-        # Probe on the REAL block geometry + storage: kernel selection
-        # (e.g. pallas_rdma tiled-vs-monolithic) depends on both.
-        effective = degrade.resolve_backend(
-            mesh, filt, effective, quantize=quantize, fuse=fuse,
-            boundary=boundary, tile=tile, interior_split=interior_split,
-            storage=storage, block_hw=block_hw, overlap=overlap,
-            col_mode=col_mode)
-        overlap = overlap and effective == "pallas_rdma"
-        col_mode = step_lib.clamp_col_mode(col_mode, effective)
-    fn = step_lib._build_iterate(mesh, filt, iters, quantize, valid_hw,
-                                 block_hw, effective, fuse, boundary,
-                                 tile, interior_split, overlap, col_mode)
+    # once, and because the runner donates its input, repetitions chain
+    # output->input — padded shape, dtype and sharding are invariant,
+    # exactly the double-buffer reuse the real pipeline gets.
+    xs, valid_hw, block_hw = step_lib._prepare(x, mesh, launch)
+    fn = step_lib._iterate_runner(mesh, filt, iters, quantize, valid_hw,
+                                  launch)
     out = fence(fn(xs))  # compile + warmup
     walls = []
     for _ in range(reps):
@@ -293,12 +275,15 @@ def bench_converge(
     rng = np.random.default_rng(seed)
     x = rng.standard_normal((channels, H, W)).astype(np.float32)
     # Post-resolution stamping, same rule as bench_iterate: resolve
-    # backend="auto"/fuse=None/tile=None through the tuning subsystem
-    # FIRST so the row records the program that actually ran.
-    backend, fuse, tile, overlap, col_mode, _ = step_lib._resolve_auto(
-        mesh, filt, backend, fuse, tile, storage, False, boundary,
-        (H, W), channels, check_every=int(check_every), overlap=overlap,
-        col_mode=col_mode)
+    # backend="auto"/fuse=None/tile=None FIRST so the row records the
+    # program that actually ran.
+    launch = step_lib.resolve_launch(
+        mesh, filt, x.shape, backend=backend, storage=storage,
+        quantize=False, boundary=boundary, fuse=fuse, tile=tile,
+        overlap=overlap, col_mode=col_mode,
+        check_every=int(check_every))
+    backend, fuse, tile = launch.backend, launch.fuse, launch.tile
+    overlap, col_mode = launch.overlap, launch.col_mode
     w = Workload.from_mesh(mesh, filt, (channels, H, W), storage=storage,
                            quantize=False, boundary=boundary)
     dev0 = mesh.devices.flat[0]
@@ -359,10 +344,7 @@ def bench_converge(
             pass
         row.update({
             "effective_backend": backend,
-            "col_mode": step_lib.resolve_col_mode(
-                col_mode, backend, mesh,
-                (-(-H // grid[0]), -(-W // grid[1])), filt.radius,
-                int(fuse), storage),
+            "col_mode": col_mode,
             "converged": diff is not None and diff < tol,
             "residual": diff,
             "iters": iters,

@@ -465,7 +465,6 @@ def run_checkpointed(
     fuse: int = 1,
     boundary: str = "zero",
     tile: tuple[int, int] | None = None,
-    interior_split: bool = False,
     fallback: bool = False,
     overlap: bool | None = None,
     col_mode: str | None = None,
@@ -544,16 +543,15 @@ def run_checkpointed(
 
     while done < total_iters:
         chunk = min(every, total_iters - done)
-        # tile, interior_split, and overlap are pure perf knobs
-        # (bit-identical for any value in every mode), so they are
-        # deliberately NOT part of
+        # tile and overlap are pure perf knobs (bit-identical for any
+        # value in every mode), so they are deliberately NOT part of
         # the resume-compatibility config above.  fuse IS kept there: it
         # is only bit-identical under quantize=True — in float mode with a
         # narrow storage dtype the fused kernel keeps f32 intermediates
         # the unfused path would have rounded through storage every
         # iteration.
         xs = step_lib.iterate_prepared(
-            xs, filt, chunk, mesh, valid_hw, interior_split=interior_split,
+            xs, filt, chunk, mesh, valid_hw,
             quantize=quantize, backend=backend, fuse=min(fuse, chunk),
             boundary=boundary, tile=tile, check_contract=False,
             fallback=fallback, overlap=overlap, col_mode=col_mode,

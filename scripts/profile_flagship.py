@@ -16,17 +16,10 @@ fenced wall), never confirmed by a device trace.  This script:
      - flops/px = 2·2k = 12 for blur3 separable (FMA = 2 flops, MACs
        only) → ``implied_vpu_gflops`` compares against 1 469.8,
      - ops/px/level = 2k FMA + 1 rint + 2 masks = 9 post-elision
-       (FMA = 1 op) → ``implied_vpu_gops`` compares against ~1 350,
-4. optionally (``--ab``) A/Bs the interior split, predicting its gain
-   from the REAL tile geometry: interior_frac · (2 mask ops / 9), the
-   DESIGN.md formula (≈ 0.66 · 2/9 ≈ 15% ceiling at the flagship point,
-   before the ~2% concat cost) — not a 100%-interior upper bound.  The
-   tile geometry comes from ``pallas_stencil.fused_tile_grid`` — the
-   SAME helper the kernel launch uses — so the prediction cannot drift
-   from the real launch.
+       (FMA = 1 op) → ``implied_vpu_gops`` compares against ~1 350.
 
 Usage (chip session):
-  python scripts/profile_flagship.py --size 8192 --fuse 32 --reps 3 --ab
+  python scripts/profile_flagship.py --size 8192 --fuse 32 --reps 3
 """
 
 from __future__ import annotations
@@ -48,15 +41,12 @@ def main() -> int:
     ap.add_argument("--storage", default="bf16")
     ap.add_argument("--tile", default="1024x512")
     ap.add_argument("--reps", type=int, default=3)
-    ap.add_argument("--ab", action="store_true",
-                    help="also run the interior-split A/B leg")
     ap.add_argument("--trace-dir", default="evidence/traces")
     args = ap.parse_args()
 
     import jax
     import numpy as np
 
-    from parallel_convolution_tpu.ops import pallas_stencil
     from parallel_convolution_tpu.ops.filters import get_filter
     from parallel_convolution_tpu.parallel import step as step_lib
     from parallel_convolution_tpu.parallel.mesh import make_grid_mesh
@@ -78,17 +68,18 @@ def main() -> int:
     trace_dir = os.path.join(args.trace_dir,
                              f"flagship_{args.size}_fuse{args.fuse}")
     os.makedirs(trace_dir, exist_ok=True)
-    xs, valid_hw, block_hw = step_lib._prepare(
+    # The same launch bench_iterate resolved, so the build cache hits
+    # and the already-compiled runner is reused (a second 8192^2 Mosaic
+    # compile would add minutes to the run).
+    launch = step_lib.resolve_launch(
+        mesh, filt, (1, args.size, args.size), backend=args.backend,
+        storage=args.storage, fuse=args.fuse, tile=tile)
+    xs, valid_hw, _ = step_lib._prepare(
         np.random.default_rng(0)
         .integers(0, 256, size=(1, args.size, args.size))
-        .astype(np.float32),
-        mesh, filt.radius, args.storage)
-    # Keyword set matches bench_iterate's _build_iterate call exactly so
-    # the lru_cache key collides and the already-compiled runner is
-    # reused (a second 8192^2 Mosaic compile would waste chip minutes).
-    fn = step_lib._build_iterate(mesh, filt, args.iters, True, valid_hw,
-                                 block_hw, args.backend, args.fuse,
-                                 tile=tile, interior_split=False)
+        .astype(np.float32), mesh, launch)
+    fn = step_lib._iterate_runner(mesh, filt, args.iters, True, valid_hw,
+                                  launch)
     out = bench.fence(fn(xs))  # compile + warm, outside the trace
     with device_trace(trace_dir):
         out = bench.fence(fn(out))
@@ -108,44 +99,6 @@ def main() -> int:
     )
     print(json.dumps(row), flush=True)
 
-    if args.ab:
-        # Predicted split gain from the REAL geometry: the masked 2 of
-        # ops_px ops disappear on the interior fraction of tiles only.
-        # fused_tile_grid is the launch's own geometry helper.
-        r, T = filt.radius, args.fuse
-        sep = pallas_stencil._sep_taps(filt, args.backend == "pallas_sep")
-        th, tw, gh, gw = pallas_stencil.fused_tile_grid(
-            (args.size, args.size), step_lib.STORAGE_DTYPES[args.storage],
-            tile, sep)
-        split = pallas_stencil._interior_range(
-            (args.size, args.size), (th, tw), r * T, (gh, gw))
-        fi = fs = 0.0
-        if split is not None:
-            # Count tiles from the launch's OWN patch plan, so this
-            # ledger cannot drift from what actually runs.
-            for (r0b, r1b), (c0b, c1b), (mr, mc) in (
-                    pallas_stencil.split_patches(split, (gh, gw))):
-                n = (r1b - r0b) * (c1b - c0b) / (gh * gw)
-                if not mr and not mc:
-                    fi += n
-                elif not mr or not mc:
-                    fs += n
-
-        row_b = bench.bench_iterate((args.size, args.size), filt, args.iters,
-                                    **kw, interior_split=True)
-        row_b.update(isplit=True, interior_tile_frac=round(fi, 3),
-                     single_mask_tile_frac=round(fs, 3))
-        print(json.dumps(row_b), flush=True)
-        speedup = row_b["gpixels_per_s_per_chip"] / max(gpx, 1e-9)
-        # 9-patch ledger: interior tiles drop 2 of ops_px mask ops,
-        # pure-edge tiles drop 1; a ceiling (concat cost ~2% not
-        # modeled), not a pass bar.
-        predicted = 1.0 / (1.0 - (2.0 * fi + fs) / ops_px)
-        print(json.dumps({
-            "ab": "interior_split",
-            "speedup": round(speedup, 4),
-            "ledger_predicts": round(predicted, 4),
-        }), flush=True)
     return 0
 
 

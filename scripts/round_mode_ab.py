@@ -79,9 +79,11 @@ def child(mode: str, outdir: str) -> int:
     # Byte-proof leg: small deterministic u8 run through the fused path.
     rng = np.random.default_rng(7)
     x = rng.integers(0, 256, size=(1, 512, 640)).astype(np.float32)
-    xs, valid_hw, block_hw = step_lib._prepare(x, mesh, filt.radius, "u8")
-    fn = step_lib._build_iterate(mesh, filt, 10, True, valid_hw, block_hw,
-                                 "pallas_sep", 5)
+    launch = step_lib.resolve_launch(mesh, filt, x.shape,
+                                     backend="pallas_sep", storage="u8",
+                                     fuse=5)
+    xs, valid_hw, _ = step_lib._prepare(x, mesh, launch)
+    fn = step_lib._iterate_runner(mesh, filt, 10, True, valid_hw, launch)
     out = np.asarray(jnp.asarray(fn(xs)))
     np.save(os.path.join(outdir, f"proof_{mode}.npy"),
             out.astype(np.uint8))

@@ -2,7 +2,7 @@
 """Randomized cross-backend byte-compare soak (CPU mesh, offline).
 
 Samples random (backend, storage, boundary, mesh, filter, fuse, tile,
-interior_split, geometry) configurations and requires every one to be
+geometry) configurations and requires every one to be
 byte-identical to the NumPy oracle through the full distributed path
 (`step.sharded_iterate` on the forced 8-virtual-device CPU mesh).  This
 is the tests' bit-exactness property run at campaign scale — the seeded
@@ -113,7 +113,6 @@ def sample(rng: random.Random) -> dict:
         "boundary": rng.choice(["zero", "zero", "periodic"]),
         "storage": rng.choice(["f32", "bf16", "u8"]),
         "fuse": 1,
-        "interior_split": False,
         "tile": None,
         "img_seed": rng.randrange(10_000),
     }
@@ -131,11 +130,6 @@ def sample(rng: random.Random) -> dict:
     if backend in ("pallas", "pallas_sep"):
         if rng.random() < 0.3:
             cfg["tile"] = (8 * rng.randrange(1, 4), 128)
-        # step.py takes the non-split path under periodic; only record
-        # the flag where it is actually exercised.
-        if (backend == "pallas_sep" and cfg["fuse"] > 1
-                and cfg["boundary"] == "zero" and rng.random() < 0.5):
-            cfg["interior_split"] = True
     return cfg
 
 
@@ -250,9 +244,10 @@ def run_fault_trial(spec: str, seed: int, out_path: str) -> int:
     ck = tempfile.mkdtemp(prefix="pctpu_fault_trial_")
 
     crashed = None
+    launch = step.resolve_launch(mesh, filt, x.shape)
     with faults.injected(spec, seed=seed) as plan:
         try:
-            xs, valid_hw, _ = step._prepare(x, mesh, filt.radius)
+            xs, valid_hw, _ = step._prepare(x, mesh, launch)
             checkpoint.run_checkpointed(xs, filt, total, mesh, valid_hw,
                                         ckpt_dir=ck, every=every)
         except Exception as e:  # noqa: BLE001 — the injected crash
@@ -260,7 +255,7 @@ def run_fault_trial(spec: str, seed: int, out_path: str) -> int:
         fired = plan.fired
     # The restarted process: fresh input, no plan — must auto-resume from
     # whatever (possibly torn) checkpoint state the crash left behind.
-    xs2, valid_hw, _ = step._prepare(x, mesh, filt.radius)
+    xs2, valid_hw, _ = step._prepare(x, mesh, launch)
     out = checkpoint.run_checkpointed(xs2, filt, total, mesh, valid_hw,
                                       ckpt_dir=ck, every=every)
     got = np.asarray(out)[:, : valid_hw[0], : valid_hw[1]].astype(np.uint8)
@@ -1381,7 +1376,8 @@ def run_reshape_trial(spec: str, seed: int, out_path: str) -> int:
     crashed = None
     with faults.injected(spec, seed=seed) as plan:
         try:
-            xs, valid_hw, _ = step._prepare(x, mesh8, filt.radius)
+            xs, valid_hw, _ = step._prepare(
+                x, mesh8, step.resolve_launch(mesh8, filt, x.shape))
             checkpoint.run_checkpointed(xs, filt, total, mesh8, valid_hw,
                                         ckpt_dir=ck, every=every, fuse=fuse)
         except Exception as e:  # noqa: BLE001 — the injected crash
@@ -1395,7 +1391,8 @@ def run_reshape_trial(spec: str, seed: int, out_path: str) -> int:
         shutil.copytree(ck, tdir, dirs_exist_ok=True)
         tmesh = mesh_lib.make_grid_mesh(
             jax.devices()[: shape[0] * shape[1]], shape)
-        xs2, valid_hw, _ = step._prepare(x, tmesh, filt.radius)
+        xs2, valid_hw, _ = step._prepare(
+            x, tmesh, step.resolve_launch(tmesh, filt, x.shape))
         try:
             meta = checkpoint.load_meta(tdir)
             resumed_from = None if meta is None else int(meta["iters_done"])
@@ -1696,8 +1693,6 @@ def main() -> int:
                 -(-cfg["H"] // gr) < r * cfg["fuse"]
                 or -(-cfg["W"] // gc) < r * cfg["fuse"]):
             cfg["fuse"] //= 2
-        if cfg["fuse"] == 1 and "interior_split" in cfg:
-            cfg["interior_split"] = False
         row = dict(cfg, i=i, mesh="x".join(map(str, cfg["mesh"])))
         try:
             if args.converge:
@@ -1718,7 +1713,7 @@ def main() -> int:
                     x, filt, cfg["iters"], mesh=mesh, quantize=True,
                     backend=cfg["backend"], storage=cfg["storage"],
                     fuse=cfg["fuse"], boundary=cfg["boundary"],
-                    tile=cfg["tile"], interior_split=cfg["interior_split"])
+                    tile=cfg["tile"])
                 got = imageio.planar_to_interleaved(
                     np.asarray(out).astype(np.uint8))
                 ok = bool(np.array_equal(got, want))

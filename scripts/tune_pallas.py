@@ -33,9 +33,6 @@ def main() -> int:
                          "filtered)")
     ap.add_argument("--fuses", default=None,
                     help="comma list of fusion depths, e.g. 16,32,64")
-    ap.add_argument("--isplit", action="store_true",
-                    help="bench the unmasked-interior launch split "
-                         "(any grid; rows carry isplit:true)")
     args = ap.parse_args()
 
     import jax
@@ -56,17 +53,6 @@ def main() -> int:
     fuses = None
     if args.fuses:
         fuses = tuple(int(v) for v in args.fuses.split(","))
-    if args.isplit:
-        # The split only exists on the fused (fuse > 1) kernel path; a
-        # fuse=1 row stamped isplit:true would record a fabricated no-op
-        # "measurement" in the evidence file.
-        fuses = fuses if fuses is not None else search.FUSE_MENU
-        dropped = [f for f in fuses if f <= 1]
-        fuses = tuple(f for f in fuses if f > 1)
-        if dropped:
-            print(f"# --isplit: dropped fuse{dropped} (split needs fuse>1)",
-                  file=sys.stderr)
-
     candidates = search.enumerate_candidates(
         w, backends=[args.backend], fuses=fuses, tiles=tiles)
     # A requested point the legality filter dropped must leave a row —
@@ -91,10 +77,7 @@ def main() -> int:
     # does NOT reach already-traced kernels.
     for _, c in search.rank(w, candidates):
         try:
-            row = search.measure(w, c, mesh, iters=args.iters, reps=2,
-                                 interior_split=args.isplit)
-            if args.isplit:
-                row.update(isplit=True)
+            row = search.measure(w, c, mesh, iters=args.iters, reps=2)
             results.append(row)
             print(json.dumps(row), flush=True)
         except Exception as e:  # noqa: BLE001 — an illegal point is data

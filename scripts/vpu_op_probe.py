@@ -1,8 +1,8 @@
 #!/usr/bin/env python
 """Price VPU ops with an in-VMEM Pallas chain, to steer kernel-ledger work.
 
-Round-5 context: two silicon A/Bs (interior-split 1.004x, fused-path
-clamp elision ~0-3%) falsified the uniform-op-cost ledger — removing
+Context: two silicon A/Bs (an unmasked-interior launch split
+1.004x, fused-path clamp elision ~0-3%) falsified the uniform-op-cost ledger — removing
 "ops" only pays when the removed op sits on the issue-critical path.
 DESIGN.md names the credible next levers as cutting *FMA or rint* work,
 e.g. integer accumulation folding rint into the u8 store, or the

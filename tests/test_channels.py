@@ -672,10 +672,10 @@ def test_probe_key_distinguishes_col_mode():
     filt = filters.get_filter("blur3")
     mesh = _mesh((1, 1))
     k1 = degrade._probe_key(mesh, filt, "pallas_rdma", True, 1, "zero",
-                            None, False, "f32", (8, 8), overlap=False,
+                            None, "f32", (8, 8), overlap=False,
                             col_mode="packed")
     k2 = degrade._probe_key(mesh, filt, "pallas_rdma", True, 1, "zero",
-                            None, False, "f32", (8, 8), overlap=False,
+                            None, "f32", (8, 8), overlap=False,
                             col_mode="strided")
     assert k1 != k2
 

@@ -174,19 +174,3 @@ def test_fast_preset_resolution(monkeypatch):
     a = ns()
     cli._resolve_perf_knobs(a, m)  # off-TPU: normal defaults
     assert (a.backend, a.storage, a.fuse) == ("shifted", "f32", 1)
-
-
-def test_cli_interior_split_end_to_end(tmp_path):
-    # --interior-split through the CLI on a 1x1 mesh, with a geometry wide
-    # enough to genuinely split; output must stay byte-identical to serial.
-    src = str(tmp_path / "in.raw")
-    cli.main(["generate", src, "45", "300", "grey", "--seed", "31"])
-    out_a = str(tmp_path / "a.raw")
-    out_b = str(tmp_path / "b.raw")
-    assert cli.main(["run", src, "45", "300", "6", "grey", "-o", out_a,
-                     "--mesh", "1x1", "--backend", "pallas_sep",
-                     "--fuse", "3", "--tile", "8,128",
-                     "--interior-split"]) == 0
-    assert cli.main(["serial", src, "45", "300", "6", "grey",
-                     "-o", out_b]) == 0
-    assert cli.main(["compare", out_a, out_b]) == 0

@@ -25,7 +25,7 @@ def _mesh(shape):
 
 def _prepare(img, m, filt):
     x = imageio.interleaved_to_planar(img).astype(np.float32)
-    return step._prepare(x, m, filt.radius)
+    return step._prepare(x, m, step.resolve_launch(m, filt, x.shape))
 
 
 def _make_snapshots(ckpt_dir, img, m, filt, total=6, every=2, fuse=1):

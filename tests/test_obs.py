@@ -371,7 +371,8 @@ def test_iterate_prepared_feeds_halo_counters(grey_small):
     m = mesh_lib.make_grid_mesh(jax.devices()[:8], (2, 4))
     filt = filters.get_filter("blur3")
     x = imageio.interleaved_to_planar(grey_small).astype(np.float32)
-    xs, valid_hw, block_hw = step._prepare(x, m, filt.radius)
+    xs, valid_hw, block_hw = step._prepare(
+        x, m, step.resolve_launch(m, filt, x.shape))
     iters = 3
     step.iterate_prepared(xs, filt, iters, m, valid_hw)
     want = attribution.halo_bytes_total(
@@ -571,7 +572,8 @@ def test_quarantine_counter_names_cause(tmp_path, grey_small):
     m = mesh_lib.make_grid_mesh(jax.devices()[:4], (2, 2))
     filt = filters.get_filter("blur3")
     x = imageio.interleaved_to_planar(grey_small).astype(np.float32)
-    xs, valid_hw, _ = step._prepare(x, m, filt.radius)
+    xs, valid_hw, _ = step._prepare(
+        x, m, step.resolve_launch(m, filt, x.shape))
     checkpoint.save_state(tmp_path, xs, {
         "grid": [2, 2], "shape": list(xs.shape), "iters_done": 4,
         "valid_hw": list(valid_hw)})

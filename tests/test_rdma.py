@@ -574,11 +574,17 @@ def test_prepare_aligns_only_the_rdma_block(storage):
     blocks; every other form keeps the mesh's own share."""
     x = np.zeros((3, 64, 280), np.float32)
     m = _mesh((2, 2))
+    filt = filters.get_filter("blur3")
     sub_v = {"f32": 8, "bf16": 16, "u8": 32}[storage]
+
+    def prepare(backend):
+        return step._prepare(x, m, step.resolve_launch(
+            m, filt, x.shape, backend=backend, storage=storage))
+
     for backend in ("pallas_sep", "pallas", "shifted"):
-        xs, valid, block = step._prepare(x, m, 1, storage, backend)
+        xs, valid, block = prepare(backend)
         assert block == (32, 140) and xs.shape == (3, 64, 280)
-    xs, valid, block = step._prepare(x, m, 1, storage, "pallas_rdma")
+    xs, valid, block = prepare("pallas_rdma")
     assert valid == (64, 280)
     assert block == (-(-32 // sub_v) * sub_v, 256)
     assert xs.shape == (3, 2 * block[0], 512)

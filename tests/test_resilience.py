@@ -41,7 +41,7 @@ def _mesh(shape):
 
 def _prepare(img, m, filt):
     x = imageio.interleaved_to_planar(img).astype(np.float32)
-    return step._prepare(x, m, filt.radius)
+    return step._prepare(x, m, step.resolve_launch(m, filt, x.shape))
 
 
 @pytest.fixture(autouse=True)

@@ -87,9 +87,9 @@ def test_hlo_contains_collective_permute(grey_small):
     # (SURVEY.md §2 'assert-in-HLO' requirement).
     filt = filters.get_filter("blur3")
     m = _mesh((2, 2))
+    x = imageio.interleaved_to_planar(grey_small).astype(np.float32)
     xs, valid_hw, block_hw = step._prepare(
-        imageio.interleaved_to_planar(grey_small).astype(np.float32), m, 1
-    )
+        x, m, step.resolve_launch(m, filt, x.shape))
     fn = step._build_iterate(m, filt, 3, True, valid_hw, block_hw, "shifted")
     hlo = fn.lower(xs).compile().as_text()
     assert "collective-permute" in hlo
@@ -205,21 +205,3 @@ def test_mesh_interpret_resolves_from_mesh_devices():
         devices = np.asarray([[FakeTpuDevice()]])
 
     assert step._mesh_interpret(FakeMesh()) is False
-
-
-@pytest.mark.parametrize("mshape", [(1, 1), (2, 2)])
-def test_converge_interior_split_bitexact(mshape):
-    # The convergence path's fused chunks accept the interior split too
-    # (any grid since round 5); iterate count and bytes must match the
-    # unsplit run exactly.
-    img = imageio.generate_test_image(45, 300, "grey", seed=23)
-    x = imageio.interleaved_to_planar(img).astype(np.float32)
-    filt = filters.get_filter("jacobi3")
-    m = mesh_lib.make_grid_mesh(
-        jax.devices()[: mshape[0] * mshape[1]], mshape)
-    kw = dict(tol=0.05, max_iters=40, check_every=5, mesh=m,
-              backend="pallas_sep", fuse=3, tile=(8, 128))
-    out_a, it_a = step.sharded_converge(x, filt, **kw)
-    out_b, it_b = step.sharded_converge(x, filt, interior_split=True, **kw)
-    assert it_a == it_b
-    np.testing.assert_array_equal(np.asarray(out_a), np.asarray(out_b))

@@ -83,7 +83,8 @@ def test_fused_halo_exchanges_deep_slabs(grey_small):
     filt = filters.get_filter("blur3")
     m = _mesh((2, 2))
     x = imageio.interleaved_to_planar(grey_small).astype(np.float32)
-    xs, valid_hw, block_hw = step._prepare(x, m, 1)
+    xs, valid_hw, block_hw = step._prepare(
+        x, m, step.resolve_launch(m, filt, x.shape))
 
     def depths(fuse):
         return _slab_depths(step._build_iterate(
@@ -101,7 +102,8 @@ def test_fused_convergence_exchanges_deep_slabs(grey_small):
     filt = filters.get_filter("blur3")
     m = _mesh((2, 2))
     x = imageio.interleaved_to_planar(grey_small).astype(np.float32)
-    xs, valid_hw, block_hw = step._prepare(x, m, 1)
+    xs, valid_hw, block_hw = step._prepare(
+        x, m, step.resolve_launch(m, filt, x.shape))
 
     def depths(fuse):
         return _slab_depths(step._build_converge(
@@ -154,256 +156,10 @@ def test_fused_pallas_multi_tile():
     try:
         out = step._build_iterate.__wrapped__(
             _mesh((2, 2)), filt, 4, True, (48, 300), (24, 150), "pallas", 2
-        )(step._prepare(x, _mesh((2, 2)), 1)[0])
+        )(step._prepare(x, _mesh((2, 2)),
+                        step.resolve_launch(_mesh((2, 2)), filt,
+                                            x.shape))[0])
     finally:
         pallas_stencil.DEFAULT_TILE = old
     got = np.asarray(out)[:, :48, :300].astype(np.uint8)
     np.testing.assert_array_equal(got[0], want)
-
-
-def test_interior_range_geometry():
-    # The split's static tile classification, directly.
-    from parallel_convolution_tpu.ops.pallas_stencil import _interior_range
-
-    # 45x300, tile 8x128, depth 3: rows [1,4] of 6, col [1,1] of 3.
-    assert _interior_range((45, 300), (8, 128), 3, (6, 3)) == ((1, 4), (1, 1))
-    # Too narrow for any interior column -> no split.
-    assert _interior_range((45, 150), (8, 128), 3, (6, 2)) is None
-    # Depth deeper than one tile row: i_lo rounds up past row 1.
-    assert _interior_range((64, 300), (8, 128), 10, (8, 3)) == ((2, 5), (1, 1))
-
-
-@pytest.mark.parametrize("hw,tile", [
-    ((45, 300), (8, 128)),    # interior = rows [1,4] x col [1,1]
-    ((45, 150), (8, 128)),    # no interior column -> fallback single call
-    ((64, 520), (16, 128)),   # dividing height, 2 interior cols
-])
-def test_interior_split_bitexact(hw, tile):
-    # Unmasked-interior launch split vs the single masked call: identical
-    # bytes whether the geometry yields several, one, or zero interior
-    # tiles (the zero case must silently fall back).
-    img = imageio.generate_test_image(hw[0], hw[1], "grey", seed=19)
-    filt = filters.get_filter("blur3")
-    x = imageio.interleaved_to_planar(img).astype(np.float32)
-    m = _mesh((1, 1))
-    base = step.sharded_iterate(x, filt, 6, mesh=m, quantize=True,
-                                backend="pallas_sep", fuse=3, tile=tile)
-    split = step.sharded_iterate(x, filt, 6, mesh=m, quantize=True,
-                                 backend="pallas_sep", fuse=3, tile=tile,
-                                 interior_split=True)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(split))
-    want = oracle.run_serial_u8(img, filt, 6)
-    got = imageio.planar_to_interleaved(np.asarray(split).astype(np.uint8))
-    np.testing.assert_array_equal(got, want)
-
-
-def test_interior_split_rgb_radius2_u8():
-    # radius-2 filter (deeper rings), RGB, u8 carries, non-dividing shape
-    # wide enough that the split is genuinely active.
-    from parallel_convolution_tpu.ops.pallas_stencil import _interior_range
-
-    img = imageio.generate_test_image(45, 300, "rgb", seed=21)
-    filt = filters.get_filter("gaussian5")
-    assert _interior_range((45, 300), (8, 128), 2 * 2, (6, 3)) is not None
-    x = imageio.interleaved_to_planar(img).astype(np.float32)
-    m = _mesh((1, 1))
-    out = step.sharded_iterate(x, filt, 4, mesh=m, quantize=True,
-                               backend="pallas", storage="u8", fuse=2,
-                               tile=(8, 128), interior_split=True)
-    want = oracle.run_serial_u8(img, filt, 4)
-    got = imageio.planar_to_interleaved(np.asarray(out).astype(np.uint8))
-    np.testing.assert_array_equal(got, want)
-
-
-def test_interior_split_noop_on_fuse1(grey_odd):
-    # The split only exists on the fused (fuse > 1) Pallas kernel path;
-    # with fuse=1 the flag must be a silent no-op with identical results.
-    filt = filters.get_filter("blur3")
-    x = imageio.interleaved_to_planar(grey_odd).astype(np.float32)
-    m = _mesh((1, 1))
-    a = step.sharded_iterate(x, filt, 3, mesh=m, quantize=True,
-                             backend="pallas_sep", fuse=1)
-    b = step.sharded_iterate(x, filt, 3, mesh=m, quantize=True,
-                             backend="pallas_sep", fuse=1,
-                             interior_split=True)
-    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-
-
-def test_interior_range_offset_classes():
-    # The per-axis offset classes and the offset-range interior ranges
-    # that make the split sound on multi-device grids.
-    from parallel_convolution_tpu.ops.pallas_stencil import (
-        _interior_range, axis_offset_classes)
-
-    assert axis_offset_classes(1, 64) == [(0, 0)]
-    assert axis_offset_classes(2, 64) == [(0, 0), (64, 64)]
-    assert axis_offset_classes(4, 64) == [(0, 0), (64, 128), (192, 192)]
-    # Image (128, 512) on a 2x2 device grid -> blocks (64, 256), kernel
-    # tiles (16, 128) -> per-block tile grid (4, 2), depth 4.
-    # Top-left block (offset (0, 0)): tile row 0 / col 0 cross the image's
-    # top/left edge; the bottom/right tiles see neighbor data via the halo,
-    # so they are interior w.r.t. the IMAGE.
-    assert _interior_range((128, 512), (16, 128), 4, (4, 2),
-                           ((0, 0), (0, 0))) == ((1, 3), (1, 1))
-    # Bottom-right block (offset (64, 256)): the far tiles cross H/W.
-    assert _interior_range((128, 512), (16, 128), 4, (4, 2),
-                           ((64, 64), (256, 256))) == ((0, 2), (0, 0))
-    # Middle-band row range (offsets 64..128 of a 4-high grid over 256
-    # rows): conservative over the whole band -> every tile row interior.
-    assert _interior_range((256, 512), (16, 128), 4, (4, 4),
-                           ((64, 128), (0, 0))) == ((0, 3), (1, 2))
-
-
-@pytest.mark.parametrize("mshape", [(2, 2), (2, 4), (4, 2)])
-def test_interior_split_multichip_bitexact(mshape):
-    # The generalized split on real multi-device grids: every device
-    # dispatches to its edge-class launch, masked borders keep dynamic
-    # offsets, and the bytes match both the unsplit run and the oracle.
-    # 90x300 is non-divisible by every grid here (pad-rim devices too).
-    img = imageio.generate_test_image(90, 300, "grey", seed=23)
-    filt = filters.get_filter("blur3")
-    x = imageio.interleaved_to_planar(img).astype(np.float32)
-    m = _mesh(mshape)
-    kw = dict(quantize=True, backend="pallas_sep", fuse=3, tile=(8, 128))
-    base = step.sharded_iterate(x, filt, 6, mesh=m, **kw)
-    split = step.sharded_iterate(x, filt, 6, mesh=m, interior_split=True,
-                                 **kw)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(split))
-    want = oracle.run_serial_u8(img, filt, 6)
-    got = imageio.planar_to_interleaved(np.asarray(split).astype(np.uint8))
-    np.testing.assert_array_equal(got, want)
-
-
-def test_interior_split_multichip_u8():
-    # u8 carries (sublane 32 -> coarser tile rounding) + the class split
-    # on a 2x2 grid; bit-exact vs unsplit and the oracle.
-    img = imageio.generate_test_image(90, 300, "grey", seed=31)
-    filt = filters.get_filter("blur3")
-    x = imageio.interleaved_to_planar(img).astype(np.float32)
-    m = _mesh((2, 2))
-    kw = dict(quantize=True, backend="pallas_sep", fuse=3, tile=(8, 128),
-              storage="u8")
-    base = step.sharded_iterate(x, filt, 6, mesh=m, **kw)
-    split = step.sharded_iterate(x, filt, 6, mesh=m, interior_split=True,
-                                 **kw)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(split))
-    want = oracle.run_serial_u8(img, filt, 6)
-    got = imageio.planar_to_interleaved(np.asarray(split).astype(np.uint8))
-    np.testing.assert_array_equal(got, want)
-
-
-def test_interior_split_multichip_bf16_radius2():
-    # Deep rings (radius-2, fuse=2 -> depth 4) + bf16 carries on a 2x2
-    # grid; bit-exact vs the unsplit fused path and the oracle.
-    img = imageio.generate_test_image(64, 300, "rgb", seed=29)
-    filt = filters.get_filter("gaussian5")
-    x = imageio.interleaved_to_planar(img).astype(np.float32)
-    m = _mesh((2, 2))
-    kw = dict(quantize=True, backend="pallas", fuse=2, tile=(8, 128),
-              storage="bf16")
-    base = step.sharded_iterate(x, filt, 4, mesh=m, **kw)
-    split = step.sharded_iterate(x, filt, 4, mesh=m, interior_split=True,
-                                 **kw)
-    np.testing.assert_array_equal(np.asarray(base), np.asarray(split))
-    want = oracle.run_serial_u8(img, filt, 4)
-    got = imageio.planar_to_interleaved(np.asarray(split).astype(np.uint8))
-    np.testing.assert_array_equal(got, want)
-
-
-def test_interior_split_requires_block_off():
-    # The unmasked-interior contract is now enforced — a direct
-    # caller on a sharded layout cannot silently skip ghost-ring masking.
-    from parallel_convolution_tpu.ops import pallas_stencil
-
-    filt = filters.get_filter("blur3")
-    import jax.numpy as jnp
-    p = jnp.zeros((1, 38, 140), jnp.float32)
-    with pytest.raises(ValueError, match="block_off"):
-        pallas_stencil.fused_iterate_pallas(
-            p, jnp.zeros((2,), jnp.int32), filt, 3, (32, 134),
-            tile=(8, 128), interior_split=True)
-
-
-def test_interior_split_geometry_fuzz():
-    # Seeded sweep: 8 grids x alternating radius x random fuse, block
-    # sizes, pad-rim shaves, and kernel tiles.  The class-based split
-    # must stay bit-identical to the unsplit fused run everywhere —
-    # including depth-vs-block edge cases, pad-rim devices, and
-    # geometries where some or all classes have no interior tiles.
-    # Guards the conservative middle-band box math beyond the
-    # hand-picked cases above.
-    rng = np.random.default_rng(1234)
-    filts = [filters.get_filter("blur3"), filters.get_filter("gaussian5")]
-    tiles = [(8, 128), (16, 128), (8, 256), (24, 128)]
-    for trial in range(8):
-        filt = filts[trial % 2]
-        r = filt.radius
-        grid = [(1, 2), (2, 1), (2, 2), (2, 4), (4, 2), (1, 4),
-                (4, 1), (2, 3)][trial]
-        tile = tiles[trial % 4]
-        fuse = int(rng.integers(2, 4))
-        depth = r * fuse
-        # Blocks must fit the fused halo; keep shapes small but awkward.
-        bh = depth + int(rng.integers(2, 40))
-        bw = depth + int(rng.integers(2, 170))
-        H = bh * grid[0] - int(rng.integers(0, min(bh - depth, 3) + 1))
-        W = bw * grid[1] - int(rng.integers(0, min(bw - depth, 3) + 1))
-        img = imageio.generate_test_image(H, W, "grey", seed=100 + trial)
-        x = imageio.interleaved_to_planar(img).astype(np.float32)
-        m = _mesh(grid)
-        kw = dict(quantize=True, backend="pallas", fuse=fuse, tile=tile)
-        base = step.sharded_iterate(x, filt, fuse * 2, mesh=m, **kw)
-        split = step.sharded_iterate(x, filt, fuse * 2, mesh=m,
-                                     interior_split=True, **kw)
-        np.testing.assert_array_equal(
-            np.asarray(base), np.asarray(split),
-            err_msg=f"trial {trial}: grid={grid} HxW={H}x{W} "
-                    f"filt={filt.name} fuse={fuse} tile={tile}")
-
-
-def test_interior_range_sound_over_offset_classes():
-    # Pure-math soundness fuzz, independent of the kernels: for ANY
-    # geometry and ANY concrete device offset inside a class's (lo, hi)
-    # range, every tile inside the box _interior_range returns must have
-    # its level-0 window fully inside the image — the property that makes
-    # skipping its ghost-ring masks an identity.  200 random points.
-    from parallel_convolution_tpu.ops.pallas_stencil import (
-        _interior_range, axis_offset_classes)
-
-    rng = np.random.default_rng(7)
-    boxes = 0
-    for _ in range(200):
-        th = 8 * int(rng.integers(1, 24))
-        tw = 128 * int(rng.integers(1, 6))
-        depth = int(rng.integers(1, 80))
-        n_r = int(rng.integers(1, 5))
-        n_c = int(rng.integers(1, 5))
-        bh = depth + int(rng.integers(1, 2048))
-        bw = depth + int(rng.integers(1, 2048))
-        H = bh * n_r - int(rng.integers(0, min(bh - depth, 64) + 1))
-        W = bw * n_c - int(rng.integers(0, min(bw - depth, 64) + 1))
-        gh, gw = -(-bh // th), -(-bw // tw)
-        for rcls in axis_offset_classes(n_r, bh):
-            for ccls in axis_offset_classes(n_c, bw):
-                box = _interior_range((H, W), (th, tw), depth, (gh, gw),
-                                      (rcls, ccls))
-                if box is None:
-                    continue
-                boxes += 1
-                (i_lo, i_hi), (j_lo, j_hi) = box
-                # Check the EXTREME offsets of the class range; interior-
-                # ness is monotone in the offset, so ends suffice — but
-                # test a midpoint too in case that assumption rots.
-                r_offs = {rcls[0], rcls[1], (rcls[0] + rcls[1]) // 2}
-                c_offs = {ccls[0], ccls[1], (ccls[0] + ccls[1]) // 2}
-                for r0 in r_offs:
-                    for i in (i_lo, i_hi):
-                        assert r0 + i * th - depth >= 0, (rcls, box)
-                        assert r0 + i * th + th + depth <= H, (rcls, box)
-                for c0 in c_offs:
-                    for j in (j_lo, j_hi):
-                        assert c0 + j * tw - depth >= 0, (ccls, box)
-                        assert c0 + j * tw + tw + depth <= W, (ccls, box)
-    # Anti-vacuity: a regression that collapses every box to None must
-    # fail here, not silently skip all 200 trials.
-    assert boxes > 50, f"only {boxes} non-None boxes across the sweep"

@@ -152,9 +152,11 @@ def test_rgb_2520_cell_launch_on_2x2_mesh(topo):
     assert text.count("tpu_custom_call") == 2
     assert "collective-permute" not in text
     assert "u8[3,960,1280]" in text
-    block = step.block_extent((1920, 2520), (2, 2), "pallas_rdma", "u8",
-                              "zero")
-    runner, _ = step._iterate_runner(
-        mesh, filt, 100, True, (1920, 2520), block, 3, "u8", "pallas_rdma",
-        16, "zero", None, False, False, None, None)
+    launch = step.resolve_launch(mesh, filt, (3, 1920, 2520),
+                                 backend="pallas_rdma", storage="u8",
+                                 fuse=16)
+    assert launch.block_hw == step.block_extent(
+        (1920, 2520), (2, 2), "pallas_rdma", "u8", "zero")
+    runner = step._iterate_runner(mesh, filt, 100, True, (1920, 2520),
+                                  launch)
     assert runner.tap_form == "separable"

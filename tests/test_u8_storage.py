@@ -107,7 +107,8 @@ def test_u8_iterate_prepared_guard(grey_small):
     filt = filters.get_filter("blur3")
     mesh = _mesh((2, 2))
     x = imageio.interleaved_to_planar(grey_small).astype(np.float32)
-    xs, valid_hw, _ = step._prepare(x, mesh, filt.radius, "u8")
+    xs, valid_hw, _ = step._prepare(
+        x, mesh, step.resolve_launch(mesh, filt, x.shape, storage="u8"))
     with pytest.raises(ValueError, match="quantize"):
         step.iterate_prepared(xs, filt, 2, mesh, valid_hw, quantize=False)
 
