@@ -130,17 +130,21 @@ class ConvolutionModel:
     def run_planar(self, x, iters: int) -> jnp.ndarray:
         """(C, H, W) f32 in → (C, H, W) f32 out after ``iters`` iterations.
 
-        Its host work sits in the profiler span ``pctpu.run_planar``, with
-        ``pctpu.resolve`` and the step layer's ``pctpu.step.*`` inside."""
+        One device program a call (``step._build_job``: cast, pad,
+        iterate, crop, cast back); ``x`` is left as it was, in every
+        storage.  Its host work sits in the profiler span
+        ``pctpu.run_planar``, with ``pctpu.resolve`` and the step layer's
+        ``pctpu.step.*`` inside."""
         with obs_trace.span("pctpu.run_planar"):
             return step_lib._run_launch(
                 x, self.filt, iters, self.mesh,
                 self._resolved_knobs(x.shape), self.quantize)
 
     def iterate_text(self, shape, iters: int) -> str:
-        """The compiled text of the iterate executable ``run_planar`` runs
-        for a (C, H, W) input of ``shape``: ``step.op_scopes`` maps its
-        instructions, as a device trace names them, to their layer."""
+        """The compiled text of the job program ``run_planar`` runs for a
+        (C, H, W) float32 input of ``shape`` in the mesh's block sharding:
+        ``step.op_scopes`` maps its instructions, as a device trace names
+        them, to their layer."""
         return step_lib._launch_text(shape, self.filt, iters, self.mesh,
                                      self._resolved_knobs(shape),
                                      self.quantize)

@@ -49,6 +49,7 @@ from parallel_convolution_tpu.parallel.mesh import (
     grid_shape,
     make_grid_mesh,
     padded_extent,
+    replicated,
 )
 from parallel_convolution_tpu.resilience.faults import fault_point
 from parallel_convolution_tpu.utils.config import (  # canonical registries
@@ -457,7 +458,9 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     ``tap_form`` names the stencil's tap arithmetic (:func:`_tap_form`);
     ``launch_args(planes, dtype)``, on a form that describes its launch
     (``pallas_rdma``: kernel, column transport, overlap, block), what the
-    build span says of a full chunk, else None.
+    build span says of a full chunk, else None.  ``sharded`` is the
+    un-jitted ``shard_map`` it compiles, which :func:`_build_job` builds
+    on.
     """
     # Consulted only on lru_cache misses — i.e. exactly when a fresh
     # trace/compile happens, the event the 'backend_compile' site models.
@@ -514,6 +517,7 @@ def _build_iterate(mesh: Mesh, filt: Filter, iters: int, quantize: bool,
     runner.halo_counts = halo_counts
     runner.tap_form = _tap_form(backend, filt, quantize)
     runner.launch_args = launch_args
+    runner.sharded = sharded
     return runner
 
 
@@ -733,7 +737,8 @@ _minmax_f32 = jax.jit(
     lambda a: jnp.stack([jnp.min(a), jnp.max(a)]).astype(jnp.float32))
 
 
-def _check_quantize_contract(xs, filt: Filter, quantize: bool) -> None:
+def _check_quantize_contract(xs, filt: Filter, quantize: bool,
+                             storage=None) -> None:
     """Fail loudly on out-of-contract quantize-mode inputs.
 
     ``quantize=True`` assumes u8-range pixel values (a decoded image,
@@ -744,14 +749,19 @@ def _check_quantize_contract(xs, filt: Filter, quantize: bool) -> None:
     input per run (negligible vs the iterations) turns that into an error.
     Traced callers skip the check: the contract stays documented but is
     unverifiable mid-trace.
+
+    ``storage`` is the dtype ``xs`` will be cast to before it iterates
+    (default: its own).  The cast rounds monotonically, so the extremes of
+    the cast values are the cast extremes: the check reads ``xs`` as it is.
     """
-    if not (quantize and filt.convex) or jnp.dtype(xs.dtype) == jnp.uint8:
+    storage = jnp.dtype(xs.dtype if storage is None else storage)
+    if not (quantize and filt.convex) or storage == jnp.uint8:
         return
     if isinstance(xs, jax.core.Tracer):
         return
     # One fused device reduction + one 2-float readback (separate min/max
     # dispatches would each stream the whole array from HBM).
-    lo, hi = (float(v) for v in _minmax_f32(xs))
+    lo, hi = (float(v) for v in np.asarray(_minmax_f32(xs)).astype(storage))
     if lo < 0.0 or hi > 255.0:
         raise ValueError(
             f"quantize=True input has values in [{lo}, {hi}], outside the "
@@ -1026,11 +1036,11 @@ def iterate_text(shape, filt: Filter, iters: int, mesh: Mesh | None = None,
                  fallback: bool = False,
                  overlap: bool | None = None,
                  col_mode: str | None = None) -> str:
-    """The compiled text of the iterate executable that
-    :func:`sharded_iterate` runs for a (C, H, W) image of ``shape`` with
-    these knobs — what :func:`op_scopes` reads to join a device trace's
-    ops to their layer.  After a run of the same shape jit's caches hold
-    the executable, so this compiles nothing new."""
+    """The compiled text of the job program that :func:`sharded_iterate`
+    runs for a (C, H, W) float32 image of ``shape`` with these knobs —
+    what :func:`op_scopes` reads to join a device trace's ops to their
+    layer.  After a run of the same shape jit's caches hold the
+    executable, so this compiles nothing new."""
     if mesh is None:
         mesh = make_grid_mesh()
     launch = resolve_launch(
@@ -1042,14 +1052,14 @@ def iterate_text(shape, filt: Filter, iters: int, mesh: Mesh | None = None,
 
 def _launch_text(shape, filt: Filter, iters: int, mesh: Mesh,
                  launch: Launch, quantize: bool) -> str:
-    """:func:`iterate_text` of a resolved launch."""
-    C, H, W = (int(v) for v in shape)
-    R, Cc = grid_shape(mesh)
-    bh, bw = launch.block_hw
-    fn = _iterate_runner(mesh, filt, iters, quantize, (H, W), launch)
-    arg = jax.ShapeDtypeStruct((C, bh * R, bw * Cc),
-                               STORAGE_DTYPES[launch.storage],
-                               sharding=block_sharding(mesh))
+    """:func:`iterate_text` of a resolved launch: the job program
+    (:func:`_build_job`) lowered for a float32 image in the mesh's block
+    sharding, the sharding of an image laid out for the grid (an image
+    the grid does not divide has none, and is lowered unsharded)."""
+    shape = tuple(int(v) for v in shape)
+    runner = _iterate_runner(mesh, filt, iters, quantize, shape[1:], launch)
+    fn = _build_job(runner, mesh, launch, shape, jnp.dtype(jnp.float32))
+    arg = jax.ShapeDtypeStruct(shape, jnp.float32, sharding=fn.sharding)
     return fn.lower(arg).compile().as_text()
 
 
@@ -1064,10 +1074,10 @@ def op_scopes(compiled_text: str) -> dict[str, str]:
 
     The scope is the innermost ``pctpu.*`` named scope in the
     instruction's ``op_name`` metadata (``pctpu.halo``,
-    ``pctpu.kernel_io``), "" where there is none.  A fusion takes its
-    root's ``op_name``.  A device trace's op events carry the same
-    instruction names (``pad.20``, ``concatenate.7``), so this reads a
-    trace by layer."""
+    ``pctpu.kernel_io``, ``pctpu.job_io``), "" where there is none.  A
+    fusion takes its root's ``op_name``.  A device trace's op events
+    carry the same instruction names (``pad.20``, ``concatenate.7``), so
+    this reads a trace by layer."""
     own: dict[str, str | None] = {}
     fusion_calls: dict[str, str] = {}
     root_of: dict[str, str] = {}
@@ -1138,14 +1148,97 @@ def sharded_iterate(x, filt: Filter, iters: int, mesh: Mesh | None = None,
     return _run_launch(x, filt, iters, mesh, launch, quantize)
 
 
+@lru_cache(maxsize=64)
+def _build_job(runner, mesh: Mesh, launch: Launch,
+               shape: tuple[int, int, int], dtype):
+    """Compile one whole :func:`sharded_iterate` job of a resolved launch
+    on a (C, H, W) input of ``shape`` and ``dtype`` as ONE program: the
+    cast to the launch's storage, the zero pad to its blocks (where they
+    exceed the image's share of the grid), the iterations of ``runner``'s
+    un-jitted body (``runner.sharded``; the donating runner itself is not
+    called), the crop back to (H, W) and the cast to float32.
+
+    The cast, pad and crop sit under the named scope ``pctpu.job_io``,
+    apart from ``pctpu.halo`` and ``pctpu.kernel_io``.  The program
+    donates nothing: the caller's array survives in every storage.  Its
+    output is in the mesh's block sharding where the grid divides (H, W),
+    else where the compiler puts it; ``sharding`` is that block sharding,
+    or None where the grid does not divide the image.  ``traced`` says
+    whether the program has traced; ``count()`` is the
+    :class:`halo.HaloCount` of one call once it has.
+    """
+    C, H, W = shape
+    R, Cc = grid_shape(mesh)
+    bh, bw = launch.block_hw
+    Hp, Wp = bh * R, bw * Cc
+    if Hp < H or Wp < W:
+        raise ValueError(
+            f"launch block {launch.block_hw} on grid {(R, Cc)} does not "
+            f"hold a {H}x{W} image")
+    storage = jnp.dtype(STORAGE_DTYPES[launch.storage])
+    pad = (Hp, Wp) != (H, W)
+    blocks = block_sharding(mesh)
+
+    def job(x):
+        fn.traced = True    # the body runs only while jit traces
+        with jax.named_scope("pctpu.job_io"):
+            xs = x.astype(storage)
+            if pad:
+                xs = jnp.pad(xs, ((0, 0), (0, Hp - H), (0, Wp - W)))
+            xs = lax.with_sharding_constraint(xs, blocks)
+        out = runner.sharded(xs)
+        with jax.named_scope("pctpu.job_io"):
+            if pad:
+                out = lax.slice(out, (0, 0, 0), (C, H, W))
+            return out.astype(jnp.float32)
+
+    grid_sharded = H % R == 0 and W % Cc == 0
+    fn = jax.jit(job, out_shardings=blocks if grid_sharded else None)
+    fn.sharding = blocks if grid_sharded else None
+    fn.traced = False
+    block = ((C, bh, bw), storage)
+    fn.count = lambda: runner.halo_counts[block]
+    return fn
+
+
 def _run_launch(x, filt: Filter, iters: int, mesh: Mesh, launch: Launch,
                 quantize: bool):
-    """:func:`sharded_iterate` on a resolved launch: pad, iterate, crop."""
-    with obs_trace.span("pctpu.step.prepare"):
-        xs, valid_hw, _ = _prepare(x, mesh, launch)
-    out = _iterate_launch(xs, filt, iters, mesh, valid_hw, launch, quantize)
-    with obs_trace.span("pctpu.step.crop"):
-        return out[:, : valid_hw[0], : valid_hw[1]].astype(jnp.float32)
+    """:func:`sharded_iterate` on a resolved launch: look up the job
+    program (:func:`_build_job`) and dispatch it, one call per job."""
+    if not isinstance(x, (np.ndarray, jax.Array)):
+        x = np.asarray(x)
+    shape = tuple(int(v) for v in x.shape)
+    storage = jnp.dtype(STORAGE_DTYPES[launch.storage])
+    with obs_trace.span("pctpu.step.build") as sp:
+        _check_quantize_contract(x, filt, quantize, storage)
+        runner = _iterate_runner(mesh, filt, iters, quantize, shape[1:],
+                                 launch)
+        fn = _build_job(runner, mesh, launch, shape, jnp.dtype(x.dtype))
+        sp.set(tap_form=runner.tap_form)
+        if runner.launch_args is not None and obs_metrics.enabled():
+            sp.set(**runner.launch_args(shape[0], storage))
+        if not fn.traced:
+            sp.set(compiled=1)  # the dispatch below traces and compiles
+    # As in _iterate_launch: no fence, and the halo counts are what the
+    # program's trace built.
+    with obs_trace.span("pctpu.step.dispatch") as sp:
+        if (isinstance(x, jax.Array) and x.committed
+                and x.sharding != fn.sharding):
+            # A program takes committed input only on its own devices, in
+            # their order: move an array committed elsewhere onto them.
+            x = jax.device_put(x, fn.sharding or replicated(mesh))
+        out = fn(x)
+        if obs_metrics.enabled():
+            count = fn.count()
+            sp.set(exchanges=count.rounds, halo_bytes=count.total_bytes)
+            if count.wire_bytes is not None:
+                sp.set(rdma_wire_bytes=count.wire_bytes)
+    if obs_metrics.enabled():
+        from parallel_convolution_tpu.obs import attribution
+
+        attribution.record_halo(launch.backend, count.bytes, count.rounds,
+                                iters, count.wire_bytes)
+    return out
 
 
 def _converge_setup(x, filt: Filter, mesh: Mesh, check_every: int, *,

@@ -20,8 +20,7 @@ from parallel_convolution_tpu.parallel import step
 from parallel_convolution_tpu.parallel.mesh import make_grid_mesh
 from parallel_convolution_tpu.utils.tracing import PhaseTimer
 
-STEP_SPANS = ("pctpu.resolve", "pctpu.step.prepare", "pctpu.step.build",
-              "pctpu.step.dispatch", "pctpu.step.crop")
+STEP_SPANS = ("pctpu.resolve", "pctpu.step.build", "pctpu.step.dispatch")
 CASES = [((1, 1), "zero"), ((2, 2), "zero"), ((1, 4), "zero"),
          ((1, 1), "periodic"), ((2, 2), "periodic"), ((1, 4), "periodic")]
 SHAPE, ITERS, FUSE = (1, 16, 24), 5, 2   # 2 fused rounds and a tail

@@ -137,20 +137,25 @@ def test_rdma_step_on_2x2_mesh(topo, overlap):
 
 
 def test_rgb_2520_cell_launch_on_2x2_mesh(topo):
-    # The rgb_2520.mesh2x2 cell's iterate program: BASELINE config 2's
+    # The rgb_2520.mesh2x2 cell's job program: BASELINE config 2's
     # 1920x2520 RGB image on 2x2 at u8, fuse 16, through the step layer's
-    # runner.  The RDMA form pads each 960x1260 block to whole (32, 128)
-    # u8 tiles, 960x1280; Mosaic refuses the 1260-wide band slices.  The
-    # fuse-16 chunk and the 4-deep tail are the program's two kernels;
-    # it has no collective-permute.  Both run blur3 in the rank-1
-    # separable form, so Mosaic takes its two-pass slices at u8 tiling.
+    # job program.  The RDMA form pads each 960x1260 block to whole
+    # (32, 128) u8 tiles, 960x1280; Mosaic refuses the 1260-wide band
+    # slices.  The fuse-16 chunk and the 4-deep tail are the program's
+    # two kernels; its only collectives are the pad's and the crop's
+    # column moves, under pctpu.job_io.  Both kernels run blur3 in the
+    # rank-1 separable form, so Mosaic takes its two-pass slices at u8
+    # tiling.
     mesh = make_grid_mesh(topo.devices, (2, 2))
     filt = get_filter("blur3")
     text = step.iterate_text((3, 1920, 2520), filt, 100,
                              mesh=mesh, backend="pallas_rdma",
                              storage="u8", fuse=16)
     assert text.count("tpu_custom_call") == 2
-    assert "collective-permute" not in text
+    scopes = step.op_scopes(text)
+    moves = {n: s for n, s in scopes.items()
+             if n.startswith(("collective-permute", "all-"))}
+    assert moves and set(moves.values()) == {"pctpu.job_io"}
     assert "u8[3,960,1280]" in text
     launch = step.resolve_launch(mesh, filt, (3, 1920, 2520),
                                  backend="pallas_rdma", storage="u8",
