@@ -6,7 +6,7 @@ itself puts on the same trace:
 
 - host spans named ``pctpu.*`` (``obs.trace.span``), with the arguments
   the program gave them: ``pctpu.run_planar``, ``pctpu.resolve``,
-  ``pctpu.step.{prepare,build,dispatch,crop}``; the dispatch span carries
+  ``pctpu.step.{build,dispatch}``; the dispatch span carries
   ``exchanges`` and ``halo_bytes``;
 - the named scopes of the iterate executable's instructions
   (``pctpu.halo``, ``pctpu.kernel_io``): the device trace's op events

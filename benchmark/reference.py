@@ -6,6 +6,12 @@ its filter from the configuration file.  Semantics (the reference's serial
 radius, accumulates ``tap * pixel`` over the taps in row-major order in the
 stated precision, rounds half to even, clips to [0, 255] and stores u8.
 
+On the device it runs where its input lies: a (C, H, W) image sharded
+over a mesh is recomputed one channel at a time in that same sharding,
+and XLA partitions the zero pad and the tap slices by itself (its own
+ghost exchange, not the program's), so no chip ever holds the whole
+image and a fault in the program's exchange cannot hide in the reference.
+
 ``precision`` is the precision of every multiply and add.  ``float32`` is
 the configuration's; ``bfloat16`` is the control, the nearest precision
 below it: each product and partial sum is rounded to bfloat16
@@ -44,8 +50,20 @@ def numpy_run(img: np.ndarray, taps, iters: int) -> np.ndarray:
     return out
 
 
+def _plane_sharding(x):
+    """The sharding of one (H, W) channel of ``x``: its mesh and the
+    spec of its last two axes; None for an unsharded input."""
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    sh = getattr(x, "sharding", None)
+    if not isinstance(sh, NamedSharding):
+        return None
+    spec = tuple(sh.spec) + (None,) * (3 - len(sh.spec))
+    return NamedSharding(sh.mesh, PartitionSpec(*spec[1:]))
+
+
 @functools.lru_cache(maxsize=None)
-def _plane_runner(taps, iters: int, precision: str):
+def _plane_runner(taps, iters: int, precision: str, sharding):
     import jax
     import jax.numpy as jnp
     from jax import lax
@@ -59,26 +77,44 @@ def _plane_runner(taps, iters: int, precision: str):
     else:
         raise ValueError(f"unknown precision {precision!r}")
 
+    def keep(v):  # a sharded plane stays in its sharding, step by step
+        if sharding is None:
+            return v
+        return lax.with_sharding_constraint(v, sharding)
+
     def one(v):
         H, W = v.shape
-        p = jnp.pad(v.astype(jnp.float32), r)
+        p = jnp.pad(v, r)  # u8: a sharded plane's ghost bands move as bytes
         acc = None
         for dy in range(k):
             for dx in range(k):
-                term = rnd(jnp.float32(taps[dy][dx]) * p[dy:dy + H, dx:dx + W])
+                px = p[dy:dy + H, dx:dx + W].astype(jnp.float32)
+                term = rnd(jnp.float32(taps[dy][dx]) * px)
                 acc = term if acc is None else rnd(acc + term)
-        return jnp.clip(jnp.round(acc), 0, 255).astype(jnp.uint8)
+        return keep(jnp.clip(jnp.round(acc), 0, 255).astype(jnp.uint8))
 
-    return jax.jit(lambda v: lax.fori_loop(0, iters, lambda _, u: one(u), v))
+    def run(x, c):
+        v = keep(x[c].astype(jnp.uint8))
+        return lax.fori_loop(0, iters, lambda _, u: one(u), v)
+
+    return jax.jit(run, static_argnums=1, out_shardings=sharding)
+
+
+@functools.lru_cache(maxsize=None)
+def _stacker(sharding):
+    import jax
+    import jax.numpy as jnp
+
+    return jax.jit(jnp.stack, out_shardings=sharding)
 
 
 def device_run(x, taps, iters: int, precision: str = "float32"):
-    """(C, H, W) device array of u8 values -> u8, one channel at a time so
-    it fits."""
-    import jax.numpy as jnp
-
-    run = _plane_runner(tuple(map(tuple, taps)), iters, precision)
-    return jnp.stack([run(x[c].astype(jnp.uint8)) for c in range(x.shape[0])])
+    """(C, H, W) array of u8 values -> u8, one channel at a time so it
+    fits; a sharded ``x`` gives an output in its own sharding."""
+    plane = _plane_sharding(x)
+    run = _plane_runner(tuple(map(tuple, taps)), iters, precision, plane)
+    planes = [run(x, c) for c in range(x.shape[0])]
+    return _stacker(None if plane is None else x.sharding)(planes)
 
 
 @functools.lru_cache(maxsize=None)
@@ -94,7 +130,9 @@ def _differ():
 
 
 def compare(got, want) -> dict:
-    """Mismatched bytes and the widest byte gap, computed on the device."""
+    """Mismatched bytes and the widest byte gap, computed on the device
+    where the two arrays lie: sharded arrays are reduced across their
+    chips, and neither is gathered onto one."""
     if tuple(got.shape) != tuple(want.shape):
         return {"mismatched_bytes": int(np.prod(want.shape)),
                 "max_abs_diff": 255}

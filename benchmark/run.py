@@ -7,7 +7,8 @@ configuration file, its traffic file (``benchmark/traffic/<traffic>.json``)
 and one reader per metric (``benchmark/metrics/<metric>.py``).  The window
 drives ``ConvolutionModel.run_planar`` (``closed_loop.py``); after it closes,
 the plain reference (``reference.py``) recomputes the checked job on the
-device and every byte is compared.
+cell's own mesh, in the resident image's sharding, and every byte is
+compared there.
 
 The last line of standard output is one JSON object: ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (with ``--trace 1`` also
@@ -181,6 +182,12 @@ def program_job(cell: Cell, devs):
     return job, model.mesh
 
 
+def peak_bytes(devs) -> int:
+    """The peak on the fullest chip, as the backend reports it."""
+    return max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+               for d in devs)
+
+
 def _configure_jax() -> None:
     import jax
 
@@ -194,21 +201,18 @@ def _configure_jax() -> None:
     jax.config.update("jax_compilation_cache_max_size", -1)
 
 
-def check(win, image, cell: Cell, device) -> dict:
-    """Recompute the checked job with the reference; compare every byte."""
-    import jax
-
+def check(win, image, cell: Cell) -> dict:
+    """Recompute the checked job with the reference on the cell's own mesh,
+    in the resident image's sharding; compare every byte there."""
     from benchmark import reference
 
     if win.kept is None:  # no job started in time to be checked
         C, H, W = image_shape(cell)
         return {"mismatched_bytes": C * H * W, "max_abs_diff": 255}
-    x = jax.device_put(image, device)
-    got = jax.device_put(win.kept, device)
-    want = reference.device_run(x, reference.taps_of(cell.config),
+    want = reference.device_run(image, reference.taps_of(cell.config),
                                 int(cell.config["iters_per_job"]),
                                 cell.config["arithmetic"])
-    return reference.compare(got, want)
+    return reference.compare(win.kept, want)
 
 
 def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
@@ -247,10 +251,11 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     finally:
         if trace:
             jax.profiler.stop_trace()
-    memory_peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                      for d in devs)
-    checks = check(win, image, cell, devs[0])
+    memory_peak = peak_bytes(devs)
+    checks = check(win, image, cell)
     win.kept = image = None
+    print(f"memory peak bytes: window {memory_peak}, with the check "
+          f"{peak_bytes(devs)}", file=sys.stderr)
     ok = all(checks[k] <= LIMITS[k] for k in LIMITS)
 
     dev = jax.devices()
